@@ -1296,6 +1296,27 @@ def _detect_batch_core(Xs, Xts, ts, valids, Ys, qas, *,
                                   rebalance=rebalance)
 
 
+_PALLAS_COMPONENTS = ("lasso", "monitor", "tmask", "fit", "score", "init",
+                      "mega")
+
+
+def _refuse_route(route: str, why: str) -> None:
+    """A requested Pallas route the code declines for this program: say
+    so (log line + ``kernel_pallas_refused`` counter) instead of falling
+    back to the XLA loop in silence, which would read as the route's own
+    result.  Trace-time, so it counts programs, not dispatches."""
+    from firebird_tpu.obs import logger
+    from firebird_tpu.obs import metrics as obs_metrics
+
+    obs_metrics.counter(
+        "kernel_pallas_refused",
+        help="programs traced with a requested Pallas route refused "
+             "(float64 on TPU, or the mega VMEM guard)").inc()
+    logger("pyccd").warning(
+        "Pallas route %r requested but refused (%s); this program runs "
+        "the XLA loop in its place", route, why)
+
+
 def _detect_batch_impl(Xs, Xts, ts, valids, Ys, qas, *, wcap, sensor,
                        max_segments, dtype, compact=None, fused=None,
                        mixed=None, rebalance=None):
@@ -1307,6 +1328,12 @@ def _detect_batch_impl(Xs, Xts, ts, valids, Ys, qas, *, wcap, sensor,
     change_thr, outlier_thr = chi2_thresholds(len(_DET))
     on_tpu = jax.default_backend() == "tpu"
     f32_ok = not on_tpu or fdtype == jnp.float32
+    if not f32_ok:
+        for route in [c for c in _PALLAS_COMPONENTS if use_pallas(c)] + (
+                ["fused"] if (fused_mode() if fused is None else fused)
+                else []):
+            _refuse_route(route, f"Mosaic lowers float32 only, the "
+                                 f"program is {fdtype.name} on TPU")
     # The mega decision is made ONCE, up front, because it shapes the
     # prologue: mega implies wire-resident mode (drops the float view)
     # and the Pallas fit kernel for the one-shot alt fits — but a mega
@@ -1318,6 +1345,9 @@ def _detect_batch_impl(Xs, Xts, ts, valids, Ys, qas, *, wcap, sensor,
         from firebird_tpu.ccd import pallas_ops
 
         mega = pallas_ops.mega_fits(T, W, B, S, Ys.dtype.itemsize)
+        if not mega:
+            _refuse_route("mega", f"mega_fits: T={T} W={W} B={B} S={S} "
+                                  f"exceeds VMEM at the 128-lane floor")
     fit_pallas = (use_pallas("fit") or mega) and f32_ok
     # Mixed-precision gram (FIREBIRD_MIXED_PRECISION / explicit mixed=):
     # bf16 split dots + int32 counts inside the Pallas fit routes, f32

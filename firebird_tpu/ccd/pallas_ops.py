@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from firebird_tpu.ccd import params
 
@@ -103,7 +104,10 @@ def _block_counts(active, BP: int, Pp: int):
     return jnp.sum(a.reshape(Pp // BP, BP), -1)[None]
 
 
-_CNT_SPEC = pl.BlockSpec((1, 1), lambda i: (0, i))
+# The counts ride whole in SMEM, the scalars the guard branches on: a
+# (1, 1) block per grid step is no legal TPU block (the last two block
+# dims must tile by 8 x 128 or span the array).
+_CNT_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _when_active(cnt_ref, compute, zero):
@@ -112,12 +116,13 @@ def _when_active(cnt_ref, compute, zero):
     if cnt_ref is None:
         compute()
         return
+    n = cnt_ref[0, pl.program_id(0)]
 
-    @pl.when(cnt_ref[0, 0] > 0)
+    @pl.when(n > 0)
     def _():
         compute()
 
-    @pl.when(cnt_ref[0, 0] == 0)
+    @pl.when(n == 0)
     def _():
         zero()
 
@@ -1864,11 +1869,9 @@ def fused_round(Yt, X, t, alive, included, cur_k, n_last_fit, in_mon,
 # ---------------------------------------------------------------------------
 
 def _ring_copy_kernel(dst_ref, x_ref, out_ref, send_sem, recv_sem):
-    from jax.experimental.pallas import tpu as pltpu  # TPU-only lowering
-
     copy = pltpu.make_async_remote_copy(
         src_ref=x_ref, dst_ref=out_ref, send_sem=send_sem,
-        recv_sem=recv_sem, device_id=(dst_ref[0],),
+        recv_sem=recv_sem, device_id=dst_ref[0],
         device_id_type=pltpu.DeviceIdType.LOGICAL)
     copy.start()
     copy.wait()
@@ -1887,12 +1890,10 @@ def ring_remote_copy(x, dst_index):
     kernels).  ``dst_index`` is a traced scalar (axis_index ± 1), fed
     through scalar prefetch.
     """
-    from jax.experimental.pallas import tpu as pltpu
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[pltpu.SemaphoreType.DMA] * 2,
     )
     return pl.pallas_call(
@@ -1922,6 +1923,13 @@ def mega_block_p(T: int, W: int, B: int, S: int, y_bytes: int) -> int:
     budget = 10 * 2 ** 20
     per_lane = _mega_per_lane_bytes(T, W, B, S, y_bytes)
     return max(128, min(512, (budget // per_lane) // 128 * 128))
+
+
+# A v5e core holds 128 MiB of VMEM, but Mosaic's default scoped limit is
+# 16 MiB: the v5e compiler refuses the mega block at the full-archive
+# T=512 under that default (37.5 MB at the 128-lane floor), so the kernel
+# asks for the room it needs.
+_MEGA_VMEM_LIMIT_BYTES = 100 * 2 ** 20
 
 
 def mega_fits(T: int, W: int, B: int, S: int, y_bytes: int) -> bool:
@@ -2403,6 +2411,8 @@ def detect_mega(Yt, phase0, cur_i0, alive0, nseg0, bufs0, t, X, Xt, vario,
             jax.ShapeDtypeStruct((C, 1, Pp), i32),
             jax.ShapeDtypeStruct((C, 3, Pp), i32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_MEGA_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*args)
     meta, rmses, mags, coefsb, nseg, alive_f, rounds, counts = outs

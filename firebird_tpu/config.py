@@ -121,8 +121,6 @@ KNOBS = (
          help="async store-writer worker threads"),
     Knob(name="FIREBIRD_PIPELINE_DEPTH", field="pipeline_depth",
          help="max device batches in flight"),
-    Knob(name="FIREBIRD_COMPILE_CACHE", field="compile_cache",
-         help="persistent XLA compile cache directory"),
     Knob(name="FIREBIRD_STREAM_DIR", field="stream_dir",
          help="streaming-state checkpoint directory"),
     Knob(name="FIREBIRD_STREAM_STATESTORE", field="stream_statestore",
@@ -328,8 +326,6 @@ KNOBS = (
     Knob(name="FIREBIRD_VARIOGRAM", default="adjusted",
          help="variogram mode: adjusted | plain"),
     # ---- process-wide switches read before/without a Config ----
-    Knob(name="FIREBIRD_JAX_PLATFORM",
-         help="pin the JAX platform (cpu/tpu) before first use"),
     Knob(name="FIREBIRD_NO_NATIVE",
          help="disable the native acceleration extensions"),
     Knob(name="FIREBIRD_METRICS", default="1",
@@ -348,11 +344,8 @@ KNOBS = (
     # ---- bench/smoke harness knobs (artifact dirs + budgets; read by
     # the tools that own the artifact, folded by bench.py) ----
     Knob(name="FIREBIRD_BENCH_BUDGET", default="2700",
-         readers=("bench.py", "tools/tpu_watchdog.sh"),
+         readers=("bench.py",),
          help="bench wall-clock budget (seconds)"),
-    Knob(name="FIREBIRD_TILE_BUDGET", default="3000",
-         readers=("tools/tpu_tile_run.sh",),
-         help="full-tile TPU run timeout (seconds)"),
     Knob(name="FIREBIRD_SOAK_DIR", default="/tmp/fb_soak",
          readers=("bench.py",),
          help="soak-run artifact directory"),
@@ -657,14 +650,6 @@ class Config:
     # tuned tight against HBM at depth 2 should either shrink the batch
     # or set FIREBIRD_PIPELINE_DEPTH=2.
     pipeline_depth: int = 3
-
-    # Persistent XLA compilation cache directory (FIREBIRD_COMPILE_CACHE /
-    # --compile-cache); "" disables.  With it set, every compiled kernel
-    # shape serializes to disk — the second run of a shape skips XLA — and
-    # the drivers AOT-compile the predicted batch shape on a background
-    # thread at run start so the first compile overlaps batch-0 fetch
-    # (driver.core.warm_start).
-    compile_cache: str = ""
 
     # ---- fleet work queue (firebird_tpu.fleet; docs/ROBUSTNESS.md) ----
     # Queue database path (FIREBIRD_FLEET_DB); "" derives fleet.db next
@@ -1102,7 +1087,6 @@ class Config:
             compact=e.get("FIREBIRD_COMPACT", "1") not in ("", "0"),
             pipeline_depth=int(e.get("FIREBIRD_PIPELINE_DEPTH",
                                      cls.pipeline_depth)),
-            compile_cache=e.get("FIREBIRD_COMPILE_CACHE", cls.compile_cache),
             fleet_db=e.get("FIREBIRD_FLEET_DB", cls.fleet_db),
             fleet_lease_sec=float(e.get("FIREBIRD_FLEET_LEASE_SEC",
                                         cls.fleet_lease_sec)),

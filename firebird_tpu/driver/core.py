@@ -23,6 +23,7 @@ import concurrent.futures as cf
 import contextlib
 import dataclasses
 import itertools
+import os
 import threading
 import time
 import traceback
@@ -105,14 +106,12 @@ def _mesh_ready() -> bool:
     (no coordinator configured), or when jax.distributed is actually up.
     An operator who exported JAX_COORDINATOR_ADDRESS but whose bring-up
     failed keeps /readyz at 503 instead of lying."""
-    import os
-
     if not os.environ.get("JAX_COORDINATOR_ADDRESS"):
         return True
     try:
-        from jax._src import distributed
+        import jax
 
-        return distributed.global_state.client is not None
+        return jax.distributed.is_initialized()
     except Exception:
         return False
 
@@ -151,8 +150,6 @@ def start_ops(cfg: Config, run_id: str, kind: str, *, chips_total: int,
     port bind fails, everything already started is torn down before the
     error propagates — a half-up ops surface must not outlive the raise.
     """
-    import os
-
     jsonlog.set_run_context(run_id=run_id, process_index=_process_index())
     obs_report.clear_stale_artifacts(cfg)
     record_topology_metrics()
@@ -389,6 +386,7 @@ def resolve_batching(cfg: Config, acquired: str) -> Config:
 _cache_listener_installed = False
 _warm_lock = threading.Lock()
 _warm_thread: threading.Thread | None = None  # guarded-by: _warm_lock
+_warm_shape: tuple | None = None  # guarded-by: _warm_lock
 
 
 def _install_cache_counters() -> None:
@@ -407,54 +405,57 @@ def _install_cache_counters() -> None:
     global _cache_listener_installed
     if _cache_listener_installed:
         return
-    try:
-        from jax._src import monitoring
+    import jax.monitoring
 
-        def _on_event(event, **kw):
-            if event == "/jax/compilation_cache/cache_hits":
-                obs_metrics.counter(
-                    "compile_cache_hits",
-                    help="persistent XLA compile-cache hits").inc()
-            elif event == "/jax/compilation_cache/cache_misses":
-                obs_metrics.counter(
-                    "compile_cache_misses",
-                    help="persistent XLA compile-cache misses").inc()
+    def _on_event(event, **kw):
+        if event == "/jax/compilation_cache/cache_hits":
+            obs_metrics.counter(
+                "compile_cache_hits",
+                help="persistent XLA compile-cache hits").inc()
+        elif event == "/jax/compilation_cache/cache_misses":
+            obs_metrics.counter(
+                "compile_cache_misses",
+                help="persistent XLA compile-cache misses").inc()
 
-        monitoring.register_event_listener(_on_event)
-        # Idempotent once-latch; a duplicate listener from a racing
-        # second run is harmless (both count the same events) and the
-        # driver installs from one thread in practice.
-        _cache_listener_installed = True  # firebird-lint: disable=ownership-global-mutation
-    except Exception:
-        pass         # older jax without the events: counters stay absent
+    jax.monitoring.register_event_listener(_on_event)
+    # Idempotent once-latch; a duplicate listener from a racing second
+    # run is harmless (both count the same events) and the driver
+    # installs from one thread in practice.
+    _cache_listener_installed = True  # firebird-lint: disable=ownership-global-mutation
 
 
-def setup_compile_cache(cfg: Config) -> str | None:
-    """Enable the persistent XLA compilation cache (FIREBIRD_COMPILE_CACHE
-    / --compile-cache).  Compiled programs serialize to the directory, so
-    the SECOND run of any shape deserializes instead of compiling — and
-    the background :func:`warm_start` AOT compile of run N becomes the
-    cache hit of run N+1's first dispatch.  Returns the cache path, or
-    None when the config leaves the cache off."""
-    if not cfg.compile_cache:
-        return None
-    import os
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
+
+def compile_cache_dir() -> str:
+    """The persistent XLA compilation cache directory:
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.cache/jax``.
+    The fallback is a FIXED path — never a tmp name, pid or time — because
+    a cache that moves between runs never hits."""
+    return os.path.abspath(os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                           or os.path.join(_CHECKOUT, ".cache", "jax"))
+
+
+def setup_compile_cache() -> str:
+    """Point jax's persistent compilation cache at :func:`compile_cache_dir`
+    (always on): compiled programs serialize there, so the SECOND run of
+    any shape deserializes instead of compiling — and the background
+    :func:`warm_start` AOT compile of run N becomes the cache hit of run
+    N+1's first dispatch.  Which compiles are worth keeping stays jax's
+    own rule (``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS``).  Returns
+    the cache path."""
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-    path = os.path.abspath(cfg.compile_cache)
+    path = compile_cache_dir()
     os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    # Cache every compile: the sub-second CPU-smoke kernels must warm run
-    # 2 as surely as a ten-minute TPU compile does.
-    with contextlib.suppress(Exception):
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    # Un-latch jax's once-per-process cache probe so enabling the cache
-    # mid-process (after an unrelated first compile) still takes effect.
-    with contextlib.suppress(Exception):
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
+    if jax.config.jax_compilation_cache_dir != path:
+        jax.config.update("jax_compilation_cache_dir", path)
+        # Un-latch jax's once-per-process cache probe so pointing the
+        # cache mid-process (after an unrelated first compile) still
+        # takes effect.
+        compilation_cache.reset_cache()
     _install_cache_counters()
     logger("change-detection").info("persistent compile cache at %s", path)
     return path
@@ -502,16 +503,14 @@ def warm_start(cfg: Config, acquired: str, sensor=None, dtype=None,
     background thread, so the (multi-second) first XLA compile overlaps
     batch 0's HTTP fetch instead of serializing after it.
 
-    Only runs when the persistent compilation cache is on: jit keeps its
-    own in-memory table, so the AOT executable can only reach the first
-    real dispatch *through* the cache (AOT writes the entry, the dispatch
-    deserializes it).  A failed or mispredicted warm compile costs
-    nothing but the background work.  Returns the started thread (join it
-    to observe ``warm_compile_seconds``), or None when the cache is off
-    or a previous warm compile is still running (no duplicate compiles).
+    Relies on the persistent compilation cache (:func:`setup_compile_cache`):
+    jit keeps its own in-memory table, so the AOT executable can only
+    reach the first real dispatch *through* the cache (AOT writes the
+    entry, the dispatch deserializes it).  A failed or mispredicted warm
+    compile costs nothing but the background work.  Returns the started
+    thread (join it to observe ``warm_compile_seconds``), or None when a
+    previous warm compile is still running (no duplicate compiles).
     """
-    if not cfg.compile_cache:
-        return None
     import jax
 
     from firebird_tpu.ccd.sensor import LANDSAT_ARD
@@ -522,7 +521,7 @@ def warm_start(cfg: Config, acquired: str, sensor=None, dtype=None,
     # donates on accelerators only) — a warm compile of the wrong donation
     # variant would miss the cache at dispatch time.
     if donate is None:
-        donate = _should_donate()
+        donate = _on_accelerator()
     kernel.ensure_x64(dtype)
     C, T, wcap = predict_batch_shape(cfg, acquired)
     B, P = sensor.n_bands, sensor.pixels
@@ -564,17 +563,34 @@ def warm_start(cfg: Config, acquired: str, sensor=None, dtype=None,
             logger("change-detection").warning(
                 "warm-start compile failed (run proceeds cold): %s", e)
 
-    global _warm_thread
+    global _warm_thread, _warm_shape
     with _warm_lock:
         if _warm_thread is not None and _warm_thread.is_alive():
             logger("change-detection").info(
                 "warm-start: previous warm compile still in flight; "
                 "not starting another")
             return None
+        _warm_shape = (C, T, wcap)
         _warm_thread = threading.Thread(
             target=_warm, name="firebird-warm-compile", daemon=True)
         _warm_thread.start()
         return _warm_thread
+
+
+def await_warm_compile(staged: StagedBatch) -> None:
+    """Before a dispatch of the shape an in-flight warm compile is
+    building, wait for it: the dispatch then reads the finished program
+    from the persistent cache instead of compiling the same program a
+    second time alongside it (on a v5e the default program compiles for
+    minutes, far longer than batch 0's fetch).  Any other shape, or no
+    warm compile in flight, returns at once."""
+    with _warm_lock:
+        thread, shape = _warm_thread, _warm_shape
+    C, _, _, T = staged.packed.spectra.shape
+    if thread is not None and thread.is_alive() \
+            and shape == (C, T, staged.wcap):
+        with tracing.span("await_warm_compile"):
+            thread.join()
 
 
 def _with_retries(cfg: Config, log, what: str, fn, policy=None):
@@ -606,8 +622,6 @@ def fetch(x, y, outdir: str, acquired: str | None = None,
     same fetch and only the manifest's chips are missing work.  Returns
     (chips written, chips attempted).
     """
-    import os
-
     cfg = cfg or Config.from_env()
     acquired = acquired or dt.default_acquired()
     log = logger("timeseries")
@@ -665,10 +679,13 @@ def fetch(x, y, outdir: str, acquired: str | None = None,
     return n, len(cids)
 
 
-def _should_donate() -> bool:
-    """Donate staged inputs on accelerators only: on the CPU backend the
-    HBM-footprint argument is moot, and the donated jit twin would just
-    double-compile every shape the (CPU) test suite already caches."""
+def _on_accelerator() -> bool:
+    """Whether the run's device is an accelerator.  Decides two things:
+    staged inputs are donated (on the CPU backend the HBM-footprint
+    argument is moot, and the donated jit twin would just double-compile
+    every shape the CPU test suite already caches), and the driver warm
+    starts (a background compile on the CPU backend competes for the very
+    cores the fetch it should overlap runs on)."""
     import jax
 
     return jax.default_backend() in ("tpu", "gpu")
@@ -1028,6 +1045,7 @@ def detect_chunk(cids, *, source, writer, acquired, cfg, counters, log,
             if prep is None:
                 continue                 # whole batch quarantined
             kept, staged = prep
+            await_warm_compile(staged)
             # The dispatch span measures enqueue time, not device compute
             # (check_capacity=False keeps it async); compute shows up as
             # the gap before the matching drain span closes.
@@ -1038,7 +1056,7 @@ def detect_chunk(cids, *, source, writer, acquired, cfg, counters, log,
                     seg, n_real = detect_batch(staged.packed, dtype,
                                                cfg.device_sharding,
                                                pad_to=pad_to, staged=staged,
-                                               donate=_should_donate(),
+                                               donate=_on_accelerator(),
                                                compact=cfg.compact)
                 obs_metrics.histogram(
                     "pipeline_dispatch_seconds").observe(tm.elapsed)
@@ -1147,11 +1165,11 @@ def changedetection(x, y, acquired: str | None = None, number: int = 2500,
     # that guarantees its stop — a setup failure here must not leak an
     # active process-global tracer into later runs.)
     obs_metrics.reset_registry()
-    # Compile-warm startup (FIREBIRD_COMPILE_CACHE): persistent cache on,
-    # then AOT-compile the predicted batch shape in the background so the
+    # Compile-warm startup: persistent cache on, then (on an accelerator)
+    # AOT-compile the predicted batch shape in the background so the
     # first XLA compile overlaps batch-0 fetch instead of following it.
-    setup_compile_cache(cfg)
-    warm = warm_start(cfg, acquired)
+    setup_compile_cache()
+    warm = warm_start(cfg, acquired) if _on_accelerator() else None
 
     # Refuse-or-warn BEFORE building anything: a resume against a
     # different acquired range must not interleave date windows (and must

@@ -235,8 +235,9 @@ def stream(x, y, acquired: str | None = None, number: int = 2500,
     # Compile-warm startup, same contract as the batch driver.  The
     # bootstrap dispatches at float32 with the capacity check ON (no
     # donation), so the warm shape must match that variant.
-    dcore.setup_compile_cache(cfg)
-    warm = dcore.warm_start(cfg, acquired, dtype=jnp.float32, donate=False)
+    dcore.setup_compile_cache()
+    warm = dcore.warm_start(cfg, acquired, dtype=jnp.float32, donate=False) \
+        if dcore._on_accelerator() else None
     # Same robustness plumbing as the batch driver (one code path:
     # dcore.robustness_setup): fault-plan proxies, shared retry budget +
     # ingest breaker, store-write retries, per-chip quarantine.

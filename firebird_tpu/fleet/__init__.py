@@ -11,7 +11,7 @@ from firebird_tpu.fleet.queue import (FencedStore, FleetQueue, Lease,
 from firebird_tpu.fleet.worker import WEDGED_EXIT, FleetWorker, make_queue
 from firebird_tpu.fleet.plan import enqueue_repairs, enqueue_tile_plan
 from firebird_tpu.fleet.policy import Decision, QueueSnapshot, ScalePolicy
-from firebird_tpu.fleet.supervisor import Supervisor
+from firebird_tpu.fleet.supervisor import Supervisor, workers_hold_accelerator
 
 __all__ = [
     "FencedStore", "FleetQueue", "Lease", "LeaseLost", "StaleFence",
@@ -19,4 +19,5 @@ __all__ = [
     "enqueue_repairs",
     "enqueue_tile_plan", "Decision", "QueueSnapshot", "ScalePolicy",
     "Supervisor",
+    "workers_hold_accelerator",
 ]

@@ -133,6 +133,19 @@ def pid_alive(pid: int) -> bool:
     return True
 
 
+def workers_hold_accelerator(env=None) -> bool:
+    """Whether spawned ``fleet work`` children open this host's
+    accelerator: yes unless ``JAX_PLATFORMS`` confines them to the CPU.
+    A chip belongs to one process, and that process holds every chip of
+    the host (the driver meshes over ``jax.local_devices()``), so a
+    second such worker fails at bring-up — on such a host the fleet is
+    ONE worker."""
+    env = os.environ if env is None else env
+    platforms = {p.strip() for p in env.get("JAX_PLATFORMS", "").split(",")
+                 if p.strip()}
+    return platforms != {"cpu"}
+
+
 class Supervisor:
     """The autoscaling control loop over one fleet queue.
 

@@ -1,15 +1,18 @@
 """ctypes bindings for the native ingest data plane (fastpack.cpp).
 
 The shared library is compiled on first use (g++, cached next to this
-file); every entry point has a NumPy fallback, so the package works — just
-slower — where no C++ toolchain exists.  ``available()`` reports which path
-is active; FIREBIRD_NO_NATIVE=1 forces the fallback (the test suite uses
-this to cover both).
+file under a name keyed on a hash of fastpack.cpp, so a checkout only
+ever loads a library built from its own source); every entry point has
+a NumPy fallback, so the package works — just slower — where no C++
+toolchain exists.  ``available()`` reports which path is active;
+FIREBIRD_NO_NATIVE=1 forces the fallback (the test suite uses this to
+cover both).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sys
@@ -19,7 +22,6 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "fastpack.cpp")
-_LIB = os.path.join(_HERE, "libfastpack.so")
 
 _lock = threading.Lock()
 # Mutated only inside _load's `with _lock:`; the double-checked fast
@@ -28,17 +30,26 @@ _lib = None  # guarded-by: _lock
 _tried = False  # guarded-by: _lock
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    """The library built from the current fastpack.cpp.  Keyed on the
+    source's content, not its mtime: a copied checkout carries a stale
+    untracked .so whose mtime says nothing about what it was built from."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"libfastpack-{digest}.so")
+
+
+def _build(lib_path: str) -> bool:
     # Compile to a process-private temp path and rename into place: the
     # in-process lock doesn't cover concurrent builds from sibling worker
     # processes, and rename() is atomic so nobody ever dlopens a
     # half-written library.
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
            _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.rename(tmp, _LIB)
+        os.rename(tmp, lib_path)
         return True
     except (OSError, subprocess.SubprocessError):
         try:
@@ -61,13 +72,14 @@ def _load():
 
         if env_knob("FIREBIRD_NO_NATIVE"):
             return None
-        if not os.path.exists(_LIB) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SRC) > os.path.getmtime(_LIB)):
-            if not _build():
-                return None
         try:
-            lib = ctypes.CDLL(_LIB)
+            lib_path = _lib_path()
+        except OSError:
+            return None
+        if not os.path.exists(lib_path) and not _build(lib_path):
+            return None
+        try:
+            lib = ctypes.CDLL(lib_path)
         except OSError:
             return None
         i64, u8p = ctypes.c_int64, ctypes.POINTER(ctypes.c_uint8)

@@ -43,6 +43,7 @@ DRIVER_SPAN_NAMES = ("fetch", "pack", "stage", "dispatch", "drain", "d2h",
 # the linter parses it from source.
 SPAN_NAMES = (
     "alert",
+    "await_warm_compile",
     "d2h",
     "deliver",
     "dispatch",

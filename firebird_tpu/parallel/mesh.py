@@ -434,20 +434,12 @@ def sharded_detect_fn(mesh: Mesh, dtype, wcap: int, sensor,
         return core(Xs, Xts, t, valid, Y_i16, qa_wire.astype(jnp.int32))
 
     spec = PartitionSpec("data")
-    # check_vma=False (check_rep=False pre-0.5 jax): the kernel's
-    # scan/while carries start from shard-constant zeros, which the
-    # varying-axes checker would demand explicit pcasts for; the
-    # collective-freedom claim is structural (nothing in _detect_core
-    # mentions the mesh axis at all).
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        wrapped = sm(local_batch, mesh=mesh, in_specs=(spec,) * 4,
-                     out_specs=spec, check_vma=False)
-    else:  # jax < 0.5: experimental module, check_rep spelling
-        from jax.experimental.shard_map import shard_map as sm_exp
-
-        wrapped = sm_exp(local_batch, mesh=mesh, in_specs=(spec,) * 4,
-                         out_specs=spec, check_rep=False)
+    # check_vma=False: the kernel's scan/while carries start from
+    # shard-constant zeros, which the varying-axes checker would demand
+    # explicit pcasts for; the collective-freedom claim is structural
+    # (nothing in _detect_core mentions the mesh axis at all).
+    wrapped = jax.shard_map(local_batch, mesh=mesh, in_specs=(spec,) * 4,
+                            out_specs=spec, check_vma=False)
     # Donation frees the sharded wire inputs (spectra + QA) at dispatch —
     # the driver's staged single-dispatch path only; capacity-retry
     # callers take the non-donating cache entry (kernel.detect_packed's

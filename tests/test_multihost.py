@@ -78,7 +78,7 @@ def test_two_process_changedetection(tmp_path):
     store = tmp_path / "mh.db"
     env_base = dict(os.environ)
     env_base.update({
-        "FIREBIRD_JAX_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "FIREBIRD_SOURCE": "synthetic",
         "FIREBIRD_STORE_BACKEND": "sqlite",
         "FIREBIRD_STORE_PATH": str(store),

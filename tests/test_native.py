@@ -6,6 +6,7 @@ must work with FIREBIRD_NO_NATIVE=1.
 """
 
 import base64
+import os
 
 import numpy as np
 import pytest
@@ -22,6 +23,29 @@ def _reload_fallback(monkeypatch):
 def test_library_builds():
     # g++ is part of the baked toolchain; the library must compile and load.
     assert native.available()
+
+
+def test_changed_source_hash_forces_rebuild(tmp_path, monkeypatch):
+    """The library is keyed on fastpack.cpp's content: a library built
+    from other source (here: the same file, one comment longer) is never
+    loaded, whatever its mtime — the edited source builds its own."""
+    src = tmp_path / "fastpack.cpp"
+    src.write_bytes(open(native._SRC, "rb").read())
+    monkeypatch.setattr(native, "_HERE", str(tmp_path))
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    monkeypatch.delenv("FIREBIRD_NO_NATIVE", raising=False)
+    first = native._lib_path()
+    assert native.available() and os.path.exists(first)
+
+    src.write_bytes(src.read_bytes() + b"\n// edited\n")
+    os.utime(first)                      # a newer stale library
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    second = native._lib_path()
+    assert second != first and not os.path.exists(second)
+    assert native.available() and os.path.exists(second)
 
 
 @pytest.mark.parametrize("n", [0, 1, 2, 3, 57, 20000])

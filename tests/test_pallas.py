@@ -721,15 +721,8 @@ def test_guarded_fit_inside_shard_map(monkeypatch):
                                    interpret=True)
         return jax.tree_util.tree_map(lambda o: o[None], out)
 
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        fn = sm(local, mesh=mesh, in_specs=(spec,) * 4, out_specs=spec,
-                check_vma=False)
-    else:  # jax < 0.5: experimental module, check_rep spelling
-        from jax.experimental.shard_map import shard_map as sm_exp
-
-        fn = sm_exp(local, mesh=mesh, in_specs=(spec,) * 4,
-                    out_specs=spec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec,) * 4,
+                       out_specs=spec, check_vma=False)
     got = fn(Yt, w, mask, act)
     for d in range(D):
         ref = pallas_ops.lasso_fit(Yt[d], w[d], X, mask[d],
@@ -848,3 +841,61 @@ def test_lasso_cd_and_tmask_guards():
     z = pallas_ops.tmask_bad(Xtw, Y2, w, v2, active=jnp.zeros(P, bool),
                              interpret=True)
     assert not np.asarray(z).any()
+
+
+def _trace_wire(T, dtype, P=16):
+    """Trace (not run) the wire-dtype batch program at [1, 7, P, T]."""
+    from firebird_tpu.driver import core
+
+    shapes = ((1, T), (1,), (1, 7, P, T), (1, P, T))
+    av = tuple(jax.ShapeDtypeStruct(s, d)
+               for s, d in zip(shapes, core.wire_avatar_dtypes()))
+    jax.eval_shape(lambda *a: kernel._detect_batch_wire(
+        *a, dtype=jnp.dtype(dtype), wcap=48, sensor=kernel.LANDSAT_ARD,
+        max_segments=kernel.MAX_SEGMENTS), *av)
+
+
+def _refused_traced(T, dtype):
+    """(counter increase, warning lines) from tracing one program."""
+    import logging
+
+    from firebird_tpu.obs import metrics as obs_metrics
+
+    def count():
+        return obs_metrics.get_registry().snapshot()["counters"].get(
+            "kernel_pallas_refused", 0)
+
+    lines = []
+    h = logging.Handler(logging.WARNING)
+    h.emit = lambda r: lines.append(r.getMessage())
+    log = logging.getLogger("firebird.pyccd")
+    log.addHandler(h)
+    before = count()
+    try:
+        _trace_wire(T, dtype)
+    finally:
+        log.removeHandler(h)
+    return count() - before, "\n".join(lines)
+
+
+def test_mega_refused_by_vmem_guard_is_logged_and_counted(monkeypatch):
+    """A mega request the VMEM guard refuses (T far past the full-archive
+    bucket) falls back to the XLA loop OUT LOUD: one log line and the
+    kernel_pallas_refused counter per traced program."""
+    assert not pallas_ops.mega_fits(2048, 48, 7, kernel.MAX_SEGMENTS, 2)
+    monkeypatch.setenv("FIREBIRD_PALLAS", "mega")
+    n, log = _refused_traced(2048, jnp.float32)
+    assert n == 1
+    assert "'mega' requested but refused (mega_fits" in log
+
+
+def test_pallas_request_refused_for_float64_on_tpu(monkeypatch):
+    """Mosaic lowers float32 only: a float64 program traced for a TPU
+    refuses each requested route by name instead of dropping it silently
+    (the backend is faked here; only the tracing decision is under test)."""
+    monkeypatch.setenv("FIREBIRD_PALLAS", "fit,score")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, log = _refused_traced(64, jnp.float64)
+    assert n == 2
+    assert "'fit' requested but refused (Mosaic lowers float32 only" in log
+    assert "'score' requested but refused" in log

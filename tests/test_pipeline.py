@@ -169,18 +169,15 @@ def test_donated_dispatch_matches_and_consumes_inputs(ragged_batch):
 
 
 @pytest.mark.slow  # ~46s (two full driver runs back-to-back); tier-1 (-m 'not slow') keeps the staging/egress pipeline rungs and `make pipeline-smoke` still proves the second-run compile-cache hit end-to-end
-def test_warm_start_compile_cache_hit_on_second_run(tmp_path):
-    """FIREBIRD_COMPILE_CACHE acceptance: run-1 warm compile populates
+def test_warm_start_compile_cache_hit_on_second_run(compile_cache_at):
+    """Persistent-cache acceptance: run-1 warm compile populates
     the persistent cache (miss counted), and after dropping the in-memory
     jit cache a second warm compile of the same predicted shape HITS."""
-    old_dir = jax.config.jax_compilation_cache_dir
-    old_min = jax.config.jax_persistent_cache_min_compile_time_secs
     cfg = Config(store_backend="memory", source_backend="synthetic",
-                 chips_per_batch=1, device_sharding="off",
-                 compile_cache=str(tmp_path / "cache"))
+                 chips_per_batch=1, device_sharding="off")
     acq = "1995-01-01/1995-09-01"
     try:
-        assert core.setup_compile_cache(cfg) == str(tmp_path / "cache")
+        assert core.setup_compile_cache() == compile_cache_at
         # Run 1 must trace from a clean slate: module-level lowering dedup
         # depends on the in-memory tracing caches, so a run 1 traced with
         # caches warmed by EARLIER tests (e.g. an x64 driver run) emits a
@@ -195,7 +192,7 @@ def test_warm_start_compile_cache_hit_on_second_run(tmp_path):
         snap = obs_metrics.get_registry().snapshot()
         assert snap["counters"]["warm_compiles"] == 1
         assert snap["histograms"]["warm_compile_seconds"]["count"] == 1
-        assert os.listdir(cfg.compile_cache)       # entry written
+        assert os.listdir(compile_cache_at)        # entry written
         assert snap["counters"].get("compile_cache_misses", 0) > 0
 
         jax.clear_caches()
@@ -207,20 +204,34 @@ def test_warm_start_compile_cache_hit_on_second_run(tmp_path):
         assert snap2["counters"].get("compile_cache_hits", 0) > 0
     finally:
         obs_metrics.reset_registry()
-        jax.config.update("jax_compilation_cache_dir", old_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          old_min)
-        try:
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:
-            pass
 
 
-def test_warm_start_off_without_compile_cache():
-    cfg = Config(store_backend="memory", compile_cache="")
-    assert core.warm_start(cfg, "1995-01-01/1996-01-01") is None
-    assert core.setup_compile_cache(cfg) is None
+def test_jax_cache_dir_wins_over_in_code_default(compile_cache_at):
+    """JAX_COMPILATION_CACHE_DIR, when set, is THE cache directory: the
+    driver sets no other (no checkout default, no config knob)."""
+    assert core.compile_cache_dir() == compile_cache_at
+    assert core.setup_compile_cache() == compile_cache_at
+    assert jax.config.jax_compilation_cache_dir == compile_cache_at
+    assert os.path.isdir(compile_cache_at)
+    assert not hasattr(Config(), "compile_cache")
+
+
+def test_cache_dir_is_fixed_across_processes_when_unset():
+    """Unset, the cache lives at <checkout>/.cache/jax in every process —
+    the path is part of what makes a later run hit, so it is never built
+    from a tmp name, pid or time."""
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    code = ("from firebird_tpu.driver import core; "
+            "print(core.compile_cache_dir())")
+    seen = [subprocess.run([sys.executable, "-c", code], cwd=root, env=env,
+                           capture_output=True, text=True, check=True,
+                           timeout=120).stdout.strip() for _ in range(2)]
+    assert seen[0] == seen[1] == os.path.join(root, ".cache", "jax")
 
 
 def test_predict_batch_shape_is_padded_and_bucketed():
@@ -241,9 +252,8 @@ def test_pipeline_depth_config():
     assert Config().pipeline_depth == 3
     with pytest.raises(ValueError):
         Config(pipeline_depth=0)
-    cfg = Config.from_env({"FIREBIRD_PIPELINE_DEPTH": "4",
-                           "FIREBIRD_COMPILE_CACHE": "/tmp/cc"})
-    assert cfg.pipeline_depth == 4 and cfg.compile_cache == "/tmp/cc"
+    cfg = Config.from_env({"FIREBIRD_PIPELINE_DEPTH": "4"})
+    assert cfg.pipeline_depth == 4
 
 
 def test_progress_reports_pipeline_occupancy():
@@ -261,3 +271,32 @@ def test_progress_reports_pipeline_occupancy():
     assert prog["pipeline"] == {"depth": 3, "in_flight": 1,
                                 "occupancy": round(1 / 3, 3)}
     assert obs_metrics.gauge("pipeline_inflight").value == 1
+
+
+def test_first_dispatch_waits_for_same_shape_warm_compile(monkeypatch):
+    """A dispatch of the shape the in-flight warm compile is building
+    waits for it (then reads the program from the persistent cache)
+    instead of compiling the same program alongside it; any other shape
+    does not wait."""
+    import threading
+
+    release = threading.Event()
+    warm = threading.Thread(target=release.wait, daemon=True)
+    warm.start()
+    monkeypatch.setattr(core, "_warm_thread", warm)
+    monkeypatch.setattr(core, "_warm_shape", (2, 64, 24))
+
+    def staged(C, T, wcap):
+        packed = PackedChips(cids=np.zeros((C, 2), np.int64),
+                             dates=np.zeros((C, T), np.int64),
+                             spectra=np.zeros((C, 7, 4, T), np.int16),
+                             qas=np.zeros((C, 4, T), np.uint16),
+                             n_obs=np.zeros(C, np.int64))
+        return core.StagedBatch(packed=packed, args=(), n_real=C,
+                                mesh=None, wcap=wcap)
+
+    core.await_warm_compile(staged(2, 64, 16))      # other shape: no wait
+    assert warm.is_alive()
+    threading.Timer(0.2, release.set).start()
+    core.await_warm_compile(staged(2, 64, 24))      # same shape: waits
+    assert not warm.is_alive()

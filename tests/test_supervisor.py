@@ -1138,3 +1138,45 @@ def test_recent_dead_rows_feed_circuit_stale_rows_do_not(tmp_path, queue,
     sup._reap_and_adopt()
     assert sup.tallies["crashed"] == before
     assert queue.workers() == []
+
+
+@pytest.mark.parametrize("platforms,held", [
+    ("cpu", False), (" cpu ", False), ("", True), ("tpu", True),
+    ("tpu,cpu", True)])
+def test_workers_hold_accelerator_reads_jax_platforms(platforms, held):
+    from firebird_tpu.fleet import workers_hold_accelerator
+
+    env = {"JAX_PLATFORMS": platforms} if platforms else {}
+    assert workers_hold_accelerator(env) is held
+
+
+def test_supervise_on_accelerator_host_runs_one_worker(tmp_path, queue,
+                                                       monkeypatch):
+    """A chip serves one process: where spawned workers would open the
+    accelerator, the supervisor clamps max_workers to 1 (and says so)
+    instead of crash-looping the second worker at bring-up."""
+    from click.testing import CliRunner
+
+    from firebird_tpu import cli
+    from firebird_tpu import fleet
+
+    seen = []
+
+    class _Probe(fleet.Supervisor):
+        def __init__(self, cfg, *a, **kw):
+            seen.append((cfg.fleet_min_workers, cfg.fleet_max_workers))
+            super().__init__(cfg, *a, **kw)
+
+    monkeypatch.setattr(cli, "apply_platform", lambda platform: None)
+    monkeypatch.setattr(fleet, "Supervisor", _Probe)
+    monkeypatch.setattr(fleet, "workers_hold_accelerator", lambda: True)
+    env = {"FIREBIRD_STORE_PATH": str(tmp_path / "s.db"),
+           "FIREBIRD_FLEET_DB": queue.path,
+           "FIREBIRD_FLEET_MAX_WORKERS": "4",
+           "FIREBIRD_OPS_PORT": "0"}
+    res = CliRunner().invoke(
+        cli.entrypoint,
+        ["fleet", "supervise", "--until-drained", "--tick", "0.01"],
+        env=env)
+    assert res.exit_code == 0, res.output
+    assert seen == [(0, 1)]

@@ -251,21 +251,15 @@ def test_f64_drain_keeps_raw_path(monkeypatch):
                                   np.asarray(seg.n_segments))
 
 
-def test_warm_avatars_hit_real_dispatch_cache(tmp_path):
+def test_warm_avatars_hit_real_dispatch_cache(compile_cache_at):
     """THE warm-start drift contract for the new signature: an AOT
     compile built from warm_start's avatar dtype tuple must be the
     persistent-cache entry a REAL staged dispatch of the same shape
     deserializes.  Any dtype drift between core.wire_avatar_dtypes and
     kernel.wire_args (e.g. a QA wire change on one side only) fails the
     equality below AND the cache-hit assertion."""
-    from firebird_tpu.config import Config
-
-    old_dir = jax.config.jax_compilation_cache_dir
-    old_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    cfg = Config(store_backend="memory", source_backend="synthetic",
-                 compile_cache=str(tmp_path / "cache"))
     try:
-        assert core.setup_compile_cache(cfg) == str(tmp_path / "cache")
+        assert core.setup_compile_cache() == compile_cache_at
 
         src = SyntheticSource(seed=3, start="1995-01-01",
                               end="1996-01-01")
@@ -283,7 +277,7 @@ def test_warm_avatars_hit_real_dispatch_cache(tmp_path):
                                         core.wire_avatar_dtypes()))
         kernel.aot_compile(avatars, dtype=jnp.float32,
                            wcap=kernel.window_cap(p), sensor=p.sensor)
-        assert os.listdir(cfg.compile_cache)       # AOT entry written
+        assert os.listdir(compile_cache_at)        # AOT entry written
         jax.clear_caches()                         # force the cache path
         obs_metrics.reset_registry()
         seg = kernel.detect_packed(p, dtype=jnp.float32)
@@ -293,6 +287,3 @@ def test_warm_avatars_hit_real_dispatch_cache(tmp_path):
             snap["counters"]
     finally:
         obs_metrics.reset_registry()
-        jax.config.update("jax_compilation_cache_dir", old_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          old_min)

@@ -141,7 +141,8 @@ def leg_env(tmp: str, leg: str) -> dict:
         "FIREBIRD_SLO": "alert_freshness=120",
         # One shared XLA cache: the first leg's compiles warm every
         # later subprocess.
-        "FIREBIRD_COMPILE_CACHE": os.path.join(tmp, "xla_cache"),
+        "JAX_COMPILATION_CACHE_DIR": os.path.join(tmp, "xla_cache"),
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
     })
     env.pop("FIREBIRD_FAULTS", None)
     env.pop("FIREBIRD_ALERT_DB", None)
@@ -284,7 +285,7 @@ def main() -> int:  # noqa: C901 (one linear drill, read top to bottom)
         # can truncate a cache entry, and a successor deserializing it
         # dies to a segfault inside XLA — the victim's corruption must
         # be as disposable as the victim.
-        victim_env = dict(env_kill, FIREBIRD_COMPILE_CACHE=os.path.join(
+        victim_env = dict(env_kill, JAX_COMPILATION_CACHE_DIR=os.path.join(
             tmp, "victim_cache"))
         victim_log = os.path.join(tmp, "victim.log")
         with open(victim_log, "w") as logf:

@@ -114,7 +114,8 @@ def base_env(tmp: str, leg: str) -> dict:
         # One shared XLA cache: the clean leg's compiles warm every
         # chaos-leg worker subprocess (the zombie deliberately forgoes
         # it so its first job outlives its 0.5 s lease on any host).
-        "FIREBIRD_COMPILE_CACHE": os.path.join(tmp, "xla_cache"),
+        "JAX_COMPILATION_CACHE_DIR": os.path.join(tmp, "xla_cache"),
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
     })
     env.pop("FIREBIRD_FAULTS", None)
     return env
@@ -134,7 +135,9 @@ def spawn_zombie(env: dict, log_path: str):
     e = dict(env)
     e.update({"FIREBIRD_FAULTS": "lease:p=1",
               "FIREBIRD_FLEET_LEASE_SEC": "0.5",
-              "FIREBIRD_COMPILE_CACHE": ""})
+              # an empty cache of its own: the zombie compiles cold
+              "JAX_COMPILATION_CACHE_DIR": os.path.join(
+                  os.path.dirname(log_path), "zombie_cache")})
     logf = open(log_path, "w")
     proc = subprocess.Popen(
         [sys.executable, "-m", "firebird_tpu.cli", "fleet", "work",
@@ -178,7 +181,10 @@ def main() -> int:
         env = base_env(tmp, "clean")
         os.makedirs(os.path.join(tmp, "clean"), exist_ok=True)
         cfg = Config.from_env(env=env)
-        dcore.setup_compile_cache(cfg)
+        # the in-process leg shares the subprocesses' cache
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = \
+            env["JAX_COMPILATION_CACHE_DIR"]
+        dcore.setup_compile_cache()
         queue = make_queue(cfg)
         t0 = time.time()
         plan = enqueue_tile_plan(queue, tiles, acquired=ACQ, number=1,

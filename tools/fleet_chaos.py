@@ -80,7 +80,8 @@ def base_env(tmp: str, leg: str) -> dict:
         "FIREBIRD_DTYPE": "float64",
         # One shared XLA cache: the clean leg's compiles warm every
         # chaos-leg worker subprocess.
-        "FIREBIRD_COMPILE_CACHE": os.path.join(tmp, "xla_cache"),
+        "JAX_COMPILATION_CACHE_DIR": os.path.join(tmp, "xla_cache"),
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
     })
     env.pop("FIREBIRD_FAULTS", None)
     return env
@@ -128,7 +129,10 @@ def main() -> int:
         env = base_env(tmp, "clean")
         os.makedirs(os.path.join(tmp, "clean"), exist_ok=True)
         cfg = Config.from_env(env=env)
-        dcore.setup_compile_cache(cfg)
+        # the in-process leg shares the subprocesses' cache
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = \
+            env["JAX_COMPILATION_CACHE_DIR"]
+        dcore.setup_compile_cache()
         queue = make_queue(cfg)
         plan = enqueue_tile_plan(queue, TILES, acquired=ACQ,
                                  number=N_CHIPS, chunk_size=CHUNK,
@@ -179,7 +183,9 @@ def main() -> int:
                 env, os.path.join(tmp, "zombie.log"),
                 {"FIREBIRD_FAULTS": "lease:p=1",
                  "FIREBIRD_FLEET_LEASE_SEC": "0.5",
-                 "FIREBIRD_COMPILE_CACHE": ""})
+                 # an empty cache of its own: the zombie compiles cold
+                 "JAX_COMPILATION_CACHE_DIR": os.path.join(
+                     tmp, "zombie_cache")})
             procs["zombie"] = zombie
             healthy = spawn_worker(env, os.path.join(tmp, "healthy.log"))
             procs["healthy"] = healthy

@@ -16,7 +16,7 @@ run 2 (warm)
 
 Exits non-zero on any violation — the CI-greppable proof that the
 zero-stall loop's staging/egress instrumentation wires through and that
-FIREBIRD_COMPILE_CACHE actually warms repeat runs.
+the persistent compile cache actually warms repeat runs.
 """
 
 import json
@@ -54,11 +54,13 @@ def main() -> int:
 
     with tempfile.TemporaryDirectory(prefix="fb_pipe_smoke_") as tmp:
         cache = os.path.join(tmp, "compile_cache")
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = cache
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         cfg = Config(store_backend="sqlite",
                      store_path=os.path.join(tmp, "smoke.db"),
                      source_backend="synthetic", chips_per_batch=1,
                      device_sharding="off", fetch_retries=0,
-                     pipeline_depth=2, compile_cache=cache)
+                     pipeline_depth=2)
         src = SyntheticSource(seed=9, start="1995-01-01", end="1998-01-01",
                               cloud_frac=0.1)
 

@@ -119,7 +119,8 @@ def smoke_env(tmp: str) -> dict:
         "FIREBIRD_DEVICE_SHARDING": "off",
         "FIREBIRD_FLEET_LEASE_SEC": "3",
         "FIREBIRD_ALERT_REPAIR": "0",
-        "FIREBIRD_COMPILE_CACHE": os.path.join(tmp, "xla_cache"),
+        "JAX_COMPILATION_CACHE_DIR": os.path.join(tmp, "xla_cache"),
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
         "FIREBIRD_TELEMETRY_SNAPSHOT_SEC": "1",
         # The budget under test: all-surfaces probe failure ratio at
         # 99% over a 5-minute window, judged at fine (10s) resolution;

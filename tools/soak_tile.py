@@ -142,7 +142,7 @@ def main() -> int:
     with open(os.path.join(workdir, "VARIOGRAM"), "w") as f:
         f.write(mode + "\n")
     env = dict(os.environ,
-               FIREBIRD_JAX_PLATFORM="cpu",
+               JAX_PLATFORMS="cpu",
                FIREBIRD_SOURCE="synthetic",
                FIREBIRD_STORE_BACKEND="sqlite",
                FIREBIRD_STORE_PATH=store,

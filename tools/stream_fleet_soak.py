@@ -227,7 +227,8 @@ def leg_env(tmp: str, leg: str) -> dict:
         # the repair roll-up would race the drill's drain accounting;
         # the soak asserts on stream/detect jobs only
         "FIREBIRD_ALERT_REPAIR": "0",
-        "FIREBIRD_COMPILE_CACHE": os.path.join(tmp, "xla_cache"),
+        "JAX_COMPILATION_CACHE_DIR": os.path.join(tmp, "xla_cache"),
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
     })
     for k in ("FIREBIRD_FAULTS", "FIREBIRD_ALERT_DB", "FIREBIRD_FLEET_DB",
               "FIREBIRD_WATCH_DB", "FIREBIRD_STREAM_STATESTORE"):

@@ -179,7 +179,8 @@ def smoke_env(tmp: str) -> dict:
         "FIREBIRD_DEVICE_SHARDING": "off",
         "FIREBIRD_FLEET_LEASE_SEC": "3",
         "FIREBIRD_ALERT_REPAIR": "0",
-        "FIREBIRD_COMPILE_CACHE": os.path.join(tmp, "xla_cache"),
+        "JAX_COMPILATION_CACHE_DIR": os.path.join(tmp, "xla_cache"),
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
         # tight snapshot cadence so even short-lived processes leave a
         # metric snapshot for `firebird top` / the collector
         "FIREBIRD_TELEMETRY_SNAPSHOT_SEC": "1",
