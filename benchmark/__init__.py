@@ -1,0 +1,2 @@
+"""The on-chip benchmark: ``python3 benchmark/run.py --workload <name>
+--seed <n> --seconds <s> --trace <0|1>`` (see ``run.py``)."""
