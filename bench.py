@@ -249,9 +249,9 @@ def _fleet_obs_fold() -> dict:
         return {}
     # The full document would dwarf the bench artifact; keep the
     # operator-relevant identity + scale block, plus the deep-dive
-    # verdicts: the SLO evaluation and the device-time attribution of
-    # any profile windows the run captured (obs/profiling.py — the
-    # per-phase split bench rounds were blind to through r01-r05).
+    # verdicts: the SLO evaluation and the device time of any profile
+    # windows the run captured (obs/profiling.py: busy, idle share, idle
+    # under the dispatch thread's waits).
     prof = rep.get("profile") or {}
     return {"fleet_obs_report": {
         "run": rep.get("run", {}),

@@ -537,8 +537,9 @@ def warm_start(cfg: Config, acquired: str, sensor=None, dtype=None,
 
     def _warm():
         try:
-            with tracing.span("warm_compile", shape=(C, T, wcap)), \
-                    obs_metrics.timer() as tm:
+            with tracing.span("warm_compile", shape=(C, T, wcap),
+                              histogram=reg.histogram(
+                                  "warm_compile_seconds")):
                 if use_mesh:
                     from firebird_tpu.parallel import make_mesh
                     from firebird_tpu.parallel.mesh import \
@@ -555,7 +556,6 @@ def warm_start(cfg: Config, acquired: str, sensor=None, dtype=None,
                     kernel.aot_compile(avatars, dtype=dtype, wcap=wcap,
                                        sensor=sensor, donate=donate,
                                        compact=cfg.compact)
-            reg.histogram("warm_compile_seconds").observe(tm.elapsed)
             reg.counter("warm_compiles",
                         help="background AOT compiles completed").inc()
         except Exception as e:
@@ -722,7 +722,8 @@ def stage_batch(packed, dtype, sharding: str = "auto",
     use_mesh = sharding != "off" and n_dev > 1
     padded, real = _pad_batch(
         packed, _pad_target(packed.n_chips, pad_to, use_mesh, n_dev))
-    with tracing.span("stage", chips=real), obs_metrics.timer() as tm:
+    with tracing.span("stage", chips=real, histogram=obs_metrics.histogram(
+            "pipeline_stage_seconds")):
         # The `transfer` span leg (leg=h2d; its d2h twin wraps the drain's
         # bulk fetch) makes transfer-vs-compute overlap directly readable
         # off the host trace: a healthy pipeline shows h2d transfer spans
@@ -738,7 +739,6 @@ def stage_batch(packed, dtype, sharding: str = "auto",
                 mesh = None
                 args = k.stage_packed(padded, dtype)
                 wcap = k.window_cap(padded)
-    obs_metrics.histogram("pipeline_stage_seconds").observe(tm.elapsed)
     obs_metrics.counter(
         "wire_h2d_bytes",
         help="bytes staged host->device (all-integer packed inputs)").inc(
@@ -808,6 +808,26 @@ def detect_batch(packed, dtype, sharding: str = "auto",
     return detect_sharded(padded, mesh, dtype=dtype, **kw), real
 
 
+def segment_depth(seg) -> int:
+    """The capacity probe: the most segments any pixel of the batch
+    closed.  Reading ``n_segments`` blocks until the batch's kernel has
+    finished, so this is where the drain waits on the device
+    (``wait_device`` span, ``egress_wait_device_seconds``)."""
+    with tracing.span("wait_device", histogram=obs_metrics.histogram(
+            "egress_wait_device_seconds")):
+        return int(np.asarray(seg.n_segments).max())
+
+
+def format_span():
+    """The span of the drain's host formatting (int-coded decode and
+    ``format.batch_frames``): wall and thread-CPU seconds, so wall minus
+    CPU is the time the drain thread waited inside it."""
+    return tracing.span(
+        "format",
+        histogram=obs_metrics.histogram("egress_format_seconds"),
+        cpu_histogram=obs_metrics.histogram("egress_format_cpu_seconds"))
+
+
 def fetch_results(seg, worst: int | None = None):
     """The ONE bulk device->host fetch per batch: ``jax.device_get`` of
     the whole batched result, collapsing the old per-chip, per-field
@@ -822,29 +842,30 @@ def fetch_results(seg, worst: int | None = None):
     budget").  ``worst`` is the caller's capacity probe (max segments
     any pixel closed) when it already paid that sync; None probes here.
     Records ``pipeline_d2h_seconds``, the ``wire_d2h_bytes`` counter,
-    and the d2h ``transfer`` span leg; returns a host-array
-    ChipSegments."""
+    the d2h ``transfer`` span leg and the decode's ``format`` span;
+    returns a host-array ChipSegments."""
     import jax
 
     payload, decode_T = seg, None
     if kernel.wire_egress_enabled() and seg.seg_meta.dtype == jnp.float32:
         if worst is None:
-            worst = int(np.asarray(seg.n_segments).max())
+            worst = segment_depth(seg)
         s_eff = kernel.egress_bucket(worst, seg.seg_meta.shape[-2])
         payload = kernel.pack_egress(seg, s_eff)
         decode_T = seg.mask.shape[-1]
     nbytes = int(sum(getattr(v, "nbytes", 0)
                      for v in jax.tree_util.tree_leaves(payload)))
-    with tracing.span("d2h", bytes=nbytes), obs_metrics.timer() as tm:
+    with tracing.span("d2h", bytes=nbytes, histogram=obs_metrics.histogram(
+            "pipeline_d2h_seconds")):
         with tracing.span("transfer", leg="d2h", bytes=nbytes):
             host = jax.device_get(payload)
-    obs_metrics.histogram("pipeline_d2h_seconds").observe(tm.elapsed)
     obs_metrics.counter(
         "wire_d2h_bytes",
         help="bytes fetched device->host (batch results, int-coded and "
              "depth-sliced when the egress diet is on)").inc(nbytes)
     if decode_T is not None:
-        host = ccdformat.decode_egress(host, decode_T)
+        with format_span():
+            host = ccdformat.decode_egress(host, decode_T)
     return host
 
 
@@ -853,10 +874,13 @@ def write_batch_frames(packed, host_seg, n_real, *, writer, counters=None):
     of both drivers: ``format.batch_frames`` builds the three tables
     across the chip axis in one numpy pass, split back into the existing
     keyed per-chip writes, so the segment frame still lands last per chip
-    (the resume invariant)."""
+    (the resume invariant).  The frames are built (``format`` span)
+    before the first write is queued, so the queue's waits
+    (``queue_wait``) stay out of the formatting time."""
     P = host_seg.n_segments.shape[1]
-    for c, (cid, frames) in enumerate(
-            ccdformat.batch_frames(packed, host_seg, n_real)):
+    with format_span():
+        batch = ccdformat.batch_frames(packed, host_seg, n_real)
+    for c, (cid, frames) in enumerate(batch):
         for table in ("chip", "pixel", "segment"):
             # keyed: one chip's frames drain in order, so the segment
             # frame lands last (the resume invariant)
@@ -887,12 +911,14 @@ def drain_batch(seg, packed, n_real, *, writer, counters, dtype=None,
     check on — rare enough that the synchronous re-run does not matter."""
     cap = seg.seg_meta.shape[-2]                   # [.., P, S, 6] -> S
     with tracing.activate(ctx):
-        with tracing.span("drain", chips=n_real), obs_metrics.timer() as tm:
+        with tracing.span("drain", chips=n_real,
+                          histogram=obs_metrics.histogram(
+                              "pipeline_drain_seconds")) as sp:
             # Capacity probe BEFORE the bulk fetch: n_segments alone is a
             # few hundred KB, so an overflowed batch never pays a
             # full-result transfer whose buffers are about to be discarded
             # (and the d2h telemetry counts only the one real bulk fetch).
-            worst = int(np.asarray(seg.n_segments).max())
+            worst = segment_depth(seg)
             if worst > cap:
                 logger("pyccd").info(
                     "segment capacity %d overflowed on drain (deepest pixel "
@@ -911,11 +937,10 @@ def drain_batch(seg, packed, n_real, *, writer, counters, dtype=None,
             kernel.record_occupancy(host)
             write_batch_frames(packed, host, n_real, writer=writer,
                                counters=counters)
-        obs_metrics.histogram("pipeline_drain_seconds").observe(tm.elapsed)
         # In-context completion line: with FIREBIRD_LOG_FORMAT=json this
         # carries the batch id, joining the drain to its spans/exemplars.
         logger("change-detection").debug(
-            "batch drained: %d chips in %.3fs", n_real, tm.elapsed)
+            "batch drained: %d chips in %.3fs", n_real, sp.elapsed)
     # Forward-progress beat: a drained batch is the watchdog's liveness
     # unit and /progress's batches_done tick (no-op when no run registered).
     obs_server.batch_done(n_real)
@@ -1004,26 +1029,31 @@ def detect_chunk(cids, *, source, writer, acquired, cfg, counters, log,
             Returns (surviving chip ids, StagedBatch), or None when every
             chip of the batch was quarantined."""
             with tracing.activate(ctx):
-                with tracing.span("fetch", chips=len(bids)), \
-                        obs_metrics.timer() as tm:
+                with tracing.span("fetch", chips=len(bids),
+                                  histogram=obs_metrics.histogram(
+                                      "pipeline_fetch_seconds")):
                     chips = list(chips_ex.map(
                         lambda xy: fetch_one(xy, ctx), bids))
-                obs_metrics.histogram(
-                    "pipeline_fetch_seconds").observe(tm.elapsed)
                 keep = [(cid, ch) for cid, ch in zip(bids, chips)
                         if ch is not None]
                 if not keep:
                     return None
-                with tracing.span("pack", chips=len(keep)), \
-                        obs_metrics.timer() as tm:
+                with tracing.span("pack", chips=len(keep),
+                                  histogram=obs_metrics.histogram(
+                                      "pipeline_pack_seconds")):
                     packed = pack([ch for _, ch in keep],
                                   bucket=cfg.obs_bucket,
                                   max_obs=cfg.max_obs)
-                obs_metrics.histogram(
-                    "pipeline_pack_seconds").observe(tm.elapsed)
                 return [cid for cid, _ in keep], \
                     stage_batch(packed, dtype, cfg.device_sharding,
                                 pad_to=pad_to)
+
+        def wait_egress():
+            """The main thread blocked on drains: a pipeline slot at
+            ``pipeline_depth``, or the chunk's last drains."""
+            return tracing.span("wait_egress",
+                                histogram=obs_metrics.histogram(
+                                    "pipeline_wait_egress_seconds"))
 
         nxt = prefetch_ex.submit(prepare_batch, batches[0], ctxs[0]) \
             if batches else None
@@ -1038,7 +1068,10 @@ def detect_chunk(cids, *, source, writer, acquired, cfg, counters, log,
             if isinstance(err, retrylib.NonRetryable):
                 raise err
             obs_server.set_stage("fetch")
-            prep = nxt.result()
+            # Blocked on the prefetch: fetch, pack or h2d not done yet.
+            with tracing.span("wait_input", histogram=obs_metrics.histogram(
+                    "pipeline_wait_input_seconds")):
+                prep = nxt.result()
             nxt = (prefetch_ex.submit(prepare_batch, batches[i + 1],
                                       ctxs[i + 1])
                    if i + 1 < len(batches) else None)
@@ -1047,19 +1080,18 @@ def detect_chunk(cids, *, source, writer, acquired, cfg, counters, log,
             kept, staged = prep
             await_warm_compile(staged)
             # The dispatch span measures enqueue time, not device compute
-            # (check_capacity=False keeps it async); compute shows up as
-            # the gap before the matching drain span closes.
+            # (check_capacity=False keeps it async); the drain's
+            # wait_device span is where the host waits for the compute.
             obs_server.set_stage("dispatch")
             with tracing.activate(ctxs[i]):
-                with tracing.span("dispatch", chips=staged.n_real), \
-                        obs_metrics.timer() as tm:
+                with tracing.span("dispatch", chips=staged.n_real,
+                                  histogram=obs_metrics.histogram(
+                                      "pipeline_dispatch_seconds")):
                     seg, n_real = detect_batch(staged.packed, dtype,
                                                cfg.device_sharding,
                                                pad_to=pad_to, staged=staged,
                                                donate=_on_accelerator(),
                                                compact=cfg.compact)
-                obs_metrics.histogram(
-                    "pipeline_dispatch_seconds").observe(tm.elapsed)
             # /readyz flips here: mesh up + first batch dispatched means
             # compile/bring-up are behind us and the run is steady-state.
             obs_server.batch_dispatched()
@@ -1075,9 +1107,11 @@ def detect_chunk(cids, *, source, writer, acquired, cfg, counters, log,
             # result buffers — but unbounded depth would still exhaust
             # HBM, hence the config.
             while len(drains) > depth - 1:
-                drains.pop(0).result()
-        for f in drains:
-            f.result()
+                with wait_egress():
+                    drains.pop(0).result()
+        with wait_egress():
+            for f in drains:
+                f.result()
     return processed
 
 

@@ -375,25 +375,23 @@ def stream(x, y, acquired: str | None = None, number: int = 2500,
 
             def prepare(bids, ctx):
                 with tracing.activate(ctx):
-                    with tracing.span("fetch", chips=len(bids)), \
-                            obs_metrics.timer() as tm:
+                    with tracing.span("fetch", chips=len(bids),
+                                      histogram=obs_metrics.histogram(
+                                          "pipeline_fetch_seconds")):
                         fetched = list(ex.map(
                             lambda c: fetch_chip(c, acquired), bids))
-                    obs_metrics.histogram(
-                        "pipeline_fetch_seconds").observe(tm.elapsed)
                     # fetch_chip already logged/quarantined each dropped
                     # chip.
                     keep = [(cid, ch) for cid, ch in zip(bids, fetched)
                             if ch is not None]
                     if not keep:
                         return None
-                    with tracing.span("pack", chips=len(keep)), \
-                            obs_metrics.timer() as tm:
+                    with tracing.span("pack", chips=len(keep),
+                                      histogram=obs_metrics.histogram(
+                                          "pipeline_pack_seconds")):
                         p = pack([ch for _, ch in keep],
                                  bucket=cfg.obs_bucket,
                                  max_obs=cfg.max_obs)
-                    obs_metrics.histogram(
-                        "pipeline_pack_seconds").observe(tm.elapsed)
                     return keep, dcore.stage_batch(
                         p, jnp.float32, cfg.device_sharding, pad_to=pad_to)
 
@@ -408,8 +406,9 @@ def stream(x, y, acquired: str | None = None, number: int = 2500,
                     continue
                 keep, staged = prep
                 with tracing.activate(ctxs[i]):
-                    with tracing.span("dispatch", chips=staged.n_real), \
-                            obs_metrics.timer() as tm:
+                    with tracing.span("dispatch", chips=staged.n_real,
+                                      histogram=obs_metrics.histogram(
+                                          "pipeline_dispatch_seconds")):
                         # capacity check ON (synchronous retry): staged
                         # args may be re-dispatched, so NOT donated.
                         seg, n_real = dcore.detect_batch(
@@ -417,11 +416,10 @@ def stream(x, y, acquired: str | None = None, number: int = 2500,
                             cfg.device_sharding, pad_to=pad_to,
                             check_capacity=True, staged=staged,
                             compact=cfg.compact)
-                    obs_metrics.histogram(
-                        "pipeline_dispatch_seconds").observe(tm.elapsed)
                     obs_server.batch_dispatched()
-                    with tracing.span("drain", chips=n_real), \
-                            obs_metrics.timer() as tm:
+                    with tracing.span("drain", chips=n_real,
+                                      histogram=obs_metrics.histogram(
+                                          "pipeline_drain_seconds")):
                         host = dcore.fetch_results(seg)
                         kernel.record_occupancy(host)
                         dcore.write_batch_frames(staged.packed, host,
@@ -443,8 +441,6 @@ def stream(x, y, acquired: str | None = None, number: int = 2500,
                             quarantine.discard(cid)
                             summary["pixels_need_batch"] += int(
                                 np.asarray(st.needs_batch).sum())
-                    obs_metrics.histogram(
-                        "pipeline_drain_seconds").observe(tm.elapsed)
                 obs_server.batch_done(n_real)
 
         # --- update: apply only acquisitions past each chip's horizon ---
@@ -551,13 +547,12 @@ def stream(x, y, acquired: str | None = None, number: int = 2500,
                                 acq_to_alert=acq_to_alert)
                             summary["alerts_emitted"] += ins
                             summary["alerts_deduped"] += dup
-                    with tracing.span("publish", chip=tuple(cid)), \
-                            obs_metrics.timer() as tm:
+                    with tracing.span("publish", chip=tuple(cid),
+                                      histogram=obs_metrics.histogram(
+                                          "stream_publish_seconds")):
                         writer.write("segment", publish_frame(p, st, side),
                                      key=tuple(cid))
                         sstore.save(cid, st, side)
-                    obs_metrics.histogram(
-                        "stream_publish_seconds").observe(tm.elapsed)
                     summary["updated"] += 1
                     summary["obs_applied"] += int(new_idx.size)
             n_need = int(np.asarray(st.needs_batch).sum())

@@ -260,15 +260,14 @@ class FleetWorker:
                 raise ValueError(
                     f"no handler for job type {lease.job_type!r}")
             with tracing.activate(ctx):
-                with tracing.span("fleet_job", job=lease.job_id,
-                                  type=lease.job_type), \
-                        obs_metrics.timer() as tm:
-                    handler(lease.payload, lease)
                 # Inside the activation on purpose: the histogram's
                 # slowest-N exemplars carry this job's trace id.
-                obs_metrics.histogram(
-                    f"fleet_job_seconds_{lease.job_type}").observe(
-                    tm.elapsed)
+                seconds = obs_metrics.histogram(
+                    f"fleet_job_seconds_{lease.job_type}")
+                with tracing.span("fleet_job", job=lease.job_id,
+                                  type=lease.job_type,
+                                  histogram=seconds) as sp:
+                    handler(lease.payload, lease)
             stop_heartbeat()
             self.queue.ack(lease)
             self.tallies["acked"] += 1
@@ -276,7 +275,7 @@ class FleetWorker:
                            fence=lease.fence)
             obs_spool.mark("job_acked", trace=ctx.batch_id,
                            job=lease.job_id, type=lease.job_type)
-            self.log.info("acked job %d (%.2fs)", lease.job_id, tm.elapsed)
+            self.log.info("acked job %d (%.2fs)", lease.job_id, sp.elapsed)
         except (StaleFence, StaleObjectFence, LeaseLost) as e:
             # The job is a successor's now: abandon it quietly — no
             # fail() (our token could not record one anyway), no

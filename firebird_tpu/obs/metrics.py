@@ -254,6 +254,16 @@ METRIC_HELP = {
     "pipeline_dispatch_seconds": "per-batch dispatch (enqueue) wall time",
     "pipeline_drain_seconds": "per-batch result drain wall time",
     "pipeline_d2h_seconds": "per-batch bulk device_get wall time",
+    "pipeline_wait_input_seconds":
+        "dispatch thread blocked on the next prefetched batch",
+    "pipeline_wait_egress_seconds":
+        "dispatch thread blocked on drains (pipeline slot, chunk end)",
+    "egress_wait_device_seconds":
+        "drain blocked in the capacity probe until the kernel finished",
+    "egress_format_seconds":
+        "drain decode + batch_frames wall time (writes excluded)",
+    "egress_format_cpu_seconds":
+        "drain thread CPU time over the egress_format span",
     "ingest_chip_seconds": "per-chip source fetch wall time",
     "ingest_http_seconds": "chipmunk HTTP request wall time",
     "ingest_http_requests": "chipmunk HTTP requests issued",
@@ -263,6 +273,10 @@ METRIC_HELP = {
     "chunk_failures": "chunks abandoned by the per-chunk isolation",
     "fetch_retries": "chip fetches retried after transient errors",
     "store_write_seconds": "store backend write wall time",
+    "store_write_cpu_seconds":
+        "writer thread CPU time inside the backend write",
+    "store_queue_wait_seconds":
+        "writer backpressure: a write blocked on the full queue",
     "store_flush_seconds": "writer flush (drain-all) wall time",
     "store_write_errors": "store writes that exhausted their retries",
     "store_write_retries": "store writes retried after transient errors",

@@ -16,14 +16,18 @@ one-request operation on a live run:
 
 Each window wraps ``jax.profiler.start_trace``/``stop_trace`` around a
 bounded wait and writes the standard XLA/TensorBoard artifact
-(``.trace.json.gz`` + xplane) under ``<store dir>/device_profile/
+(``.xplane.pb`` + ``.trace.json.gz``) under ``<store dir>/device_profile/
 window_<n>/`` — linkable from the run's other artifacts, loadable in
-Perfetto/TensorBoard.  The Chrome-trace half is then parsed for
-**per-phase device-time attribution**: event durations bucketed into
-the CCD loop's phases (fit / monitor / compaction) by kernel-name
-pattern, folded into ``obs_report.json`` (``profile`` block — structure
-always present, zeros allowed on backends whose op names match nothing)
-and from there into bench artifacts.
+Perfetto/TensorBoard.  The window's ``.xplane.pb`` is then reduced to
+**why the device was idle**: its busy seconds (the union of each device
+plane's ``XLA Ops`` intervals, averaged over planes), its idle share,
+and the idle seconds that fall inside the dispatch thread's waits on
+egress (``firebird.wait_egress`` / ``firebird.store_flush``) and on
+input (``firebird.wait_input``) — the program's own spans, which
+obs/tracing.py puts on the profiler's clock.  The reduction is folded
+into ``obs_report.json`` (``profile`` block — structure always present,
+zeros allowed on backends with no device plane) and from there into
+bench artifacts.
 
 ``Config.profile_dir`` (FIREBIRD_PROFILE_DIR) remains the whole-run
 capture; this module is the *windowed* complement a multi-hour run
@@ -33,8 +37,6 @@ needs (a full-run device trace of a tile run is gigabytes).
 from __future__ import annotations
 
 import glob
-import gzip
-import json
 import os
 import threading
 import time
@@ -42,64 +44,116 @@ import time
 from firebird_tpu.obs import metrics as obs_metrics
 from firebird_tpu.obs import tracing
 
-# Kernel-name patterns -> CCD event-loop phase.  Matched as lowercase
-# substrings against every trace event name; first phase wins, anything
-# unmatched lands in "other".  Zeros are legitimate (the lax CPU path
-# fuses phases into opaque while-loop ops) — the structure is the
-# contract, the split fills in where the lowering preserves names
-# (Pallas kernels, named HLO ops on TPU).
-PHASE_PATTERNS = (
-    ("fit", ("lasso", "gram", "cd_step", "lstsq", "fit")),
-    ("monitor", ("monitor", "score", "peek", "tmask")),
-    ("compaction", ("compact", "permut", "scatter", "cumsum", "sort")),
-)
-PHASES = tuple(name for name, _ in PHASE_PATTERNS) + ("other",)
+OPS_LINE = "XLA Ops"
+# Idle time inside these spans is put down to the dispatch thread
+# waiting on egress (a pipeline slot, the chunk's last drains, the
+# writer's flush) or on its next input batch.
+EGRESS_WAIT_SPANS = ("firebird.wait_egress", "firebird.store_flush")
+INPUT_WAIT_SPANS = ("firebird.wait_input",)
+SECONDS = ("window_s", "busy_s", "idle_wait_egress_s", "idle_wait_input_s")
 
 
-def empty_attribution(source: str = "none") -> dict:
-    out = {f"{p}_ms": 0.0 for p in PHASES}
-    out.update({"total_ms": 0.0, "events": 0, "source": source})
+def empty_device_time(source: str = "none") -> dict:
+    out = dict.fromkeys(SECONDS, 0.0)
+    out.update({"idle_pct": None, "devices": 0, "source": source})
     return out
 
 
-def attribute_phases(trace_dir: str) -> dict:
-    """Per-phase device-time split of a captured window.
-
-    Walks the window directory for the ``.trace.json.gz`` files jax's
-    profiler writes (``plugins/profile/<ts>/<host>.trace.json.gz``),
-    sums complete-event durations by PHASE_PATTERNS, and returns the
-    attribution dict (milliseconds).  Unreadable/absent traces return
-    the zero structure with ``source`` saying why.
-    """
-    paths = sorted(glob.glob(os.path.join(trace_dir, "**",
-                                          "*.trace.json.gz"),
-                             recursive=True))
-    if not paths:
-        return empty_attribution("no-trace-files")
-    out = empty_attribution("trace")
-    for path in paths:
-        try:
-            with gzip.open(path, "rt", errors="replace") as f:
-                doc = json.load(f)
-        except (OSError, ValueError):
+def _union(intervals) -> list:
+    merged: list = []
+    for s, e in sorted(intervals):
+        if e <= s:
             continue
-        for ev in doc.get("traceEvents", ()):
-            if not isinstance(ev, dict) or ev.get("ph") != "X":
-                continue
-            dur_ms = float(ev.get("dur", 0.0)) / 1e3
-            name = str(ev.get("name", "")).lower()
-            phase = "other"
-            for p, pats in PHASE_PATTERNS:
-                if any(s in name for s in pats):
-                    phase = p
-                    break
-            out[f"{phase}_ms"] += dur_ms
-            out["total_ms"] += dur_ms
-            out["events"] += 1
-    for p in PHASES:
-        out[f"{p}_ms"] = round(out[f"{p}_ms"], 3)
-    out["total_ms"] = round(out["total_ms"], 3)
+        if merged and s <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], e)
+        else:
+            merged.append([s, e])
+    return merged
+
+
+def _overlap(a, b) -> float:
+    """Length of the intersection of two sorted disjoint interval lists."""
+    i = j = 0
+    total = 0.0
+    while i < len(a) and j < len(b):
+        total += max(0.0, min(a[i][1], b[j][1]) - max(a[i][0], b[j][0]))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+def _idle_pct(block: dict):
+    if not block["devices"] or block["window_s"] <= 0:
+        return None
+    return round(100.0 * (1.0 - block["busy_s"] / block["window_s"]), 3)
+
+
+def reduce_window(events) -> dict:
+    """A capture's device time from ``(plane, line, name, start_ns,
+    end_ns)`` events.  The window is the capture's extent (first to last
+    event); idle is its complement of the busy union, per device plane."""
+    events = list(events)
+    if not events:
+        return empty_device_time("empty-trace")
+    lo = min(e[3] for e in events)
+    hi = max(e[4] for e in events)
+    planes = sorted({e[0] for e in events if e[0].startswith("/device:")})
+    waits = [_union((e[3], e[4]) for e in events if e[2] in names)
+             for names in (EGRESS_WAIT_SPANS, INPUT_WAIT_SPANS)]
+    busy, idle = 0.0, [0.0, 0.0]
+    for p in planes:
+        on = [e for e in events if e[0] == p]
+        ops = _union((e[3], e[4]) for e in
+                     ([e for e in on if e[1] == OPS_LINE] or on))
+        busy += sum(e - s for s, e in ops)
+        for k, w in enumerate(waits):
+            idle[k] += sum(e - s for s, e in w) - _overlap(w, ops)
+    n = max(len(planes), 1)
+    out = {"window_s": (hi - lo) / 1e9, "busy_s": busy / n / 1e9,
+           "idle_wait_egress_s": idle[0] / n / 1e9,
+           "idle_wait_input_s": idle[1] / n / 1e9,
+           "devices": len(planes), "source": "trace"}
+    out["idle_pct"] = _idle_pct(out)
     return out
+
+
+def merge_device_time(blocks) -> dict:
+    """Windows (or hosts) combined: seconds sum, the idle share is
+    recomputed from the sums.  Provenance survives: 'trace' when any
+    block really reduced a trace, else 'error' when any failed (a fleet
+    whose every profiler broke must not read as one that never
+    profiled), else 'none'."""
+    blocks = [b for b in blocks if b]
+    sources = {b.get("source") for b in blocks}
+    out = empty_device_time(
+        "trace" if "trace" in sources
+        else "error" if sources & {"error", "no-trace-files", "empty-trace"}
+        else "none")
+    for b in blocks:
+        for k in SECONDS:
+            out[k] += b.get(k) or 0.0
+        out["devices"] = max(out["devices"], b.get("devices") or 0)
+    out["idle_pct"] = _idle_pct(out)
+    return out
+
+
+def window_device_time(trace_dir: str) -> dict:
+    """:func:`reduce_window` over the newest ``.xplane.pb`` of a capture
+    window, read with ``jax.profiler.ProfileData``."""
+    import jax
+
+    paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                             recursive=True), key=os.path.getmtime)
+    if not paths:
+        return empty_device_time("no-trace-files")
+    data = jax.profiler.ProfileData.from_file(paths[-1])
+    return reduce_window(
+        (plane.name, line.name, e.name, e.start_ns,
+         e.start_ns + e.duration_ns)
+        for plane in data.planes for line in plane.lines
+        for e in line.events)
 
 
 class ProfilerBusy(RuntimeError):
@@ -169,7 +223,7 @@ class DeviceProfiler:
                     self._stop.wait(info["seconds"])
                 finally:
                     jax.profiler.stop_trace()
-            info["attribution"] = attribute_phases(info["dir"])
+            info["device_time"] = window_device_time(info["dir"])
             info["trace_files"] = len(glob.glob(
                 os.path.join(info["dir"], "**", "*"), recursive=True))
             obs_metrics.counter(
@@ -179,7 +233,7 @@ class DeviceProfiler:
             # A broken profiler (unsupported backend, concurrent trace)
             # must cost the operator a diagnosable record, not the run.
             info["error"] = f"{type(e).__name__}: {e}"
-            info["attribution"] = empty_attribution("error")
+            info["device_time"] = empty_device_time("error")
             from firebird_tpu.obs import logger
             logger("change-detection").warning(
                 "device-profile window failed: %s", info["error"])
@@ -209,33 +263,15 @@ class DeviceProfiler:
     # -- reads / teardown ----------------------------------------------------
 
     def summary(self) -> dict:
-        """The report's ``profile`` block: windows so far + device-time
-        totals across them (structure matches :func:`report_block`)."""
+        """The report's ``profile`` block: windows so far + their device
+        time combined (structure matches :func:`report_block`)."""
         with self._lock:
             windows = [dict(w) for w in self._windows]
             busy = self._busy
-        # Provenance must survive aggregation: 'trace' only when a
-        # window REALLY parsed trace files — every-window-failed reports
-        # 'error' (all zeros + 'trace' would mask a broken profiler as a
-        # healthy capture that attributed nothing).
-        sources = {w.get("attribution", {}).get("source") for w in windows}
-        device_time = empty_attribution(
-            "trace" if "trace" in sources
-            else "error" if ("error" in sources
-                             or "no-trace-files" in sources)
-            else "none")
-        for w in windows:
-            a = w.get("attribution")
-            if not a:
-                continue
-            for p in PHASES:
-                device_time[f"{p}_ms"] = round(
-                    device_time[f"{p}_ms"] + a.get(f"{p}_ms", 0.0), 3)
-            device_time["total_ms"] = round(
-                device_time["total_ms"] + a.get("total_ms", 0.0), 3)
-            device_time["events"] += a.get("events", 0)
         return {"windows": windows, "in_flight": busy,
-                "device_time": device_time, "dir": self.outdir}
+                "device_time": merge_device_time(
+                    w.get("device_time") for w in windows),
+                "dir": self.outdir}
 
     def close(self, timeout: float = 10.0) -> None:
         """End any in-flight window early and collect it — called before
@@ -269,7 +305,7 @@ def active() -> DeviceProfiler | None:
 def close_active() -> None:
     """Flush an in-flight window (never raises) — obs.report.finish_run
     calls this before building the report so the artifact carries the
-    final window's attribution."""
+    final window's device time."""
     prof = _active
     if prof is not None:
         try:
@@ -284,5 +320,5 @@ def report_block() -> dict:
     prof = _active
     if prof is None:
         return {"windows": [], "in_flight": False,
-                "device_time": empty_attribution("none"), "dir": None}
+                "device_time": empty_device_time("none"), "dir": None}
     return prof.summary()

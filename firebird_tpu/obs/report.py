@@ -27,12 +27,20 @@ DRIVER_STAGE_HISTOGRAMS = (
     "pipeline_dispatch_seconds",
     "pipeline_drain_seconds",
     "pipeline_d2h_seconds",
+    "pipeline_wait_input_seconds",
+    "pipeline_wait_egress_seconds",
+    "egress_wait_device_seconds",
+    "egress_format_seconds",
+    "egress_format_cpu_seconds",
+    "store_queue_wait_seconds",
     "store_write_seconds",
+    "store_write_cpu_seconds",
     "store_flush_seconds",
     "kernel_first_call_seconds",
 )
 DRIVER_SPAN_NAMES = ("fetch", "pack", "stage", "dispatch", "drain", "d2h",
-                     "transfer")
+                     "transfer", "wait_input", "wait_egress", "wait_device",
+                     "format", "queue_wait")
 
 # THE span-name catalog: every tracing.span(...) call site in the
 # codebase must use a name declared here, and every declared name must
@@ -51,15 +59,20 @@ SPAN_NAMES = (
     "fetch",
     "first_dispatch",
     "fleet_job",
+    "format",
     "pack",
     "probe_cycle",
     "profile",
     "publish",
+    "queue_wait",
     "stage",
     "step",
     "store_flush",
     "store_write",
     "transfer",
+    "wait_device",
+    "wait_egress",
+    "wait_input",
     "warm_compile",
     "watch_poll",
 )
@@ -77,7 +90,7 @@ def build_report(*, registry=None, tracer=None, run: dict | None = None,
     metrics = reg.snapshot()
     # SLO + device-profile blocks are structurally ALWAYS present (the
     # obs-smoke contract): no-data objectives report ok=null, a run
-    # without profile windows reports the zero attribution.
+    # without profile windows reports zero device time.
     st = obs_server.current()
     wd_snap = None
     spec = None
@@ -288,31 +301,17 @@ def merge_reports(reports: list[dict]) -> dict:
              if r.get("slo")]
     out["slo"] = slomod.evaluate_snapshot(
         out["metrics"], spec=specs[0] if specs else None)
-    # Device-profile attribution sums across hosts; windows concatenate
-    # (each already names its host-local artifact directory).
+    # Device-profile seconds sum across hosts (the idle share is
+    # recomputed from the sums); windows concatenate (each already names
+    # its host-local artifact directory).
     from firebird_tpu.obs import profiling
 
-    prof = {"windows": [], "in_flight": False,
-            "device_time": profiling.empty_attribution("none"), "dir": None}
-    sources = set()
-    for r in reports:
-        p = r.get("profile")
-        if not p:
-            continue
-        prof["windows"].extend(p.get("windows", ()))
-        dt = p.get("device_time") or {}
-        sources.add(dt.get("source"))
-        for k, v in dt.items():
-            if isinstance(v, (int, float)):
-                prof["device_time"][k] = round(
-                    prof["device_time"].get(k, 0) + v, 3)
-    # Shard provenance survives the merge: any real capture -> 'trace';
-    # otherwise any failed shard -> 'error' (a fleet whose every
-    # profiler broke must not read as one that never profiled).
-    if "trace" in sources:
-        prof["device_time"]["source"] = "trace"
-    elif "error" in sources:
-        prof["device_time"]["source"] = "error"
+    profs = [r["profile"] for r in reports if r.get("profile")]
+    prof = {"windows": [w for p in profs for w in p.get("windows", ())],
+            "in_flight": False,
+            "device_time": profiling.merge_device_time(
+                p.get("device_time") for p in profs),
+            "dir": None}
     out["profile"] = prof
     rcs = [r["run_counters"] for r in reports if r.get("run_counters")]
     if rcs:
@@ -440,7 +439,7 @@ def finish_run(cfg, *, tracer=None, run: dict | None = None,
 
     log = logger("change-detection")
     # Flush any in-flight device-profile window FIRST so the report's
-    # profile block carries its attribution (never raises).
+    # profile block carries its device time (never raises).
     profiling.close_active()
     out = {}
     # Independent try blocks: an unwritable trace path must not also

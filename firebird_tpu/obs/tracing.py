@@ -7,10 +7,19 @@ prefetch/pack/dispatch/drain overlap is visually inspectable — the
 host-orchestration counterpart of the XLA trace ``profile_dir`` captures
 (driver/core.py).
 
-Disabled cost is one module-attribute read and a ``None`` check per span:
-no tracer installed means ``span()`` returns a shared no-op context
-manager and records nothing.  Enable per run with FIREBIRD_TRACE (see
-resolve_path) or programmatically via ``start()``/``stop()``.
+Disabled cost is a few module-attribute reads and one
+``TraceAnnotation.is_enabled()`` call per span: with no sink on, a span
+without a histogram is a shared no-op context manager and records
+nothing.  Enable per run with FIREBIRD_TRACE (see resolve_path) or
+programmatically via ``start()``/``stop()``.
+
+While the jax profiler records (``FIREBIRD_PROFILE_DIR``, a ``/profile``
+window, any ``jax.profiler.start_trace``), every span is also a
+``jax.profiler.TraceAnnotation`` named ``firebird.<span name>``, so the
+program's stages land in the ``.xplane.pb`` on the same clock as the
+device's operations.  A span given a ``histogram`` observes its own
+duration on exit (and ``cpu_histogram`` the thread's CPU seconds over
+the same interval): one timing per stage, recorded with tracing off too.
 
 Export is the Chrome trace-event JSON format (``{"traceEvents": [...]}``,
 "X" complete events with microsecond timestamps) — loads directly in
@@ -24,6 +33,7 @@ import dataclasses
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 
@@ -152,29 +162,42 @@ def exemplar() -> dict | None:
 
 
 class _Span:
-    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ctx")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_c0", "_ctx",
+                 "_hist", "_cpu_hist", "_note", "elapsed")
 
-    def __init__(self, tracer: "Tracer | None", name: str, args: dict):
+    def __init__(self, tracer: "Tracer | None", name: str, args: dict,
+                 hist=None, cpu_hist=None, note=None):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._hist = hist
+        self._cpu_hist = cpu_hist
+        self._note = note
 
     def __enter__(self):
         self._ctx = _tls.ctx
+        if self._note is not None:
+            self._note.__enter__()
+        if self._cpu_hist is not None:
+            self._c0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        dur = time.perf_counter() - self._t0
+        dur = self.elapsed = time.perf_counter() - self._t0
+        if self._cpu_hist is not None:
+            cpu = time.thread_time() - self._c0
+        if self._note is not None:
+            self._note.__exit__(None, None, None)
         sid = next(_span_ids)
         _tls.last_span_id = sid
-        args = self._args
         ctx = self._ctx
-        if ctx is not None:
-            args = dict(args, batch=ctx.batch_id, span_id=sid)
-        else:
-            args = dict(args, span_id=sid) if args else {"span_id": sid}
         if self._tracer is not None:
+            args = self._args
+            if ctx is not None:
+                args = dict(args, batch=ctx.batch_id, span_id=sid)
+            else:
+                args = dict(args, span_id=sid) if args else {"span_id": sid}
             self._tracer._record(self._name, self._t0, dur, args)
         rec = _recorder
         if rec is not None:
@@ -184,6 +207,14 @@ class _Span:
         if sp is not None:
             sp.span_event(self._name, dur,
                           ctx.batch_id if ctx is not None else None)
+        # After the span id is set, so the exemplars name this span; a
+        # span left by an exception observes nothing (a failed stage is
+        # not a latency sample).
+        if exc[0] is None:
+            if self._hist is not None:
+                self._hist.observe(dur)
+            if self._cpu_hist is not None:
+                self._cpu_hist.observe(cpu)
         return False
 
 
@@ -231,7 +262,7 @@ class Tracer:
             self._events.append(ev)
 
     def span(self, name: str, **args) -> _Span:
-        return _Span(self, name, args)
+        return _Span(self, name, args, note=_profiler_note(name))
 
     def to_chrome_trace(self) -> dict:
         with self._lock:
@@ -332,13 +363,33 @@ def stop() -> Tracer | None:
     return t
 
 
-def span(name: str, **args):
-    """A span against the active tracer (and the armed flight recorder
-    and telemetry spool); a shared no-op when all three are off."""
+def _profiler_note(name: str):
+    """A ``TraceAnnotation`` named ``firebird.<name>`` while the jax
+    profiler records, else None.  jax is looked up in ``sys.modules``
+    only: a process that never imported jax runs no profiler, and this
+    module stays free of jax imports."""
+    ta = getattr(getattr(sys.modules.get("jax"), "profiler", None),
+                 "TraceAnnotation", None)
+    if ta is None or not ta.is_enabled():
+        return None
+    return ta("firebird." + name)
+
+
+def span(name: str, *, histogram=None, cpu_histogram=None, **args):
+    """A span against the active tracer, the armed flight recorder and
+    telemetry spool, and the jax profiler when it records.
+
+    ``histogram`` (an ``obs.metrics`` Histogram) observes the span's wall
+    seconds on exit, ``cpu_histogram`` the calling thread's CPU seconds
+    (``time.thread_time``) over the same interval; both are recorded
+    whether or not any trace sink is on.  A shared no-op when every sink
+    is off and no histogram is given."""
     t = _active
-    if t is None and _recorder is None and _spool is None:
+    note = _profiler_note(name)
+    if t is None and _recorder is None and _spool is None and note is None \
+            and histogram is None and cpu_histogram is None:
         return _NULL_SPAN
-    return _Span(t, name, args)
+    return _Span(t, name, args, histogram, cpu_histogram, note)
 
 
 def wants_trace(trace: str) -> bool:

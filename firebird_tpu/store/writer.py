@@ -77,19 +77,23 @@ class AsyncWriter:
                     # queue item: this write's span, exemplar, and any
                     # log line parent to the BATCH that produced the
                     # frame, not to an anonymous writer thread.  The
-                    # observe stays INSIDE the activation so the
-                    # histogram exemplar sees the batch id.
+                    # span observes INSIDE the activation so the
+                    # histogram exemplar sees the batch id.  Wall minus
+                    # CPU seconds is the time the writer waited inside
+                    # the backend: on the GIL or on the disk.
                     with tracing.activate(ctx):
-                        with tracing.span("store_write", table=table), \
-                                obs_metrics.timer() as tm:
+                        with tracing.span(
+                                "store_write", table=table,
+                                histogram=obs_metrics.histogram(
+                                    "store_write_seconds"),
+                                cpu_histogram=obs_metrics.histogram(
+                                    "store_write_cpu_seconds")):
                             if self.retry is not None:
                                 self.retry.run(
                                     log, f"store write to {table}",
                                     lambda: self.store.write(table, frame))
                             else:
                                 self.store.write(table, frame)
-                        obs_metrics.histogram(
-                            "store_write_seconds").observe(tm.elapsed)
                     obs_metrics.counter(
                         "store_rows_written",
                         help="rows landed in the results store").inc(
@@ -146,15 +150,19 @@ class AsyncWriter:
             raise err
         self._check_alive()
         i = (hash(key) if key is not None else next(self._rr)) % len(self._qs)
-        self._qs[i].put((table, frame, tracing.current_context()))
+        # Writer backpressure: the caller blocks here while the queue is
+        # full.
+        with tracing.span("queue_wait", histogram=obs_metrics.histogram(
+                "store_queue_wait_seconds")):
+            self._qs[i].put((table, frame, tracing.current_context()))
         self._update_depth()
 
     def flush(self) -> None:
         self._check_alive()
-        with tracing.span("store_flush"), obs_metrics.timer() as tm:
+        with tracing.span("store_flush", histogram=obs_metrics.histogram(
+                "store_flush_seconds")):
             for q in self._qs:
                 q.join()
-        obs_metrics.histogram("store_flush_seconds").observe(tm.elapsed)
         # Authoritative sweep AFTER the joins and BEFORE any raise: all
         # acks happened-before this point, so even if worker-side updates
         # interleaved badly the gauge lands at the true (empty) depth on

@@ -4,7 +4,6 @@ obs/flightrec.py + the TraceContext plumbing in obs/tracing.py,
 obs/metrics.py exemplars, obs/jsonlog.py, and the drivers)."""
 
 import glob
-import gzip
 import json
 import logging
 import os
@@ -253,36 +252,52 @@ def test_slo_reevaluated_over_merged_fleet_reports(fresh_metrics):
 # Device profiling
 # ---------------------------------------------------------------------------
 
-def _write_trace(dirpath, events):
-    os.makedirs(dirpath, exist_ok=True)
-    with gzip.open(os.path.join(dirpath, "host.trace.json.gz"), "wt") as f:
-        json.dump({"traceEvents": events}, f)
+DEV, HOST = "/device:TPU:0", "/host:CPU"
 
 
-def test_attribution_buckets_by_kernel_name(tmp_path):
-    _write_trace(str(tmp_path / "plugins" / "profile" / "x"), [
-        {"ph": "X", "name": "fused_lasso_cd_kernel", "dur": 2000.0},
-        {"ph": "X", "name": "monitor_chain_scored", "dur": 1000.0},
-        {"ph": "X", "name": "compact_scatter_prefix", "dur": 500.0},
-        {"ph": "X", "name": "mystery_op", "dur": 250.0},
-        {"ph": "B", "name": "not_complete", "dur": 9e9},   # skipped
+def _ev(plane, line, name, start_ms, end_ms):
+    return (plane, line, name, start_ms * 1e6, end_ms * 1e6)
+
+
+def test_device_time_idle_under_the_dispatch_waits():
+    """Busy is the union of the device's ops; idle is put down to the
+    dispatch thread's egress waits (wait_egress + store_flush) and input
+    waits only where the device had nothing running."""
+    a = profiling.reduce_window([
+        _ev(HOST, "python", "firebird.wait_input", 0, 10),    # 10 ms idle
+        _ev(DEV, "XLA Ops", "fusion.1", 10, 40),
+        _ev(DEV, "XLA Ops", "fusion.2", 20, 30),              # nested
+        _ev(HOST, "python", "firebird.wait_egress", 30, 60),  # 20 ms idle
+        _ev(DEV, "XLA Modules", "jit_detect", 10, 40),        # not an op
+        _ev(DEV, "XLA Ops", "fusion.3", 70, 80),
+        _ev(HOST, "python", "firebird.store_flush", 75, 100),  # 20 ms idle
+        _ev(HOST, "drain", "firebird.format", 80, 100),        # not a wait
     ])
-    a = profiling.attribute_phases(str(tmp_path))
-    assert a["source"] == "trace" and a["events"] == 4
-    assert a["fit_ms"] == 2.0 and a["monitor_ms"] == 1.0
-    assert a["compaction_ms"] == 0.5 and a["other_ms"] == 0.25
-    assert a["total_ms"] == 3.75
+    assert a["source"] == "trace" and a["devices"] == 1
+    assert a["window_s"] == pytest.approx(0.1)
+    assert a["busy_s"] == pytest.approx(0.04)
+    assert a["idle_pct"] == pytest.approx(60.0)
+    assert a["idle_wait_egress_s"] == pytest.approx(0.04)
+    assert a["idle_wait_input_s"] == pytest.approx(0.01)
+    merged = profiling.merge_device_time([a, a])
+    assert merged["window_s"] == pytest.approx(0.2)
+    assert merged["idle_wait_egress_s"] == pytest.approx(0.08)
+    assert merged["idle_pct"] == pytest.approx(60.0)
 
 
-def test_attribution_zero_structure_when_no_trace(tmp_path):
-    a = profiling.attribute_phases(str(tmp_path))
-    assert a["source"] == "no-trace-files" and a["total_ms"] == 0.0
-    assert set(f"{p}_ms" for p in profiling.PHASES) < set(a)
+def test_device_time_zero_structure_when_no_trace(tmp_path):
+    a = profiling.window_device_time(str(tmp_path))
+    assert a["source"] == "no-trace-files" and a["busy_s"] == 0.0
+    assert a["idle_pct"] is None
+    assert set(profiling.SECONDS) < set(a)
+    # A host-only trace (the CPU backend) has no device to be idle.
+    host = profiling.reduce_window([_ev(HOST, "python", "x", 0, 5)])
+    assert host["devices"] == 0 and host["idle_pct"] is None
 
 
 def test_profiler_window_real_capture(tmp_path, fresh_metrics):
     """A real (tiny) jax.profiler window on the CPU backend: artifact
-    files land under window_00/ and the summary carries attribution —
+    files land under window_00/ and the summary carries device time —
     the POST /profile acceptance path minus HTTP."""
     import jax.numpy as jnp
 
@@ -292,11 +307,13 @@ def test_profiler_window_real_capture(tmp_path, fresh_metrics):
     info = prof.window(0.05, block=True)
     assert "error" not in info, info
     assert info["trace_files"] >= 1
-    assert glob.glob(os.path.join(info["dir"], "**", "*.trace.json.gz"),
+    assert glob.glob(os.path.join(info["dir"], "**", "*.xplane.pb"),
                      recursive=True)
     s = prof.summary()
     assert len(s["windows"]) == 1 and not s["in_flight"]
-    assert set(f"{p}_ms" for p in profiling.PHASES) < set(s["device_time"])
+    dt = s["device_time"]
+    assert set(profiling.empty_device_time()) == set(dt)
+    assert dt["source"] == "trace" and dt["window_s"] > 0
     assert obs_metrics.counter("profile_windows").value == 1
 
 
@@ -320,7 +337,7 @@ def test_profile_report_block_always_structured():
     block = profiling.report_block()
     assert block["windows"] == [] and block["in_flight"] is False
     assert block["device_time"]["source"] == "none"
-    assert block["device_time"]["total_ms"] == 0.0
+    assert block["device_time"]["busy_s"] == 0.0
 
 
 def test_auto_window_armed_fires_once(tmp_path, monkeypatch):

@@ -132,6 +132,144 @@ def test_span_noop_when_disabled():
         assert s is tracing._NULL_SPAN
 
 
+def test_span_is_the_shared_noop_with_jax_imported_and_no_profiler():
+    """jax imported but not recording, no tracer/recorder/spool: still the
+    shared no-op — the profiler check costs no allocation."""
+    import jax
+
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert tracing.active() is None and tracing._recorder is None \
+        and tracing._spool is None
+    assert tracing.span("drain", chips=3) is tracing._NULL_SPAN
+
+
+def test_span_histogram_times_itself_with_every_sink_off():
+    """One span, one timing: a span given histograms observes its wall
+    and thread-CPU seconds on exit with tracing off, and a span left by
+    an exception observes nothing."""
+    obs_metrics.reset_registry()
+    wall = obs_metrics.histogram("egress_format_seconds")
+    cpu = obs_metrics.histogram("egress_format_cpu_seconds")
+    with tracing.span("format", histogram=wall, cpu_histogram=cpu) as sp:
+        sum(range(200_000))
+    assert sp is not tracing._NULL_SPAN
+    w, c = wall.snapshot(), cpu.snapshot()
+    assert w["count"] == c["count"] == 1
+    assert w["sum"] == sp.elapsed > 0
+    assert 0 <= c["sum"] <= w["sum"] + 1e-3
+    with pytest.raises(RuntimeError):
+        with tracing.span("format", histogram=wall):
+            raise RuntimeError("boom")
+    assert wall.snapshot()["count"] == 1
+    obs_metrics.reset_registry()
+
+
+# The drain's parts, each a span of its own inside ``drain``.
+DRAIN_PARTS = ("egress_wait_device_seconds", "pipeline_d2h_seconds",
+               "egress_format_seconds", "store_queue_wait_seconds")
+# span name -> the histogram it observes
+SPAN_HISTOGRAMS = {
+    "wait_input": "pipeline_wait_input_seconds",
+    "wait_egress": "pipeline_wait_egress_seconds",
+    "wait_device": "egress_wait_device_seconds",
+    "format": "egress_format_seconds",
+    "queue_wait": "store_queue_wait_seconds",
+    "drain": "pipeline_drain_seconds",
+    "store_write": "store_write_seconds",
+    "store_flush": "store_flush_seconds",
+}
+
+
+@pytest.fixture(scope="module")
+def profiled_chunk(tmp_path_factory):
+    """A two-batch run_chunk of tiny (10x10 px) chips under the jax
+    profiler on the CPU backend, with the span tracer on too: (xplane
+    events, tracer, registry snapshot)."""
+    import glob
+    import os
+
+    import jax
+
+    from firebird_tpu import grid
+    from firebird_tpu.ccd.sensor import SENSORS
+    from firebird_tpu.config import Config
+    from firebird_tpu.driver import core
+    from firebird_tpu.ingest import SyntheticSource
+    from firebird_tpu.store import MemoryStore
+
+    cfg = Config(store_backend="memory", source_backend="synthetic",
+                 chips_per_batch=1, dtype="float32", device_sharding="off",
+                 fetch_retries=0, pipeline_depth=2)
+    src = SyntheticSource(seed=9, start="1995-01-01", end="1998-01-01",
+                          sensor=SENSORS["landsat-ard-tiny"])
+    source, _, writer, policy, _, quarantine = core.robustness_setup(
+        cfg, "obs-test", source=src, store=MemoryStore("obs-test"))
+    cids = list(grid.chips(grid.tile(x=100, y=200)))[:2]
+    chunk = dict(source=source, writer=writer,
+                 acquired="1995-01-01/1997-06-01", cfg=cfg,
+                 counters=obs.Counters(),
+                 log=obs.logger("change-detection"), policy=policy,
+                 quarantine=quarantine, reraise=True)
+    core.run_chunk(cids[:1], **chunk)          # compile outside the trace
+    obs_metrics.reset_registry()
+    trace_dir = str(tmp_path_factory.mktemp("xplane"))
+    tracer = tracing.start()
+    jax.profiler.start_trace(trace_dir)
+    try:
+        done = core.run_chunk(cids, **chunk)
+    finally:
+        jax.profiler.stop_trace()
+        tracing.stop()
+        snap = obs_metrics.get_registry().snapshot()
+        writer.close()
+        obs_metrics.reset_registry()
+    assert len(done) == 2
+    path = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    data = jax.profiler.ProfileData.from_file(path)
+    events = [(plane.name, e.name, e.duration_ns / 1e9)
+              for plane in data.planes for line in plane.lines
+              for e in line.events if e.name.startswith("firebird.")]
+    return events, tracer, snap
+
+
+def test_program_spans_land_on_the_profiler_clock(profiled_chunk):
+    events, _, _ = profiled_chunk
+    names = {n for plane, n, _ in events if plane.startswith("/host:")}
+    assert {"firebird." + s for s in SPAN_HISTOGRAMS} <= names, names
+    # the pre-existing stage spans ride along
+    assert {"firebird.fetch", "firebird.pack", "firebird.stage",
+            "firebird.dispatch", "firebird.d2h",
+            "firebird.transfer"} <= names, names
+
+
+@pytest.mark.parametrize("span", sorted(SPAN_HISTOGRAMS))
+def test_span_histograms_match_the_spans(profiled_chunk, span):
+    """Each histogram observes exactly its span's interval: the same
+    count and seconds as the tracer's events (the same two clock reads),
+    and no more than the profiler's annotation around them."""
+    events, tracer, snap = profiled_chunk
+    h = snap["histograms"][SPAN_HISTOGRAMS[span]]
+    spans = [e["dur"] / 1e6 for e in tracer.to_chrome_trace()["traceEvents"]
+             if e.get("ph") == "X" and e["name"] == span]
+    assert h["count"] == len(spans) > 0
+    assert h["sum"] == pytest.approx(sum(spans), rel=1e-6, abs=1e-9)
+    xp = [d for _, n, d in events if n == "firebird." + span]
+    assert len(xp) == h["count"]
+    assert sum(xp) >= h["sum"] - 1e-4
+
+
+def test_drain_parts_fit_inside_the_drain(profiled_chunk):
+    _, _, snap = profiled_chunk
+    hists = snap["histograms"]
+    parts = sum(hists[k]["sum"] for k in DRAIN_PARTS)
+    assert 0 < parts <= hists["pipeline_drain_seconds"]["sum"]
+    assert hists["egress_format_cpu_seconds"]["count"] == \
+        hists["egress_format_seconds"]["count"]
+    assert hists["store_write_cpu_seconds"]["count"] == \
+        hists["store_write_seconds"]["count"]
+
+
 # ---------------------------------------------------------------------------
 # Metrics registry
 # ---------------------------------------------------------------------------

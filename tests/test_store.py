@@ -160,6 +160,37 @@ def test_queue_depth_gauge_drains_on_flush_failure():
     assert obs_metrics.gauge("store_queue_depth").value == 0
 
 
+def test_full_queue_backpressure_and_writer_cpu_are_measured():
+    """A slow backend behind a one-frame queue: the caller's puts wait
+    (store_queue_wait_seconds > 0, one observation per write), and the
+    writer's CPU seconds inside the backend are at most its wall seconds
+    (a sleeping backend is nearly all wait)."""
+    import time
+
+    from firebird_tpu.obs import metrics as obs_metrics
+
+    obs_metrics.reset_registry()
+
+    class Slow(MemoryStore):
+        def write(self, table, frame):
+            time.sleep(0.02)
+            return super().write(table, frame)
+
+    w = AsyncWriter(Slow(), max_queue=1)
+    for i in range(5):
+        w.write("chip", {"cx": [i], "cy": [0], "dates": [["1999-01-01"]]})
+    w.flush()
+    w.close()
+    snap = obs_metrics.get_registry().snapshot()["histograms"]
+    obs_metrics.reset_registry()
+    qw = snap["store_queue_wait_seconds"]
+    assert qw["count"] == 5 and qw["sum"] > 0.01
+    wall, cpu = snap["store_write_seconds"], snap["store_write_cpu_seconds"]
+    assert wall["count"] == cpu["count"] == 5
+    assert cpu["sum"] <= wall["sum"]
+    assert wall["sum"] >= 5 * 0.02
+
+
 def test_async_writer_drains_and_raises(tmp_path):
     store = MemoryStore()
     w = AsyncWriter(store)

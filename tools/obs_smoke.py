@@ -11,9 +11,10 @@ obs_report.json must pass ``validate_report`` and carry every
 DRIVER_STAGE_HISTOGRAMS stage key; and the live ``/progress`` chip
 totals must agree with the final report.  The deep-dive layer rides the
 same run: one ``POST /profile?seconds=N`` window is captured mid-run and
-must leave a device-trace artifact + per-phase attribution in the
-report's ``profile`` block (zeros allowed on the CPU backend, structure
-always present), ``/slo`` must answer live, and the report's ``slo``
+must leave a device-trace artifact + its device-time reduction (busy,
+idle share, idle under the dispatch thread's waits) in the report's
+``profile`` block (zeros allowed on the CPU backend, structure always
+present), ``/slo`` must answer live, and the report's ``slo``
 block must have evaluated the batch objective against real data.  Exits
 non-zero on any violation — the CI-greppable proof that the telemetry layer still wires
 through every pipeline stage and that the live ops surface serves during
@@ -100,7 +101,7 @@ def main() -> int:
         # Poll the live surface while the run is in flight; keep the last
         # good sample of each endpoint.  As soon as the endpoint answers,
         # fire ONE windowed device-profile capture (POST /profile) so the
-        # final report must carry its attribution — the on-demand
+        # final report must carry its device time — the on-demand
         # profiling acceptance path.
         live: dict = {}
         posted: dict = {}
@@ -161,12 +162,12 @@ def main() -> int:
             print(f"obs-smoke: report profile block has no windows: {prof}",
                   file=sys.stderr)
             return 1
-        from firebird_tpu.obs.profiling import PHASES
+        from firebird_tpu.obs.profiling import empty_device_time
         dt = prof.get("device_time") or {}
-        missing = [f"{p}_ms" for p in PHASES if f"{p}_ms" not in dt]
-        if missing or "total_ms" not in dt:
-            print(f"obs-smoke: device_time attribution incomplete "
-                  f"(missing {missing}): {dt}", file=sys.stderr)
+        missing = [k for k in empty_device_time() if k not in dt]
+        if missing or dt.get("source") != "trace":
+            print(f"obs-smoke: device_time incomplete (missing {missing}, "
+                  f"source {dt.get('source')!r}): {dt}", file=sys.stderr)
             return 1
         win = prof["windows"][0]
         if "error" in win or not os.path.isdir(win["dir"]) \
@@ -216,7 +217,8 @@ def main() -> int:
               f"live progress {prog['chips_done']}/{prog['chips_total']} "
               f"chips at stage {prog['stage']!r}, "
               f"profile window {win['trace_files']} trace files "
-              f"({dt['total_ms']:.1f} device-ms attributed), "
+              f"({dt['window_s']:.3f} s window, {dt['devices']} device "
+              f"planes), "
               f"slo ok={slo_rep['ok']}")
     return 0
 
