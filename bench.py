@@ -788,20 +788,23 @@ def measure() -> None:
                 packed, c, kernel.chip_slice(seg, c, to_host=True))
         drain_per_chip_s = time.time() - t0
         # Int-coded egress (the d2h wire diet, kernel.pack_egress):
-        # pack on device to int tables sliced to the observed segment
-        # depth, fetch, decode — bytes + wall vs the raw f32 fetch
-        # above.  The decoded result is store-row identical (the golden
-        # test in tests/test_wire.py); here we report the wire cut.
+        # pack on device to int tables, one buffer per segment slot,
+        # fetch the slots to the observed segment depth, decode — bytes
+        # + wall vs the raw f32 fetch above.  The decoded result is
+        # store-row identical (the golden test in tests/test_wire.py);
+        # here we report the wire cut.
         d2h_raw = int(sum(v.nbytes
                           for v in jax.tree_util.tree_leaves(seg)))
         worst = int(np.asarray(seg.n_segments).max())
         s_eff = kernel.egress_bucket(worst, host_seg.seg_meta.shape[-2])
-        jax.block_until_ready(kernel.pack_egress(seg, s_eff))  # compile
+        jax.block_until_ready(kernel.pack_egress(seg))  # compile
         t0 = time.time()
-        tables = jax.device_get(kernel.pack_egress(seg, s_eff))
+        tables = jax.device_get(kernel.egress_slots(kernel.pack_egress(seg),
+                                                    s_eff))
         ccdformat.decode_egress(tables, host_seg.mask.shape[-1])
         drain_packed_s = time.time() - t0
-        d2h_packed = int(sum(v.nbytes for v in tables.values()))
+        d2h_packed = int(sum(v.nbytes
+                             for v in jax.tree_util.tree_leaves(tables)))
         obs_metrics.histogram("pipeline_drain_seconds").observe(
             drain_fetch_s + drain_fmt_s)
         pipeline_detail = {"pipeline": {

@@ -69,19 +69,22 @@ def format_records(cx, cy, px, py, dates, ccdresult) -> list[dict]:
 def decode_egress(tables: dict, T: int):
     """Host-fetched int egress tables -> a float32 host ChipSegments,
     bit-exact against the raw f32 drain (the kernel.pack_egress coding
-    contract): integer meta columns widen exactly (< 2^24), the
+    contract): the segment planes' fetched slot buffers stack back onto
+    the slot axis, integer meta columns widen exactly (< 2^24), the
     count-coded chprob column re-runs the kernel's own f32 division,
-    the bitcast planes reinterpret in place (zero-copy views), and the
-    bitpacked mask unpacks to ``T`` columns.  Segment planes come back
-    at the PACKED depth ``s_eff`` — every consumer reads capacity from
-    ``seg_meta.shape[-2]``, and the drain's capacity probe guarantees no
-    pixel closed more than ``s_eff`` segments, so frames are identical
-    to the full-capacity result."""
+    the bitcast planes reinterpret in place (views), and the bitpacked
+    mask unpacks to ``T`` columns.  Segment planes come back at the
+    FETCHED depth ``s_eff`` (the number of slot buffers) — every
+    consumer reads capacity from ``seg_meta.shape[-2]``, and the drain's
+    capacity probe guarantees no pixel closed more than ``s_eff``
+    segments, so frames are identical to the full-capacity result."""
     from firebird_tpu.ccd import kernel as _kernel
 
+    plane = lambda k: np.stack(
+        [np.asarray(a, np.int32) for a in tables[k]], axis=2)
     f32 = lambda a: np.ascontiguousarray(
         np.asarray(a, np.int32)).view(np.float32)
-    meta_i = np.asarray(tables["meta"], np.int32)
+    meta_i = plane("meta")
     meta = meta_i.astype(np.float32)
     meta[..., 3] = meta_i[..., 3].astype(np.float32) \
         / np.float32(params.PEEK_SIZE)
@@ -93,8 +96,8 @@ def decode_egress(tables: dict, T: int):
     vario = f32(tables["vario"]) if "vario" in tables else None
     return _kernel.ChipSegments(
         n_segments=np.asarray(tables["n_segments"]),
-        seg_meta=meta, seg_rmse=f32(tables["rmse"]),
-        seg_mag=f32(tables["mag"]), seg_coef=f32(tables["coef"]),
+        seg_meta=meta, seg_rmse=f32(plane("rmse")),
+        seg_mag=f32(plane("mag")), seg_coef=f32(plane("coef")),
         mask=mask, procedure=np.asarray(tables["procedure"]),
         rounds=opt["rounds"], vario=vario,
         round_counts=opt["round_counts"], occupancy=opt["occupancy"],

@@ -2274,7 +2274,8 @@ def detect_packed(packed, dtype=jnp.float32,
 # "Wire budget").  ChipSegments drains as float32 planes sized for the
 # WORST-CASE segment capacity; the store's row values are integers or
 # exact functions of the f32 bits, so the drain can cross the wire as
-# integer tables sliced to the batch's OBSERVED segment depth — decoded
+# integer tables, packed at capacity one buffer per segment slot and
+# fetched only to the batch's OBSERVED segment depth — decoded
 # bit-exactly on the host (ccd.format.decode_egress), store rows
 # byte-identical to the raw-f32 drain (tests/test_wire.py golden).
 # ---------------------------------------------------------------------------
@@ -2282,7 +2283,7 @@ def detect_packed(packed, dtype=jnp.float32,
 def wire_egress_enabled() -> bool:
     """Whether batch drains cross d2h as int-coded tables
     (FIREBIRD_WIRE_EGRESS, default on; f32 results only — the f64
-    bit-parity path keeps the raw drain).  Read per drain, not per
+    bit-parity path keeps the raw drain).  Read per batch, not per
     trace: the packing program is a separate jit."""
     from firebird_tpu.config import env_knob
 
@@ -2290,46 +2291,51 @@ def wire_egress_enabled() -> bool:
 
 
 def egress_bucket(worst: int, S: int) -> int:
-    """The packed egress segment depth: the observed deepest pixel's
-    close count rounded up to a power of two (few compiled packing
-    shapes), capped at the result buffers' capacity ``S``."""
+    """The segment depth a drain fetches: the observed deepest pixel's
+    close count rounded up to a power of two (the wire budget's depth
+    buckets, docs/ROOFLINE.md), capped at the result buffers' capacity
+    ``S``."""
     w = max(int(worst), 1)
     return min(1 << (w - 1).bit_length(), S)
 
 
-@functools.partial(jax.jit, static_argnames=("s_eff",))
-def pack_egress(seg: ChipSegments, s_eff: int) -> dict:
-    """Device-side egress packing of a batched f32 ChipSegments: every
-    table integer-dtyped, segment planes sliced to ``s_eff`` slots.
+# The pack_egress tables that hold one device buffer per segment slot.
+EGRESS_SLOT_PLANES = ("meta", "rmse", "mag", "coef")
+
+
+@jax.jit
+def pack_egress(seg: ChipSegments) -> dict:
+    """Device-side egress packing of a batched f32 ChipSegments at its
+    full segment capacity ``S``: every table integer-dtyped, and each
+    segment plane (:data:`EGRESS_SLOT_PLANES`) a tuple of ``S`` per-slot
+    buffers ``[C, P, ...]``.  One compiled shape per result shape; a
+    drain fetches the first :func:`egress_bucket` slots as whole buffers
+    (:func:`egress_slots`), so fetching enqueues no device program.
 
     Codings (all lossless — the golden test requires store rows
     byte-identical to the raw f32 drain):
 
-    - ``meta`` [C,P,s_eff,6] int32: sday/eday/bday/curqa/nobs are exact
+    - ``meta`` slots [C,P,6] int32: sday/eday/bday/curqa/nobs are exact
       small integers in f32 (ordinal days < 2^24), rint-coded; the
       chprob column is count-coded as ``rint(chprob * PEEK_SIZE)`` —
       chprob is always k/PEEK_SIZE or 1.0, and the host decode re-runs
       the same f32 division the kernel performed, reproducing the f32
       value bit-exactly.
-    - ``rmse``/``mag``/``coef``/``vario``: f32 bitcast to int32 (free,
-      and it keeps the d2h contract checkable: no float leaves).
+    - ``rmse``/``mag``/``coef`` slots and ``vario``: f32 bitcast to int32
+      (free, and it keeps the d2h contract checkable: no float leaves).
     - ``mask``: bitpacked along T (8x).
     - counters/diagnostics (n_segments, procedure, rounds, round_counts,
       occupancy, compactions) are already integer and pass through.
-
-    ``s_eff`` (static; :func:`egress_bucket` of the drain's capacity
-    probe) is what buys the big cut: the f32 drain ships S=10 slots per
-    pixel while the observed depth is typically 1-2.
     """
-    sl = lambda a: a[:, :, :s_eff]
+    S = seg.seg_meta.shape[-2]
+    slots = lambda a: tuple(a[:, :, s] for s in range(S))
     bc = lambda a: lax.bitcast_convert_type(a, jnp.int32)
-    meta = sl(seg.seg_meta)
-    meta_i = jnp.rint(meta).astype(jnp.int32)
+    meta_i = jnp.rint(seg.seg_meta).astype(jnp.int32)
     meta_i = meta_i.at[..., 3].set(
-        jnp.rint(meta[..., 3] * params.PEEK_SIZE).astype(jnp.int32))
+        jnp.rint(seg.seg_meta[..., 3] * params.PEEK_SIZE).astype(jnp.int32))
     out = dict(n_segments=seg.n_segments, procedure=seg.procedure,
-               meta=meta_i, rmse=bc(sl(seg.seg_rmse)),
-               mag=bc(sl(seg.seg_mag)), coef=bc(sl(seg.seg_coef)),
+               meta=slots(meta_i), rmse=slots(bc(seg.seg_rmse)),
+               mag=slots(bc(seg.seg_mag)), coef=slots(bc(seg.seg_coef)),
                mask=jnp.packbits(seg.mask, axis=-1))
     for f in ("rounds", "round_counts", "occupancy", "compactions",
               "lanes_migrated"):
@@ -2339,6 +2345,14 @@ def pack_egress(seg: ChipSegments, s_eff: int) -> dict:
     if seg.vario is not None:
         out["vario"] = bc(seg.vario)
     return out
+
+
+def egress_slots(tables: dict, s_eff: int) -> dict:
+    """The :func:`pack_egress` tables a drain fetches: the first ``s_eff``
+    slot buffers of each segment plane, every other table whole.  Picks
+    buffers; runs nothing on the device."""
+    return {k: (v[:s_eff] if k in EGRESS_SLOT_PLANES else v)
+            for k, v in tables.items()}
 
 
 def chip_slice(seg: ChipSegments, c: int, to_host: bool = False) -> ChipSegments:

@@ -808,14 +808,45 @@ def detect_batch(packed, dtype, sharding: str = "auto",
     return detect_sharded(padded, mesh, dtype=dtype, **kw), real
 
 
-def segment_depth(seg) -> int:
+@dataclasses.dataclass(frozen=True)
+class Egress:
+    """One batch's int-coded egress payload on the device: the
+    :func:`kernel.pack_egress` tables, and the mask length ``T`` their
+    host decode unpacks to."""
+
+    tables: dict
+    T: int
+
+
+def pack_results(seg):
+    """Enqueue the int-coded packing of a kernel result
+    (``kernel.pack_egress``, at the result's full segment capacity) and
+    return the :class:`Egress` payload; with ``FIREBIRD_WIRE_EGRESS``
+    off, or a float64 result (the bit-parity path), the raw ChipSegments
+    itself, which drains as is."""
+    if kernel.wire_egress_enabled() and seg.seg_meta.dtype == jnp.float32:
+        return Egress(kernel.pack_egress(seg), seg.mask.shape[-1])
+    return seg
+
+
+def segment_capacity(result) -> int:
+    """The segment slots per pixel of a raw result or an Egress payload."""
+    if isinstance(result, Egress):
+        return len(result.tables["meta"])
+    return result.seg_meta.shape[-2]
+
+
+def segment_depth(result) -> int:
     """The capacity probe: the most segments any pixel of the batch
-    closed.  Reading ``n_segments`` blocks until the batch's kernel has
-    finished, so this is where the drain waits on the device
-    (``wait_device`` span, ``egress_wait_device_seconds``)."""
+    closed, read from a raw result or an :class:`Egress` payload.
+    Reading ``n_segments`` blocks until the batch's kernel has finished,
+    so this is where the drain waits on the device (``wait_device``
+    span, ``egress_wait_device_seconds``)."""
+    n = result.tables["n_segments"] if isinstance(result, Egress) \
+        else result.n_segments
     with tracing.span("wait_device", histogram=obs_metrics.histogram(
             "egress_wait_device_seconds")):
-        return int(np.asarray(seg.n_segments).max())
+        return int(np.asarray(n).max())
 
 
 def format_span():
@@ -828,31 +859,34 @@ def format_span():
         cpu_histogram=obs_metrics.histogram("egress_format_cpu_seconds"))
 
 
-def fetch_results(seg, worst: int | None = None):
+def fetch_results(result, worst: int | None = None):
     """The ONE bulk device->host fetch per batch: ``jax.device_get`` of
     the whole batched result, collapsing the old per-chip, per-field
     ``chip_slice(to_host=True)`` pattern (~C x fields D2H round trips per
     batch) into a single transfer sweep.
 
-    With ``FIREBIRD_WIRE_EGRESS`` (default on) and a float32 result, the
-    ChipSegments is first packed ON DEVICE into int-coded tables sliced
-    to the batch's observed segment depth (``kernel.pack_egress``) and
-    decoded back host-side (``format.decode_egress``) — identical host
-    arrays, a fraction of the bytes on the wire (docs/ROOFLINE.md "Wire
-    budget").  ``worst`` is the caller's capacity probe (max segments
-    any pixel closed) when it already paid that sync; None probes here.
-    Records ``pipeline_d2h_seconds``, the ``wire_d2h_bytes`` counter,
-    the d2h ``transfer`` span leg and the decode's ``format`` span;
-    returns a host-array ChipSegments."""
+    ``result`` is an :class:`Egress` payload (the batch driver packs each
+    batch right behind its own kernel) or a raw ChipSegments, which is
+    packed here by :func:`pack_results`.  A payload crosses as int-coded
+    tables holding only the first ``egress_bucket(worst)`` segment slot
+    buffers and decodes back host-side (``format.decode_egress``) —
+    identical host arrays, a fraction of the bytes on the wire
+    (docs/ROOFLINE.md "Wire budget"); fetching whole buffers enqueues
+    nothing on the device.  ``worst`` is the caller's capacity probe
+    (max segments any pixel closed) when it already paid that sync;
+    None probes here.  Records ``pipeline_d2h_seconds``, the
+    ``wire_d2h_bytes`` counter, the d2h ``transfer`` span leg and the
+    decode's ``format`` span; returns a host-array ChipSegments."""
     import jax
 
-    payload, decode_T = seg, None
-    if kernel.wire_egress_enabled() and seg.seg_meta.dtype == jnp.float32:
+    if not isinstance(result, Egress):
+        result = pack_results(result)
+    payload = result
+    if isinstance(result, Egress):
         if worst is None:
-            worst = segment_depth(seg)
-        s_eff = kernel.egress_bucket(worst, seg.seg_meta.shape[-2])
-        payload = kernel.pack_egress(seg, s_eff)
-        decode_T = seg.mask.shape[-1]
+            worst = segment_depth(result)
+        payload = kernel.egress_slots(result.tables, kernel.egress_bucket(
+            worst, segment_capacity(result)))
     nbytes = int(sum(getattr(v, "nbytes", 0)
                      for v in jax.tree_util.tree_leaves(payload)))
     with tracing.span("d2h", bytes=nbytes, histogram=obs_metrics.histogram(
@@ -863,9 +897,9 @@ def fetch_results(seg, worst: int | None = None):
         "wire_d2h_bytes",
         help="bytes fetched device->host (batch results, int-coded and "
              "depth-sliced when the egress diet is on)").inc(nbytes)
-    if decode_T is not None:
+    if isinstance(result, Egress):
         with format_span():
-            host = ccdformat.decode_egress(host, decode_T)
+            host = ccdformat.decode_egress(host, result.T)
     return host
 
 
@@ -891,13 +925,15 @@ def write_batch_frames(packed, host_seg, n_real, *, writer, counters=None):
             counters.add("segments", int(host_seg.n_segments[c].sum()))
 
 
-def drain_batch(seg, packed, n_real, *, writer, counters, dtype=None,
+def drain_batch(result, packed, n_real, *, writer, counters, dtype=None,
                 sharding: str = "auto", pad_to: int | None = None,
                 compact: bool | None = None, ctx=None):
     """Fetch one batch's results to the host, format, and queue writes
     (the egress half of ref core.detect, core.py:69-72) — results cross
     D2H as one bulk :func:`fetch_results` transfer and format through the
-    vectorized :func:`write_batch_frames` path.
+    vectorized :func:`write_batch_frames` path.  ``result`` is the
+    batch's :class:`Egress` payload (packed at dispatch) or its raw
+    ChipSegments.
 
     ``ctx`` is the batch's :class:`~firebird_tpu.obs.tracing.TraceContext`
     — this function runs on the drain executor, so the context must
@@ -909,7 +945,10 @@ def drain_batch(seg, packed, n_real, *, writer, counters, dtype=None,
     more segments than the result buffers hold, the batch is recomputed
     here through the same (sharded-aware) dispatch with the capacity
     check on — rare enough that the synchronous re-run does not matter."""
-    cap = seg.seg_meta.shape[-2]                   # [.., P, S, 6] -> S
+    cap = segment_capacity(result)
+    if dtype is None:
+        dtype = jnp.float32 if isinstance(result, Egress) \
+            else result.seg_meta.dtype
     with tracing.activate(ctx):
         with tracing.span("drain", chips=n_real,
                           histogram=obs_metrics.histogram(
@@ -918,19 +957,19 @@ def drain_batch(seg, packed, n_real, *, writer, counters, dtype=None,
             # few hundred KB, so an overflowed batch never pays a
             # full-result transfer whose buffers are about to be discarded
             # (and the d2h telemetry counts only the one real bulk fetch).
-            worst = segment_depth(seg)
+            worst = segment_depth(result)
             if worst > cap:
                 logger("pyccd").info(
                     "segment capacity %d overflowed on drain (deepest pixel "
                     "closed %d); recomputing the batch", cap, worst)
                 obs_metrics.counter("capacity_redispatches").inc()
-                seg, _ = detect_batch(packed, dtype or seg.seg_meta.dtype,
-                                      sharding, pad_to=pad_to,
-                                      check_capacity=True, compact=compact,
-                                      max_segments=min(
-                                          2 * cap,
-                                          kernel.capacity_bound(packed)))
-            host = fetch_results(seg, worst=worst)
+                result, _ = detect_batch(packed, dtype, sharding,
+                                         pad_to=pad_to, check_capacity=True,
+                                         compact=compact,
+                                         max_segments=min(
+                                             2 * cap,
+                                             kernel.capacity_bound(packed)))
+            host = fetch_results(result, worst=worst)
             # Occupancy telemetry: the event loop's per-round active/paid
             # lane capture feeds kernel_round_active_fraction and the
             # compaction counters (results are on the host anyway).
@@ -1092,11 +1131,19 @@ def detect_chunk(cids, *, source, writer, acquired, cfg, counters, log,
                                                pad_to=pad_to, staged=staged,
                                                donate=_on_accelerator(),
                                                compact=cfg.compact)
+                    # Packed right behind its own kernel, so the drain's
+                    # d2h finds ready buffers instead of a packing program
+                    # queued behind the next batches' kernels.  Dropping
+                    # the raw result frees its buffers once the pack ran.
+                    result = pack_results(seg)
+                    del seg
+            if isinstance(result, Egress):
+                obs_metrics.counter("egress_packed_at_dispatch").inc()
             # /readyz flips here: mesh up + first batch dispatched means
             # compile/bring-up are behind us and the run is steady-state.
             obs_server.batch_dispatched()
             drains.append(drain_ex.submit(
-                drain_batch, seg, staged.packed, n_real, writer=writer,
+                drain_batch, result, staged.packed, n_real, writer=writer,
                 counters=counters, dtype=dtype,
                 sharding=cfg.device_sharding, pad_to=pad_to,
                 compact=cfg.compact, ctx=ctxs[i]))

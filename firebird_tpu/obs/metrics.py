@@ -270,6 +270,9 @@ METRIC_HELP = {
     "ingest_bytes_in": "decoded ingest payload bytes",
     "capacity_redispatches":
         "batches re-dispatched at doubled segment capacity",
+    "egress_packed_at_dispatch":
+        "batches whose int-coded egress was packed right behind their own "
+        "kernel",
     "chunk_failures": "chunks abandoned by the per-chunk isolation",
     "fetch_retries": "chip fetches retried after transient errors",
     "store_write_seconds": "store backend write wall time",

@@ -227,6 +227,43 @@ def test_drain_recomputes_on_capacity_overflow():
     assert len(real) == worst              # every closed segment persisted
 
 
+def test_drain_recomputes_packed_payload_on_capacity_overflow(monkeypatch):
+    """An overflowed float32 batch whose egress was packed at dispatch:
+    the drain probes the payload, re-dispatches with the check on, packs
+    the recomputed result itself, and lands every segment."""
+    import jax.numpy as jnp
+
+    from firebird_tpu.ccd import kernel
+    from firebird_tpu.obs import Counters
+    from firebird_tpu.obs import metrics as obs_metrics
+    from firebird_tpu.store import AsyncWriter
+    from test_ccd_kernel import overflow_packed
+
+    monkeypatch.setenv("FIREBIRD_WIRE_EGRESS", "1")
+    p = overflow_packed()
+    seg = kernel.detect_packed(p, dtype=jnp.float32, check_capacity=False)
+    payload = core.pack_results(seg)
+    assert isinstance(payload, core.Egress)
+    assert core.segment_capacity(payload) == kernel.MAX_SEGMENTS
+    worst = core.segment_depth(payload)
+    assert worst > kernel.MAX_SEGMENTS
+    store = MemoryStore("overflow32")
+    writer = AsyncWriter(store)
+    obs_metrics.reset_registry()
+    try:
+        core.drain_batch(payload, p, 1, writer=writer, counters=Counters(),
+                         dtype=jnp.float32)
+        writer.flush()
+        counts = obs_metrics.get_registry().snapshot()["counters"]
+    finally:
+        writer.close()
+        obs_metrics.reset_registry()
+    assert counts["capacity_redispatches"] == 1
+    rows = store.read("segment", {"px": 0, "py": 0})
+    real = [s for s in rows["sday"] if s != "0001-01-01"]
+    assert len(real) == worst
+
+
 def test_cli_status_reports_store_and_tile_progress(tmp_path, monkeypatch):
     from firebird_tpu.store import SqliteStore
 

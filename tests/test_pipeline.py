@@ -9,6 +9,7 @@ final batch — both drivers drain through this one code path.
 """
 
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -119,6 +120,62 @@ def test_drain_records_egress_metrics(ragged_batch):
     assert snap["counters"]["wire_d2h_bytes"] > 0
     assert snap["counters"]["store_rows_written"] >= n_real * (1 + 64 + 64)
     obs_metrics.reset_registry()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+def test_egress_packed_behind_its_own_kernel(dtype, monkeypatch):
+    """detect_chunk enqueues each batch's int-coded packing right behind
+    that batch's kernel, on the dispatch thread: batch k's pack comes
+    before batch k+1's kernel, and no drain packs on its success path.
+    The float64 bit-parity path packs nothing and drains raw."""
+    from firebird_tpu import grid
+    from firebird_tpu.ccd.sensor import SENSORS
+    from firebird_tpu.obs import logger
+
+    monkeypatch.setenv("FIREBIRD_WIRE_EGRESS", "1")
+    events = []
+    real_detect, real_pack = core.detect_batch, kernel.pack_egress
+
+    def detect_batch(*a, **kw):
+        out = real_detect(*a, **kw)
+        events.append(("kernel", threading.current_thread()))
+        return out
+
+    def pack_egress(seg):
+        events.append(("pack", threading.current_thread()))
+        return real_pack(seg)
+
+    monkeypatch.setattr(core, "detect_batch", detect_batch)
+    monkeypatch.setattr(kernel, "pack_egress", pack_egress)
+    cfg = Config(store_backend="memory", source_backend="synthetic",
+                 chips_per_batch=1, dtype=dtype, device_sharding="off",
+                 fetch_retries=0, pipeline_depth=3)
+    src = SyntheticSource(seed=9, start="1995-01-01", end="1998-01-01",
+                          sensor=SENSORS["landsat-ard-tiny"])
+    store = MemoryStore(f"order-{dtype}")
+    source, _, writer, policy, _, quarantine = core.robustness_setup(
+        cfg, "order-test", source=src, store=store)
+    cids = list(grid.chips(grid.tile(x=100, y=200)))[:3]
+    obs_metrics.reset_registry()
+    try:
+        done = core.run_chunk(
+            cids, source=source, writer=writer,
+            acquired="1995-01-01/1997-06-01", cfg=cfg, counters=Counters(),
+            log=logger("change-detection"), policy=policy,
+            quarantine=quarantine, reraise=True)
+        counts = obs_metrics.get_registry().snapshot()["counters"]
+    finally:
+        writer.close()
+        obs_metrics.reset_registry()
+    assert len(done) == 3 and store.count("chip") == 3
+    me = threading.current_thread()
+    if dtype == "float32":
+        assert events == [("kernel", me), ("pack", me)] * 3
+        assert counts["egress_packed_at_dispatch"] == 3
+    else:
+        assert events == [("kernel", me)] * 3
+        assert "egress_packed_at_dispatch" not in counts
+    assert "capacity_redispatches" not in counts
 
 
 def test_stage_batch_then_staged_dispatch_matches(ragged_batch):

@@ -49,17 +49,29 @@ def batch():
 # 1. golden: int-coded egress writes byte-identical store rows
 # ---------------------------------------------------------------------------
 
-def _drain_to_store(seg, p, egress: str, monkeypatch):
+def _drain_to_store(seg, p, egress: str, monkeypatch, prepack=False):
+    """Drain ``seg`` into a fresh store with the egress diet ``egress``;
+    ``prepack`` hands the drain the payload packed beforehand, as the
+    batch driver does at dispatch."""
     monkeypatch.setenv("FIREBIRD_WIRE_EGRESS", egress)
+    result = core.pack_results(seg) if prepack else seg
     store = MemoryStore(f"wire{egress}")
     writer = AsyncWriter(store)
     try:
-        core.drain_batch(seg, p, p.n_chips, writer=writer,
+        core.drain_batch(result, p, p.n_chips, writer=writer,
                          counters=Counters(), dtype=jnp.float32)
         writer.flush()
     finally:
         writer.close()
     return store
+
+
+def _assert_same_rows(on, off):
+    for table in ("chip", "pixel", "segment"):
+        rows_on, rows_off = on._tables[table], off._tables[table]
+        assert set(rows_on) == set(rows_off), table
+        for key in rows_off:
+            assert rows_on[key] == rows_off[key], (table, key)
 
 
 def test_golden_int_egress_store_rows_identical(batch, monkeypatch):
@@ -68,24 +80,89 @@ def test_golden_int_egress_store_rows_identical(batch, monkeypatch):
     p, seg = batch
     on = _drain_to_store(seg, p, "1", monkeypatch)
     off = _drain_to_store(seg, p, "0", monkeypatch)
-    for table in ("chip", "pixel", "segment"):
-        rows_on, rows_off = on._tables[table], off._tables[table]
-        assert set(rows_on) == set(rows_off), table
-        for key in rows_off:
-            assert rows_on[key] == rows_off[key], (table, key)
+    _assert_same_rows(on, off)
     assert on.count("segment") >= p.n_chips * 96
+
+
+def _segments_at_depth(seg, depth: int, seed: int):
+    """A float32 batched result of ``seg``'s shapes whose deepest pixel
+    closed exactly ``depth`` segments: ordinal days in slot order,
+    change probabilities k/PEEK_SIZE, arbitrary finite floats in the
+    bitcast planes."""
+    rng = np.random.default_rng(seed)
+    C, P, S, _ = seg.seg_meta.shape
+    T = seg.mask.shape[-1]
+    n = rng.integers(0, depth + 1, (C, P)).astype(np.int32)
+    n[0, 0] = depth
+    meta = np.zeros((C, P, S, 6), np.float32)
+    meta[..., 0] = 725000 + 400 * np.arange(S) \
+        + rng.integers(0, 100, (C, P, S))
+    meta[..., 1] = meta[..., 0] + rng.integers(100, 300, (C, P, S))
+    meta[..., 2] = meta[..., 1] + 1
+    meta[..., 3] = rng.integers(0, params.PEEK_SIZE + 1, (C, P, S)
+                                ).astype(np.float32) \
+        / np.float32(params.PEEK_SIZE)
+    meta[..., 4] = rng.integers(0, 100, (C, P, S))
+    meta[..., 5] = rng.integers(12, 400, (C, P, S))
+    f = lambda *shape: rng.normal(0, 50, shape).astype(np.float32)
+    return kernel.ChipSegments(
+        n_segments=jnp.asarray(n), seg_meta=jnp.asarray(meta),
+        seg_rmse=jnp.asarray(f(C, P, S, 7)),
+        seg_mag=jnp.asarray(f(C, P, S, 7)),
+        seg_coef=jnp.asarray(f(C, P, S, 7, 8)),
+        mask=jnp.asarray(rng.random((C, P, T)) < 0.7),
+        procedure=jnp.asarray(rng.integers(0, 4, (C, P)).astype(np.int32)),
+        vario=jnp.asarray(f(C, P, 7)))
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3, 7, 10])
+def test_golden_packed_at_capacity_fetched_to_depth(batch, depth,
+                                                    monkeypatch):
+    """Packed at full capacity (at dispatch), fetched as the first
+    egress_bucket(depth) slot buffers: the store rows equal the raw-f32
+    drain's byte for byte, and the wire carries exactly the int-coded
+    budget at that depth — int32 per segment value for s_eff slots, a
+    bit per mask date, int32 per-pixel tables."""
+    p, real = batch
+    seg = _segments_at_depth(real, depth, seed=depth)
+    C, P, S, _ = real.seg_meta.shape
+    T = real.mask.shape[-1]
+    s_eff = kernel.egress_bucket(depth, S)
+    obs_metrics.reset_registry()
+    try:
+        on = _drain_to_store(seg, p, "1", monkeypatch, prepack=True)
+        d2h = obs_metrics.get_registry().snapshot()["counters"][
+            "wire_d2h_bytes"]
+    finally:
+        obs_metrics.reset_registry()
+    off = _drain_to_store(seg, p, "0", monkeypatch)
+    _assert_same_rows(on, off)
+    # every closed segment landed, and a sentinel row for empty pixels
+    assert on.count("segment") == int(
+        np.maximum(np.asarray(seg.n_segments), 1).sum())
+    per_px = 4 + 4 + (T + 7) // 8 + 7 * 4 + s_eff * (6 + 7 + 7 + 56) * 4
+    assert d2h == C * P * per_px
 
 
 def test_pack_unpack_roundtrip_bit_exact(batch):
     """pack_egress -> decode_egress reproduces every result field bit
-    for bit (at the packed depth), and ships only integer tables."""
+    for bit (at the fetched depth), and ships only integer tables: one
+    buffer per segment slot at full capacity, of which the drain fetches
+    the first egress_bucket(worst)."""
     p, seg = batch
     raw = jax.device_get(seg)
     worst = int(raw.n_segments.max())
-    s_eff = kernel.egress_bucket(worst, raw.seg_meta.shape[-2])
-    tables = jax.device_get(kernel.pack_egress(seg, s_eff))
-    assert all(v.dtype.kind in "iu" for v in tables.values()), \
-        {k: str(v.dtype) for k, v in tables.items()}
+    S = raw.seg_meta.shape[-2]
+    s_eff = kernel.egress_bucket(worst, S)
+    packed = kernel.pack_egress(seg)
+    for k in kernel.EGRESS_SLOT_PLANES:
+        assert len(packed[k]) == S, k
+    tables = jax.device_get(kernel.egress_slots(packed, s_eff))
+    for k in kernel.EGRESS_SLOT_PLANES:
+        assert len(tables[k]) == s_eff, k
+    leaves = jax.tree_util.tree_leaves(tables)
+    assert all(v.dtype.kind in "iu" for v in leaves), \
+        {str(v.dtype) for v in leaves}
     dec = ccdformat.decode_egress(tables, raw.mask.shape[-1])
     np.testing.assert_array_equal(dec.n_segments, raw.n_segments)
     np.testing.assert_array_equal(dec.procedure, raw.procedure)

@@ -8,8 +8,8 @@ that ISSUE 11 put in place (docs/ROOFLINE.md "Wire budget"):
    The float design matrices / date grid / validity mask must be built
    on device (`kernel.device_designs`), never shipped.
 2. **Egress is int-coded.**  `kernel.pack_egress` of the batch result
-   yields integer-dtyped tables only, sliced to the observed segment
-   depth, and `format.decode_egress` round-trips them BIT-EXACTLY to
+   yields integer-dtyped tables only, fetched as slot buffers to the
+   observed segment depth, and `format.decode_egress` round-trips them BIT-EXACTLY to
    the raw f32 result.
 3. **The counters move.**  `wire_h2d_bytes` / `wire_d2h_bytes` record
    the staged/drained volume, and the packed egress is measurably
@@ -69,13 +69,16 @@ def main() -> int:
     raw = jax.device_get(seg)
     worst = int(np.asarray(raw.n_segments).max())
     s_eff = kernel.egress_bucket(worst, raw.seg_meta.shape[-2])
-    tables = jax.device_get(kernel.pack_egress(seg, s_eff))
-    for name, v in tables.items():
-        if v.dtype.kind not in "iu":
-            failures.append(f"float egress table {name!r}: {v.dtype}")
-    report["egress_tables"] = {k: {"dtype": str(v.dtype),
-                                   "bytes": int(v.nbytes)}
-                               for k, v in tables.items()}
+    tables = jax.device_get(kernel.egress_slots(kernel.pack_egress(seg),
+                                                s_eff))
+    leaves = {k: jax.tree_util.tree_leaves(v) for k, v in tables.items()}
+    for name, vs in leaves.items():
+        for v in vs:
+            if v.dtype.kind not in "iu":
+                failures.append(f"float egress table {name!r}: {v.dtype}")
+    report["egress_tables"] = {k: {"dtype": str(vs[0].dtype),
+                                   "bytes": int(sum(v.nbytes for v in vs))}
+                               for k, vs in leaves.items()}
     dec = ccdformat.decode_egress(tables, raw.mask.shape[-1])
     for f in ("n_segments", "procedure", "mask", "vario", "rounds",
               "round_counts", "occupancy", "compactions"):
@@ -102,7 +105,7 @@ def main() -> int:
         failures.append("fetch_results packed drain changed n_segments")
     d2h_raw = int(sum(np.asarray(v).nbytes
                       for v in jax.tree_util.tree_leaves(raw)))
-    d2h_packed = int(sum(v.nbytes for v in tables.values()))
+    d2h_packed = int(sum(v.nbytes for vs in leaves.values() for v in vs))
     report["d2h_bytes_raw_f32"] = d2h_raw
     report["d2h_bytes_packed"] = d2h_packed
     report["d2h_cut"] = round(d2h_raw / max(d2h_packed, 1), 2)
