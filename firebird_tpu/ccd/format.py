@@ -5,6 +5,11 @@ pyccd result into 40-column rows with ISO dates, golden-tested by the
 reference at test/test_pyccd.py:37-126) — plus a vectorized chip-level path
 that goes straight from the kernel's ChipSegments arrays to the three table
 frames (chip / pixel / segment), skipping per-pixel Python entirely.
+
+The segment rows are keyed by sensor: each band gets four columns under
+the sensor's store prefix (ccd/sensor.py ``store_prefixes``,
+store/schema.py).  Landsat ARD's seven prefixes are the reference's
+contract (ccdc/pyccd.py:118-145); a Sentinel-2 chip writes its twelve.
 """
 
 from __future__ import annotations
@@ -12,10 +17,8 @@ from __future__ import annotations
 import numpy as np
 
 from firebird_tpu.ccd import harmonic, params
+from firebird_tpu.ccd.sensor import LANDSAT_ARD
 from firebird_tpu.utils import dates as dt
-
-# Column prefixes in band order (ccdc/pyccd.py:118-145).
-BAND_PREFIX = ("bl", "gr", "re", "ni", "s1", "s2", "th")
 
 
 def default(change_models: list) -> list:
@@ -25,11 +28,13 @@ def default(change_models: list) -> list:
             if not change_models else change_models)
 
 
-def format_records(cx, cy, px, py, dates, ccdresult) -> list[dict]:
+def format_records(cx, cy, px, py, dates, ccdresult,
+                   sensor=LANDSAT_ARD) -> list[dict]:
     """Per-pixel result -> list of flat row dicts (ccdc/pyccd.py:106-148).
 
     ``dates`` are ordinal days; emitted as ISO strings in input order, the
-    processing mask alongside.
+    processing mask alongside.  Band columns follow ``sensor``'s names
+    and store prefixes.
     """
     def g(cm, *keys, default=None):
         v = cm
@@ -50,8 +55,7 @@ def format_records(cx, cy, px, py, dates, ccdresult) -> list[dict]:
             "chprob": g(cm, "change_probability"),
             "curqa": g(cm, "curve_qa"),
         }
-        for b, name in enumerate(params.BAND_NAMES):
-            p = BAND_PREFIX[b]
+        for name, p in zip(sensor.band_names, sensor.store_prefixes):
             row[f"{p}mag"] = g(cm, name, "magnitude")
             row[f"{p}rmse"] = g(cm, name, "rmse")
             row[f"{p}coef"] = g(cm, name, "coefficients")
@@ -126,13 +130,20 @@ def _iso_col(ordinals: np.ndarray) -> np.ndarray:
     return table[inv]
 
 
-def _check_landsat_schema(packed, what: str) -> None:
-    if packed.sensor.band_names != params.BAND_NAMES:
-        raise ValueError(
-            f"{what} writes the reference's Landsat segment schema "
-            f"(7 bands, ccdc/segment.py:16-56); got sensor "
-            f"{packed.sensor.name!r} with {packed.sensor.n_bands} bands — "
-            "persist non-Landsat results through a sensor-specific schema")
+def _band_columns(segment: dict, prefixes, real, mag, rmse, intercept,
+                  coefs7) -> None:
+    """Each band's four columns (``<p>mag``, ``rmse``, ``int``, ``coef``)
+    into ``segment``, from [R, B] (coefficients [R, B, 7]) row arrays;
+    sentinel rows (``~real``) hold NaN / None."""
+    R = real.shape[0]
+    for b, p in enumerate(prefixes):
+        segment[f"{p}mag"] = np.where(real, mag[:, b], np.nan)
+        segment[f"{p}rmse"] = np.where(real, rmse[:, b], np.nan)
+        segment[f"{p}int"] = np.where(real, intercept[:, b], np.nan)
+        col = np.empty(R, object)
+        col[:] = list(coefs7[:, b])         # rows stay numpy; backends pack
+        col[~real] = None
+        segment[f"{p}coef"] = col
 
 
 def chip_frames(packed, chip: int, seg) -> dict[str, dict]:
@@ -140,11 +151,11 @@ def chip_frames(packed, chip: int, seg) -> dict[str, dict]:
 
     Returns {'chip': {...}, 'pixel': {...}, 'segment': {...}} where each
     value is a dict of column -> numpy array, matching the reference table
-    schemas (ccdc/chip.py:15-22, pixel.py:14-21, segment.py:16-56).
+    schemas (ccdc/chip.py:15-22, pixel.py:14-21, segment.py:16-56), with
+    the segment table's band columns keyed by ``packed.sensor``.
     Pixels with no segments contribute the sentinel row (sday=eday=bday=
     0001-01-01, ccdc/pyccd.py:99-103) so reruns stay idempotent.
     """
-    _check_landsat_schema(packed, "chip_frames")
     cx, cy = (int(v) for v in packed.cids[chip])
     T = int(packed.n_obs[chip])
     dates_ord = packed.dates[chip][:T]
@@ -184,15 +195,8 @@ def chip_frames(packed, chip: int, seg) -> dict[str, dict]:
         "curqa": _int_or_none(meta[:, 4], real),
         "rfrawp": np.full(R, None, object),
     }
-    for b in range(params.NUM_BANDS):
-        p = BAND_PREFIX[b]
-        segment[f"{p}mag"] = np.where(real, mag[:, b], np.nan)
-        segment[f"{p}rmse"] = np.where(real, rmse[:, b], np.nan)
-        segment[f"{p}int"] = np.where(real, intercept[:, b], np.nan)
-        col = np.empty(R, object)
-        col[:] = list(coefs7[:, b])         # rows stay numpy; backends pack
-        col[~real] = None
-        segment[f"{p}coef"] = col
+    _band_columns(segment, packed.sensor.store_prefixes, real, mag, rmse,
+                  intercept, coefs7)
 
     mask = np.asarray(seg.mask, np.uint8)[:, :T]
     mask_col = np.empty(P, object)
@@ -229,7 +233,6 @@ def batch_frames(packed, seg,
     regression surface both drivers' drains share (driver/core.py
     ``write_batch_frames``).
     """
-    _check_landsat_schema(packed, "batch_frames")
     C = packed.n_chips if n_real is None else int(n_real)
     if C == 0:
         return []
@@ -275,15 +278,8 @@ def batch_frames(packed, seg,
         "curqa": _int_or_none(meta[:, 4], real),
         "rfrawp": np.full(R, None, object),
     }
-    for b in range(params.NUM_BANDS):
-        p = BAND_PREFIX[b]
-        segment[f"{p}mag"] = np.where(real, mag[:, b], np.nan)
-        segment[f"{p}rmse"] = np.where(real, rmse[:, b], np.nan)
-        segment[f"{p}int"] = np.where(real, intercept[:, b], np.nan)
-        col = np.empty(R, object)
-        col[:] = list(coefs7[:, b])
-        col[~real] = None
-        segment[f"{p}coef"] = col
+    _band_columns(segment, packed.sensor.store_prefixes, real, mag, rmse,
+                  intercept, coefs7)
 
     # ---- split per chip (keyed writes preserve the resume invariant) ----
     rows_per_chip = n_rows.reshape(C, P).sum(1)

@@ -1226,6 +1226,59 @@ def _block_widths(P: int) -> np.ndarray:
     return w
 
 
+# The widest chip, in pixels, the event loop is compiled over.  The v5e
+# compile's host memory grows with the lanes of one chip, about 1.35 MB a
+# lane, and not with the chip count (AOT for a v5e: one chip of 3,600 px
+# 7.7 GB, four chips of 900 px 3.1 GB), so one Sentinel-2 chip of 90,000
+# px outgrows a 40 GiB host where 8-chip batches of Landsat's 10,000 px
+# compile.  A wider chip runs as equal lane blocks on the chip axis
+# (:func:`lane_blocks`); pixels are independent, so its rows are
+# unchanged.
+MAX_CHIP_LANES = 10_000
+
+
+def lane_blocks(P: int) -> int:
+    """The equal lane blocks a chip of ``P`` pixels runs as: the fewest
+    ``k`` dividing ``P`` with ``P / k <= MAX_CHIP_LANES`` (1 when it
+    fits; Sentinel-2's 90,000 px give 9 blocks of 10,000)."""
+    k = -(-P // MAX_CHIP_LANES)
+    while P % k:
+        k += 1
+    return k
+
+
+def _split_lanes(k, Xs, Xts, ts, valids, Ys, qas):
+    """A batch of C chips as C*k chips of P/k lanes: each block keeps
+    its chip's date grid and design (chip-major, block-minor order)."""
+    C, B, P, T = Ys.shape
+    rep = lambda a: jnp.repeat(a, k, axis=0)
+    Ys = Ys.reshape(C, B, k, P // k, T).transpose(0, 2, 1, 3, 4) \
+        .reshape(C * k, B, P // k, T)
+    return (rep(Xs), rep(Xts), rep(ts), rep(valids), Ys,
+            qas.reshape(C * k, P // k, T))
+
+
+def _join_lanes(k, seg: ChipSegments) -> ChipSegments:
+    """Undo :func:`_split_lanes` on a result: per-pixel fields back to
+    [C, P, ...]; per-chip diagnostics as the chip's (the loop's round
+    counts are shared, lane and compaction counts add up)."""
+    C = seg.n_segments.shape[0] // k
+    pix = lambda a: a.reshape((C, -1) + a.shape[2:])
+    blocks = lambda a: a.reshape((C, k) + a.shape[1:])
+    opt = lambda a, f: None if a is None else f(blocks(a))
+    return ChipSegments(
+        n_segments=pix(seg.n_segments), seg_meta=pix(seg.seg_meta),
+        seg_rmse=pix(seg.seg_rmse), seg_mag=pix(seg.seg_mag),
+        seg_coef=pix(seg.seg_coef), mask=pix(seg.mask),
+        procedure=pix(seg.procedure),
+        rounds=opt(seg.rounds, lambda a: a[:, 0]),
+        vario=None if seg.vario is None else pix(seg.vario),
+        round_counts=opt(seg.round_counts, lambda a: a[:, 0]),
+        occupancy=opt(seg.occupancy, lambda a: a.sum(1)),
+        compactions=opt(seg.compactions, lambda a: a.sum(1)),
+        lanes_migrated=opt(seg.lanes_migrated, lambda a: a.sum(1)))
+
+
 def _detect_batch_core(Xs, Xts, ts, valids, Ys, qas, *,
                        wcap: int | None = None, sensor=LANDSAT_ARD,
                        max_segments: int = MAX_SEGMENTS, dtype=None,
@@ -1283,17 +1336,25 @@ def _detect_batch_core(Xs, Xts, ts, valids, Ys, qas, *,
     identical to f32, coef/rmse inside params.MIXED_ULP_BUDGET; f32
     stores and Pallas fit routes only (see use_mixed_precision).
 
+    A chip wider than ``MAX_CHIP_LANES`` runs as equal lane blocks on
+    the chip axis and is joined back before return (:func:`lane_blocks`).
+
     ``rebalance`` (static; a parallel.mesh.RebalanceSpec, sharded
     dispatches only) arms the cross-device straggler rebalancing ring at
     the bucketed-tail boundary — lanes migrate to the right-neighbor
     device when the alive-count imbalance crosses the threshold, results
     migrate back, stores stay row-identical."""
+    k = lane_blocks(Ys.shape[2])
+    if k > 1:
+        Xs, Xts, ts, valids, Ys, qas = _split_lanes(k, Xs, Xts, ts, valids,
+                                                    Ys, qas)
     with jax.default_matmul_precision("highest"):
-        return _detect_batch_impl(Xs, Xts, ts, valids, Ys, qas, wcap=wcap,
-                                  sensor=sensor, max_segments=max_segments,
-                                  dtype=dtype, compact=compact,
-                                  fused=fused, mixed=mixed,
-                                  rebalance=rebalance)
+        seg = _detect_batch_impl(Xs, Xts, ts, valids, Ys, qas, wcap=wcap,
+                                 sensor=sensor, max_segments=max_segments,
+                                 dtype=dtype, compact=compact,
+                                 fused=fused, mixed=mixed,
+                                 rebalance=rebalance)
+    return _join_lanes(k, seg) if k > 1 else seg
 
 
 _PALLAS_COMPONENTS = ("lasso", "monitor", "tmask", "fit", "score", "init",

@@ -27,7 +27,10 @@ class Sensor:
     checked against params.OPTICAL_MIN/MAX, ``thermal_bands`` against
     THERMAL_MIN/MAX (empty for sensors with no thermal band).
     ``blue_band`` drives the insufficient-clear procedure's blue-median
-    screen (params.INSUF_CLEAR_BLUE_DELTA).
+    screen (params.INSUF_CLEAR_BLUE_DELTA).  ``store_prefixes`` name each
+    band's four segment-table columns (``<prefix>mag``, ``rmse``,
+    ``coef``, ``int``; store/schema.py), one distinct prefix per band in
+    band order.
     """
 
     name: str
@@ -39,6 +42,7 @@ class Sensor:
     blue_band: int
     chip_side: int
     pixel_size_m: int
+    store_prefixes: tuple[str, ...]
 
     @property
     def n_bands(self) -> int:
@@ -76,14 +80,18 @@ LANDSAT_ARD = Sensor(
     blue_band=0,
     chip_side=100,
     pixel_size_m=30,
+    # the reference's segment columns (ccdc/pyccd.py:118-145)
+    store_prefixes=("bl", "gr", "re", "ni", "s1", "s2", "th"),
 )
 
 # Sentinel-2 L2A surface reflectance, 12-band stack resampled to 10 m: a
 # 3 km chip is 300x300 px — 9x the pixel density of Landsat ARD
 # (BASELINE.json config #5).  CCDC detection/Tmask band roles map by
-# wavelength: green, red, nir, swir1, swir2; no thermal instrument.
+# wavelength: green, red, nir, swir1, swir2; no thermal instrument.  Named
+# for its product level, as landsat-ard is: the twelve bands (and so the
+# store's segment columns) are L2A's, where L1C carries a thirteenth, B10.
 SENTINEL2 = Sensor(
-    name="sentinel2",
+    name="sentinel2-l2a",
     band_names=("coastal", "blue", "green", "red", "re1", "re2", "re3",
                 "nir", "nir08", "wv", "swir1", "swir2"),
     detection_bands=(2, 3, 7, 10, 11),
@@ -93,6 +101,8 @@ SENTINEL2 = Sensor(
     blue_band=1,
     chip_side=300,
     pixel_size_m=10,
+    store_prefixes=("ca", "bl", "gr", "re", "r1", "r2", "r3", "ni", "n8",
+                    "wv", "s1", "s2"),
 )
 
 # Landsat ARD band semantics on a 10x10 chip: the fleet-scale test

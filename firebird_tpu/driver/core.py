@@ -849,12 +849,13 @@ def segment_depth(result) -> int:
         return int(np.asarray(n).max())
 
 
-def format_span():
+def format_span(bands: int):
     """The span of the drain's host formatting (int-coded decode and
-    ``format.batch_frames``): wall and thread-CPU seconds, so wall minus
-    CPU is the time the drain thread waited inside it."""
+    ``format.batch_frames``) of results of ``bands`` bands: wall and
+    thread-CPU seconds, so wall minus CPU is the time the drain thread
+    waited inside it."""
     return tracing.span(
-        "format",
+        "format", bands=bands,
         histogram=obs_metrics.histogram("egress_format_seconds"),
         cpu_histogram=obs_metrics.histogram("egress_format_cpu_seconds"))
 
@@ -898,7 +899,8 @@ def fetch_results(result, worst: int | None = None):
         help="bytes fetched device->host (batch results, int-coded and "
              "depth-sliced when the egress diet is on)").inc(nbytes)
     if isinstance(result, Egress):
-        with format_span():
+        bands = host["rmse"][0].shape[-1]       # a slot is [C, P, B]
+        with format_span(bands):
             host = ccdformat.decode_egress(host, result.T)
     return host
 
@@ -912,7 +914,7 @@ def write_batch_frames(packed, host_seg, n_real, *, writer, counters=None):
     before the first write is queued, so the queue's waits
     (``queue_wait``) stay out of the formatting time."""
     P = host_seg.n_segments.shape[1]
-    with format_span():
+    with format_span(packed.sensor.n_bands):
         batch = ccdformat.batch_frames(packed, host_seg, n_real)
     for c, (cid, frames) in enumerate(batch):
         for table in ("chip", "pixel", "segment"):

@@ -126,8 +126,7 @@ def publish_frame(packed, st: incremental.StreamState, side: dict) -> dict:
         "curqa": ccdformat._int_or_none(curqa, ones),
         "rfrawp": np.full(R, None, object),
     }
-    for b in range(params.NUM_BANDS):
-        p = ccdformat.BAND_PREFIX[b]
+    for b, p in enumerate(packed.sensor.store_prefixes):
         frame[f"{p}mag"] = np.zeros(R)
         frame[f"{p}rmse"] = rmse[:, b]
         frame[f"{p}int"] = intercept[:, b]

@@ -41,7 +41,7 @@ from firebird_tpu.ccd.params import FILL_VALUE
 from firebird_tpu.config import Config
 from firebird_tpu.ingest.packer import CHIP_SIDE, PIXEL_SIZE_M, PIXELS
 from firebird_tpu.obs import logger
-from firebird_tpu.store import open_store
+from firebird_tpu.store import open_store, schema
 from firebird_tpu.utils import dates as dt
 
 log = logger("products")
@@ -69,6 +69,7 @@ class ChipSegmentArrays:
     def __init__(self, cx: int, cy: int, seg: dict):
         from firebird_tpu.rf.features import pixel_index
 
+        schema.require_landsat(seg, "product rasters")
         px = np.asarray(seg["px"], np.int64)
         py = np.asarray(seg["py"], np.int64)
         if px.size:

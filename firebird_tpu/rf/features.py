@@ -19,11 +19,13 @@ from __future__ import annotations
 
 import numpy as np
 
-from firebird_tpu.ccd.format import BAND_PREFIX
+from firebird_tpu.ccd.sensor import LANDSAT_ARD
 from firebird_tpu.ingest.packer import CHIP_SIDE, PIXEL_SIZE_M
+from firebird_tpu.store import schema
 from firebird_tpu.utils import dates as dt
 
 AUX_FEATURES = ("dem", "aspect", "slope", "mpw", "posidex")
+BAND_PREFIX = LANDSAT_ARD.store_prefixes
 
 COLUMNS = (
     tuple(f"{p}mag" for p in BAND_PREFIX)
@@ -78,6 +80,7 @@ def assemble(seg: dict, aux: dict, cx: int, cy: int,
     (cx, cy, px, py, sday, eday) and, when ``trends`` is present in aux,
     a ``label`` column.
     """
+    schema.require_landsat(seg, "classification features")
     n = len(seg["sday"])
     mask = np.ones(n, bool) if row_mask is None else np.asarray(row_mask)
     idx = np.flatnonzero(mask)

@@ -22,6 +22,7 @@ import time
 
 import numpy as np
 
+from firebird_tpu.obs import metrics as obs_metrics
 from firebird_tpu.store import schema
 
 
@@ -57,8 +58,23 @@ def _normalize(v):
     return v
 
 
-def _col_types(table: str) -> dict[str, str]:
-    return dict(schema.TABLES[table]["columns"])
+def _segment_bands(have, frame: dict) -> tuple[str, ...]:
+    """The band prefixes of a store's segment table once ``frame`` is
+    written to it: ``have``, or where the store has no segment table yet
+    (``None``) the frame's own — Landsat's for a frame with no band
+    column.  Refuses a frame of other bands (a store holds one sensor)
+    and sets the ``store_segment_columns`` gauge."""
+    got = schema.band_prefixes(frame)
+    bands = have or got or schema.LANDSAT_BANDS
+    if got and set(got) != set(bands):
+        raise ValueError(
+            f"segment frame has band columns {got}, the store's segment "
+            f"table {tuple(bands)}: a store holds one sensor's segments")
+    obs_metrics.gauge(
+        "store_segment_columns",
+        help="columns of the segment table the store writes").set(
+        len(schema.segment_columns(bands)))
+    return bands
 
 
 def _encode_cell(v, typ: str):
@@ -112,6 +128,7 @@ class MemoryStore:
     def __init__(self, keyspace: str = "default"):
         self.keyspace = keyspace
         self._tables: dict[str, dict] = {t: {} for t in schema.TABLES}
+        self._bands = None      # the segment table's, from its first frame
         self._lock = threading.Lock()
 
     def write(self, table: str, frame: dict) -> int:
@@ -119,6 +136,8 @@ class MemoryStore:
         cols = list(frame.keys())
         n = len(next(iter(frame.values())))
         with self._lock:
+            if table == "segment":
+                self._bands = _segment_bands(self._bands, frame)
             for i in range(n):
                 row = {c: _normalize(frame[c][i]) for c in cols}
                 self._tables[table][tuple(row[k] for k in key)] = row
@@ -128,7 +147,7 @@ class MemoryStore:
         with self._lock:
             rows = [r for r in self._tables[table].values()
                     if not where or all(r.get(k) == v for k, v in where.items())]
-        cols = schema.columns(table)
+        cols = schema.columns(table, self._bands)
         return {c: [r.get(c) for r in rows] for c in cols}
 
     def count(self, table: str) -> int:
@@ -176,6 +195,7 @@ class SqliteStore:
         self._local = threading.local()
         self._all_conns: list[sqlite3.Connection] = []
         self._conns_lock = threading.Lock()
+        self._bands = None      # the segment table's band prefixes, once seen
         if not self.read_only:
             self._create()
 
@@ -207,17 +227,15 @@ class SqliteStore:
                 self._all_conns.append(conn)
         return self._local.conn
 
-    def _create(self):
+    def _create_table(self, table: str, columns) -> None:
         con = self._conn()
         sql_type = lambda typ: ("TEXT" if typ == "JSON" else
                                 "BLOB" if typ in schema.PACKED_DTYPES else typ)
-        for t, spec in schema.TABLES.items():
-            cols = ", ".join(
-                f'"{c}" {sql_type(typ)}' for c, typ in spec["columns"])
-            pk = ", ".join(spec["key"])
-            sql = (f'CREATE TABLE IF NOT EXISTS "{t}" '
-                   f'({cols}, PRIMARY KEY ({pk}))')
-            _retry_locked(lambda: con.execute(sql))
+        cols = ", ".join(f'"{c}" {sql_type(typ)}' for c, typ in columns)
+        pk = ", ".join(schema.primary_key(table))
+        sql = (f'CREATE TABLE IF NOT EXISTS "{table}" '
+               f'({cols}, PRIMARY KEY ({pk}))')
+        _retry_locked(lambda: con.execute(sql))
         # Secondary (cx, cy) index for the serve-path point reads.  The
         # segment PK's autoindex already leads with (cx, cy), but the
         # product PK leads with (name, date) — a `WHERE cx=? AND cy=?`
@@ -225,11 +243,40 @@ class SqliteStore:
         # whole table.  Explicit on both so the serving layer's access
         # pattern is index-backed regardless of which table it reads;
         # tests pin the query plan (tests/test_store.py).
-        for t in ("segment", "product"):
-            sql = (f'CREATE INDEX IF NOT EXISTS "idx_{t}_chip" '
-                   f'ON "{t}" (cx, cy)')
+        if table in ("segment", "product"):
+            sql = (f'CREATE INDEX IF NOT EXISTS "idx_{table}_chip" '
+                   f'ON "{table}" (cx, cy)')
             _retry_locked(lambda: con.execute(sql))
         con.commit()
+
+    def _create(self):
+        # The segment table waits for its first frame, which names its
+        # sensor's band columns (_segment_prefixes).
+        for t, spec in schema.TABLES.items():
+            if t != "segment":
+                self._create_table(t, spec["columns"])
+
+    def _segment_prefixes(self, frame: dict | None = None):
+        """The segment table's band prefixes, read from the table on disk
+        (None while there is none); with ``frame``, a missing table is
+        first created with the frame's bands."""
+        if self._bands is None:
+            cols = [r[1] for r in self._conn().execute(
+                'PRAGMA table_info("segment")')]
+            if cols:
+                self._bands = schema.band_prefixes(cols)
+            elif frame is not None:
+                self._create_table("segment", schema.segment_columns(
+                    _segment_bands(None, frame)))
+                return self._segment_prefixes()
+        return self._bands
+
+    def _types(self, table: str) -> dict[str, str]:
+        return schema.column_types(
+            table, self._bands if table == "segment" else None)
+
+    def _absent(self, table: str) -> bool:
+        return table == "segment" and self._segment_prefixes() is None
 
     def write(self, table: str, frame: dict) -> int:
         if self.read_only:
@@ -237,7 +284,9 @@ class SqliteStore:
                 f"write to {table!r} on a read-only replica connection "
                 f"({self.path}): writes belong to the writer process "
                 "(open_store(..., read_only=False))")
-        types = _col_types(table)
+        if table == "segment":
+            _segment_bands(self._segment_prefixes(frame), frame)
+        types = self._types(table)
         cols = list(types)
         n = len(next(iter(frame.values())))
         rows = list(zip(*(_encode_column(frame, c, types[c], n)
@@ -251,7 +300,9 @@ class SqliteStore:
         return n
 
     def read(self, table: str, where: dict | None = None) -> dict:
-        types = _col_types(table)
+        if self._absent(table):
+            return {c: [] for c in schema.columns(table)}
+        types = self._types(table)
         cols = list(types)
         sql = f'SELECT {", ".join(cols)} FROM "{table}"'
         args: list = []
@@ -266,10 +317,14 @@ class SqliteStore:
         return out
 
     def count(self, table: str) -> int:
+        if self._absent(table):
+            return 0
         return self._conn().execute(
             f'SELECT COUNT(*) FROM "{table}"').fetchone()[0]
 
     def chip_ids(self, table: str = "segment") -> set[tuple[int, int]]:
+        if self._absent(table):
+            return set()
         k1, k2 = schema.primary_key(table)[:2]
         cur = self._conn().execute(
             f'SELECT DISTINCT "{k1}", "{k2}" FROM "{table}"')
@@ -313,6 +368,8 @@ class ParquetStore:
     def write(self, table: str, frame: dict) -> int:
         import pyarrow as pa
         import pyarrow.parquet as pq
+        if table == "segment":      # Landsat's columns only (read())
+            _segment_bands(schema.LANDSAT_BANDS, frame)
         # One frame = one partition: the file is named after row 0's key
         # prefix, so rows for a second chip would silently land in (and
         # clobber) the first chip's file.
@@ -488,7 +545,9 @@ class CassandraStore:
         return self._prepared[table]
 
     def write(self, table: str, frame: dict) -> int:
-        types = _col_types(table)
+        if table == "segment":      # Landsat's columns only (the DDL)
+            _segment_bands(schema.LANDSAT_BANDS, frame)
+        types = schema.column_types(table)
         cols = list(types)
         stmt = self._prepare(table)
         n = len(next(iter(frame.values())))
@@ -505,7 +564,7 @@ class CassandraStore:
         return n
 
     def read(self, table: str, where: dict | None = None) -> dict:
-        types = _col_types(table)
+        types = schema.column_types(table)
         cols = list(types)
         cql = f"SELECT {', '.join(cols)} FROM {self.keyspace}.{table}"
         params: tuple = ()

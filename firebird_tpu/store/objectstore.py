@@ -62,7 +62,7 @@ import numpy as np
 from firebird_tpu import retry as retrylib
 from firebird_tpu.obs import metrics as obs_metrics
 from firebird_tpu.store import schema
-from firebird_tpu.store.backends import _col_types, _normalize
+from firebird_tpu.store.backends import _normalize, _segment_bands
 
 # Retained generations per key: newest + one fallback — the double-bank
 # contract (statestore.py slot banks) lifted to the object tier.
@@ -617,7 +617,9 @@ class ObjectBackedStore:
         if self.read_only:
             raise RuntimeError(
                 f"write to {table!r} on a read-only object-store handle")
-        types = _col_types(table)
+        if table == "segment":      # Landsat's columns only (TABLES)
+            _segment_bands(schema.LANDSAT_BANDS, frame)
+        types = schema.column_types(table)
         pk = schema.primary_key(table)
         keyp = pk[:_PART[table]]
         n = len(next(iter(frame.values())))
@@ -675,7 +677,7 @@ class ObjectBackedStore:
         return self._obj.list(f"{self._prefix}/{table}/")
 
     def read(self, table: str, where: dict | None = None) -> dict:
-        types = _col_types(table)
+        types = schema.column_types(table)
         cols = list(types)
         keyp = schema.primary_key(table)[:_PART[table]]
         if where and all(k in where for k in keyp):

@@ -25,6 +25,7 @@ import threading
 from firebird_tpu.obs import logger
 from firebird_tpu.obs import metrics as obs_metrics
 from firebird_tpu.obs import tracing
+from firebird_tpu.store import schema
 
 log = logger("change-detection")
 
@@ -84,6 +85,7 @@ class AsyncWriter:
                     with tracing.activate(ctx):
                         with tracing.span(
                                 "store_write", table=table,
+                                bands=len(schema.band_prefixes(frame)),
                                 histogram=obs_metrics.histogram(
                                     "store_write_seconds"),
                                 cpu_histogram=obs_metrics.histogram(
@@ -94,10 +96,14 @@ class AsyncWriter:
                                     lambda: self.store.write(table, frame))
                             else:
                                 self.store.write(table, frame)
+                    rows = _frame_rows(frame)
                     obs_metrics.counter(
                         "store_rows_written",
-                        help="rows landed in the results store").inc(
-                        _frame_rows(frame))
+                        help="rows landed in the results store").inc(rows)
+                    obs_metrics.counter(
+                        "store_values_written",
+                        help="cells landed in the results store (rows x "
+                             "columns of each frame)").inc(rows * len(frame))
             except BaseException as e:  # incl. KeyboardInterrupt: a dead
                 # worker with un-acked items would hang flush() forever
                 log.error("async write to %s failed: %s", table, e)

@@ -7,6 +7,7 @@ import numpy as np
 
 from firebird_tpu.ccd import format as fmt
 from firebird_tpu.ccd import params
+from firebird_tpu.ccd.sensor import LANDSAT_ARD
 
 
 def test_format_golden():
@@ -30,7 +31,7 @@ def test_format_golden():
                 "chprob": fval, "curqa": fval,
                 "dates": [iso(sday), iso(bday), iso(eday)],
                 "mask": [0, 1, 0]}
-    for p in fmt.BAND_PREFIX:
+    for p in LANDSAT_ARD.store_prefixes:
         expected[f"{p}mag"] = fval
         expected[f"{p}rmse"] = fval
         expected[f"{p}coef"] = (fval, fval)

@@ -2,11 +2,15 @@
 geometry (BASELINE.json config #5 — Sentinel-2 12-band, 10 m, 300x300-pixel
 chips), with Landsat ARD as the default spec."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from firebird_tpu.ccd import format as ccdformat
 from firebird_tpu.ccd import kernel, params
-from firebird_tpu.ccd.sensor import LANDSAT_ARD, SENTINEL2, chi2_thresholds
+from firebird_tpu.ccd.sensor import (LANDSAT_ARD, SENSORS, SENTINEL2,
+                                     chi2_thresholds)
 from firebird_tpu.ccd.synthetic import means_amps
 from firebird_tpu.ingest import SyntheticSource, pack
 from firebird_tpu.ingest.packer import PackedChips
@@ -39,6 +43,12 @@ def test_sensor_specs_consistent():
     assert [names[i] for i in SENTINEL2.detection_bands] == \
         ["green", "red", "nir", "swir1", "swir2"]
     assert [names[i] for i in SENTINEL2.tmask_bands] == ["green", "swir1"]
+    # one distinct segment-column prefix per band, Landsat's the reference's
+    for s in SENSORS.values():
+        assert len(set(s.store_prefixes)) == len(s.store_prefixes) \
+            == s.n_bands
+    assert LANDSAT_ARD.store_prefixes == ("bl", "gr", "re", "ni", "s1", "s2",
+                                          "th")
 
 
 def test_means_amps_sized_to_sensor():
@@ -127,3 +137,138 @@ def test_mixed_sensor_pack_rejected():
         assert "sensor" in str(e)
     else:
         raise AssertionError("mixed-sensor pack must be rejected")
+
+
+# ---------------------------------------------------------------------------
+# Sentinel-2 rows: kernel -> format -> store, against the float64 reference
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def s2_batch():
+    """Two pixel-sliced Sentinel-2 chips, every pixel breaking, and their
+    float64 kernel result, fetched to the host."""
+    src = SyntheticSource(seed=6, start="1995-01-01", end="2000-01-01",
+                          sensor=SENTINEL2, change_frac=1.0, cloud_frac=0.1)
+    p = slice_pixels(pack([src.chip(3000 * i, 30000) for i in range(2)],
+                          bucket=32), 48)
+    return p, jax.device_get(kernel.detect_packed(p, dtype=jnp.float64))
+
+
+def _same(a, b):
+    if a is None or b is None:
+        return a is None and b is None
+    if isinstance(a, (list, tuple, np.ndarray)):
+        return np.array_equal(np.asarray(a), np.asarray(b))
+    return a == b or (a != a and b != b)
+
+
+def test_s2_batch_frames_match_chip_frames(s2_batch):
+    """At 12 bands the one-pass batch formatter equals the per-chip path
+    on every column, and the segment rows carry the twelve prefixes."""
+    p, host = s2_batch
+    assert (np.asarray(host.n_segments) >= 2).any()     # breaks among them
+    out = ccdformat.batch_frames(p, host)
+    assert len(out) == 2
+    for c, (_, frames) in enumerate(out):
+        ref = ccdformat.chip_frames(p, c, kernel.chip_slice(host, c,
+                                                             to_host=True))
+        for table in ("chip", "pixel", "segment"):
+            assert list(frames[table]) == list(ref[table])
+            for col in ref[table]:
+                assert all(_same(a, b) for a, b in zip(
+                    frames[table][col], ref[table][col])), (table, col)
+        assert [k[:-4] for k in frames["segment"] if k.endswith("coef")] \
+            == list(SENTINEL2.store_prefixes)
+
+
+def test_s2_stored_rows_agree_with_the_reference(s2_batch, tmp_path):
+    """Every pixel's stored Sentinel-2 rows, written through the drivers'
+    egress tail into sqlite, equal the float64 reference's records
+    formatted under the same prefixes: decisions exactly, the model
+    values to float64 rounding."""
+    from firebird_tpu.ccd.reference import detect_sensor
+    from firebird_tpu.driver import core
+    from firebird_tpu.obs import metrics as obs_metrics
+    from firebird_tpu.store import AsyncWriter, SqliteStore
+
+    p, host = s2_batch
+    obs_metrics.reset_registry()
+    store = SqliteStore(str(tmp_path / "s2.db"), "ks")
+    writer = AsyncWriter(store)
+    try:
+        core.write_batch_frames(p, host, 2, writer=writer)
+        writer.flush()
+        snap = obs_metrics.get_registry().snapshot()
+        assert snap["gauges"]["store_segment_columns"] == 58
+        rows = store.count("segment")
+        assert snap["counters"]["store_values_written"] == \
+            rows * 58 + 2 * 48 * 5 + 2 * 3
+        P = host.n_segments.shape[1]
+        for c in range(2):
+            T = int(p.n_obs[c])
+            dates = p.dates[c][:T]
+            for i, (px, py) in enumerate(p.pixel_coords(c)[:P]):
+                cx, cy = (int(v) for v in p.cids[c])
+                key = dict(cx=cx, cy=cy, px=int(px), py=int(py))
+                got = store.read("segment", key)
+                want = ccdformat.format_records(
+                    cx, cy, px, py, dates,
+                    detect_sensor(dates, p.spectra[c, :, i, :T],
+                                  p.qas[c, i, :T], SENTINEL2),
+                    sensor=SENTINEL2)
+                assert len(got["sday"]) == len(want)
+                order = np.argsort(got["sday"], kind="stable")
+                for j, w in zip(order, sorted(want,
+                                              key=lambda r: r["sday"])):
+                    for k in ("sday", "eday", "bday", "curqa"):
+                        assert got[k][j] == w[k], (key, k)
+                    for k, v in w.items():
+                        if k[:-3] in SENTINEL2.store_prefixes \
+                                or k[:-4] in SENTINEL2.store_prefixes \
+                                or k == "chprob":
+                            g = got[k][j]
+                            if v is None:
+                                assert g is None, (key, k)
+                            else:
+                                np.testing.assert_allclose(
+                                    g, v, rtol=1e-6, atol=1e-6,
+                                    err_msg=f"{key} {k}")
+                mask = store.read("pixel", key)["mask"][0]
+                assert list(mask) == list(want[0]["mask"])
+    finally:
+        writer.close()
+        store.close()
+
+
+@pytest.mark.parametrize("P, blocks", [(90000, 9), (10000, 1), (100, 1),
+                                       (20001, 3)])
+def test_lane_blocks(P, blocks):
+    assert kernel.lane_blocks(P) == blocks
+    assert P % blocks == 0 and P // blocks <= kernel.MAX_CHIP_LANES
+
+
+def test_wide_chip_runs_as_lane_blocks_with_unchanged_rows(monkeypatch):
+    """A chip wider than kernel.MAX_CHIP_LANES runs as equal lane blocks
+    on the chip axis: every per-pixel field equals the one-block run's,
+    and the chip's active lanes per round add up across its blocks."""
+    src = SyntheticSource(seed=7, start="1995-01-01", end="2000-01-01",
+                          sensor=SENTINEL2, change_frac=1.0, cloud_frac=0.1)
+    p = slice_pixels(pack([src.chip(0, 0), src.chip(3000, 0)], bucket=32),
+                     40)
+    jax.clear_caches()
+    whole = jax.device_get(kernel.detect_packed(p, dtype=jnp.float64))
+    monkeypatch.setattr(kernel, "MAX_CHIP_LANES", 16)
+    assert kernel.lane_blocks(40) == 4
+    jax.clear_caches()
+    try:
+        split = jax.device_get(kernel.detect_packed(p, dtype=jnp.float64))
+    finally:
+        jax.clear_caches()
+    for f in ("n_segments", "seg_meta", "seg_rmse", "seg_mag", "seg_coef",
+              "mask", "procedure", "vario"):
+        np.testing.assert_array_equal(getattr(split, f), getattr(whole, f),
+                                      err_msg=f)
+    assert (np.asarray(whole.n_segments) >= 2).all()
+    np.testing.assert_array_equal(split.rounds, whole.rounds)
+    np.testing.assert_array_equal(split.occupancy[..., 0],
+                                  whole.occupancy[..., 0])

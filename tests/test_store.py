@@ -436,9 +436,11 @@ def test_sqlite_chip_reads_use_secondary_index(tmp_path):
     index-backed on BOTH result tables.  The segment PK's autoindex
     already leads with (cx, cy), but the product PK leads with
     (name, date) — without idx_product_chip a per-chip product read
-    scans the whole table (backends.SqliteStore._create)."""
+    scans the whole table (backends.SqliteStore._create).  The segment
+    table is created by the store's first segment frame."""
     store = SqliteStore(str(tmp_path / "idx.db"), "ks")
     try:
+        store.write("segment", seg_frame())
         con = store._conn()
         for table in ("segment", "product"):
             plan = " ".join(
@@ -549,3 +551,168 @@ def test_read_only_replica_does_not_block_live_writer(tmp_path):
             t.join(5)
         replica.close()
         store.close()
+
+
+# ---------------------------------------------------------------------------
+# The segment table keyed by sensor: Landsat's pinned, one sensor a store
+# ---------------------------------------------------------------------------
+
+# The reference's segment table (ccdc/segment.py:16-56, schema.cql:103-142)
+# as this store has always written it: names, order and types.
+LANDSAT_SEGMENT_COLUMNS = [
+    ("cx", "INTEGER"), ("cy", "INTEGER"), ("px", "INTEGER"),
+    ("py", "INTEGER"), ("sday", "TEXT"), ("eday", "TEXT"), ("bday", "TEXT"),
+    ("chprob", "REAL"), ("curqa", "INTEGER"),
+    ("blmag", "REAL"), ("blrmse", "REAL"), ("blcoef", "F64S"),
+    ("blint", "REAL"),
+    ("grmag", "REAL"), ("grrmse", "REAL"), ("grcoef", "F64S"),
+    ("grint", "REAL"),
+    ("remag", "REAL"), ("rermse", "REAL"), ("recoef", "F64S"),
+    ("reint", "REAL"),
+    ("nimag", "REAL"), ("nirmse", "REAL"), ("nicoef", "F64S"),
+    ("niint", "REAL"),
+    ("s1mag", "REAL"), ("s1rmse", "REAL"), ("s1coef", "F64S"),
+    ("s1int", "REAL"),
+    ("s2mag", "REAL"), ("s2rmse", "REAL"), ("s2coef", "F64S"),
+    ("s2int", "REAL"),
+    ("thmag", "REAL"), ("thrmse", "REAL"), ("thcoef", "F64S"),
+    ("thint", "REAL"),
+    ("rfrawp", "F64S")]
+
+S2_PREFIXES = ("ca", "bl", "gr", "re", "r1", "r2", "r3", "ni", "n8", "wv",
+               "s1", "s2")
+
+
+def s2_frame(cx=1, cy=2, px=3, py=4):
+    """A one-row Sentinel-2 segment frame: Landsat's decision columns and
+    the twelve bands' four columns each."""
+    f = {k: v for k, v in seg_frame(cx, cy, px, py).items()
+         if not k.endswith(("mag", "rmse", "coef", "int"))}
+    for i, p in enumerate(S2_PREFIXES):
+        f[f"{p}mag"] = [float(i)]
+        f[f"{p}rmse"] = [0.5 + i]
+        f[f"{p}coef"] = [[0.1 * i, 0.2, 0.3]]
+        f[f"{p}int"] = [7.0 + i]
+    return f
+
+
+def test_landsat_segment_table_columns_are_pinned(tmp_path):
+    """Landsat's segment table is today's, column for column, in the
+    schema and in the sqlite file a Landsat run creates."""
+    from firebird_tpu.ccd.sensor import LANDSAT_ARD
+    from firebird_tpu.store import schema
+
+    assert TABLES["segment"]["columns"] == LANDSAT_SEGMENT_COLUMNS
+    assert schema.segment_columns(LANDSAT_ARD.store_prefixes) == \
+        LANDSAT_SEGMENT_COLUMNS
+    store = SqliteStore(str(tmp_path / "ls.db"), "ks")
+    try:
+        store.write("segment", seg_frame())
+        sql = {"F64S": "BLOB"}
+        got = [(r[1], r[2]) for r in store._conn().execute(
+            'PRAGMA table_info("segment")')]
+        assert got == [(c, sql.get(t, t))
+                       for c, t in LANDSAT_SEGMENT_COLUMNS]
+    finally:
+        store.close()
+
+
+@pytest.mark.parametrize("backend", ["memory", "sqlite"])
+@pytest.mark.parametrize("first", ["landsat", "sentinel2"])
+def test_a_store_holds_one_sensor(tmp_path, backend, first):
+    """The segment table takes its band columns from the first segment
+    frame; a frame of the other sensor is refused, naming both sets, and
+    nothing of it lands."""
+    frames = {"landsat": seg_frame(), "sentinel2": s2_frame(px=9)}
+    other = "sentinel2" if first == "landsat" else "landsat"
+    store = open_store(backend, str(tmp_path / "st"), "ks")
+    try:
+        store.write("segment", frames[first])
+        with pytest.raises(ValueError, match="one sensor") as e:
+            store.write("segment", frames[other])
+        assert "'th'" in str(e.value) and "'n8'" in str(e.value)
+        assert store.count("segment") == 1
+        out = store.read("segment")
+        prefixes = S2_PREFIXES if first == "sentinel2" else \
+            ("bl", "gr", "re", "ni", "s1", "s2", "th")
+        assert [c for c in out if c.endswith("coef")] == \
+            [f"{p}coef" for p in prefixes]
+        # a frame with no band column writes none and passes either way
+        store.write("segment", {k: v for k, v in seg_frame(px=7).items()
+                                if not k.endswith(("mag", "rmse", "coef",
+                                                   "int"))})
+        assert store.count("segment") == 2
+    finally:
+        store.close()
+
+
+def test_sqlite_reopen_takes_the_bands_of_its_table(tmp_path):
+    """A reopened store (writer or read-only replica) reads its segment
+    columns from the table on disk, and keeps refusing the other
+    sensor."""
+    path = str(tmp_path / "s2.db")
+    store = SqliteStore(path, "ks")
+    store.write("segment", s2_frame())
+    store.close()
+    again = SqliteStore(path, "ks")
+    replica = open_store("sqlite", path, "ks", read_only=True)
+    try:
+        for s in (again, replica):
+            out = s.read("segment")
+            assert out["n8coef"] == [[0.8, 0.2, 0.3]]
+            assert out["wvint"] == [16.0]
+            assert "thcoef" not in out
+        with pytest.raises(ValueError, match="one sensor"):
+            again.write("segment", seg_frame(px=5))
+    finally:
+        again.close()
+        replica.close()
+
+
+def test_sqlite_reads_before_the_first_segment_frame(tmp_path):
+    """Until its first segment frame a store has no segment table: reads
+    see an empty Landsat table, and the other tables are there."""
+    store = SqliteStore(str(tmp_path / "e.db"), "ks")
+    try:
+        assert store.count("segment") == 0
+        assert store.chip_ids("segment") == set()
+        assert store.read("segment", {"cx": 1})["blcoef"] == []
+        assert store.count("pixel") == 0
+    finally:
+        store.close()
+
+
+@pytest.mark.parametrize("backend", ["parquet", "cassandra", "object"])
+def test_landsat_only_backends_refuse_sentinel2(tmp_path, backend,
+                                                 monkeypatch):
+    """Parquet, Cassandra and the object tier keep Landsat's segment
+    columns: a Sentinel-2 frame is refused before any row lands."""
+    if backend == "cassandra":
+        store = CassandraStore(keyspace="ks", session=FakeCqlSession())
+    else:
+        monkeypatch.setenv("FIREBIRD_OBJECT_ROOT", str(tmp_path / "obj"))
+        store = open_store(backend, str(tmp_path / "st"), "ks")
+    with pytest.raises(ValueError, match="one sensor"):
+        store.write("segment", s2_frame())
+    store.write("segment", seg_frame())
+    assert store.count("segment") == 1
+
+
+@pytest.mark.parametrize("reader", ["products", "features"])
+def test_landsat_readers_refuse_sentinel2_segments(tmp_path, reader):
+    """Readers that name Landsat's band columns refuse a Sentinel-2
+    store's segments instead of reading NULLs."""
+    from firebird_tpu import products
+    from firebird_tpu.rf import features
+
+    store = SqliteStore(str(tmp_path / "r.db"), "ks")
+    try:
+        store.write("segment", s2_frame(cx=0, cy=3000, px=0, py=3000))
+        seg = store.read("segment")
+    finally:
+        store.close()
+    with pytest.raises(ValueError, match="Landsat ARD's segment columns"):
+        if reader == "products":
+            products.ChipSegmentArrays(0, 3000, seg)
+        else:
+            features.assemble(seg, {}, 0, 3000)

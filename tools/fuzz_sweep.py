@@ -9,7 +9,7 @@ pixel.  The numbers cited in docs/ARCHITECTURE.md (§parity audit) come
 from runs of this tool.
 
     python tools/fuzz_sweep.py --seeds 1000:1036            # Landsat
-    python tools/fuzz_sweep.py --seeds 3000:3016 --sensor sentinel2
+    python tools/fuzz_sweep.py --seeds 3000:3016 --sensor sentinel2-l2a
     python tools/fuzz_sweep.py --seeds 1000:1018 --compare-f32
 
 The docs' published envelope came from: Landsat seeds 1000:1036,
