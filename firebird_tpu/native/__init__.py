@@ -6,7 +6,8 @@ ever loads a library built from its own source); every entry point has
 a NumPy fallback, so the package works — just slower — where no C++
 toolchain exists.  ``available()`` reports which path is active;
 FIREBIRD_NO_NATIVE=1 forces the fallback (the test suite uses this to
-cover both).
+cover both).  The sqlite store's bulk insert (sqlite.py, sqlitebulk.cpp)
+is built the same way, as a library of its own.
 """
 
 from __future__ import annotations
@@ -30,23 +31,26 @@ _lib = None  # guarded-by: _lock
 _tried = False  # guarded-by: _lock
 
 
-def _lib_path() -> str:
-    """The library built from the current fastpack.cpp.  Keyed on the
-    source's content, not its mtime: a copied checkout carries a stale
-    untracked .so whose mtime says nothing about what it was built from."""
-    with open(_SRC, "rb") as f:
+def _lib_path(src: str | None = None) -> str:
+    """The library built from the current ``src`` (fastpack.cpp by
+    default).  Keyed on the source's content, not its mtime: a copied
+    checkout carries a stale untracked .so whose mtime says nothing about
+    what it was built from."""
+    src = src or _SRC
+    with open(src, "rb") as f:
         digest = hashlib.sha256(f.read()).hexdigest()[:16]
-    return os.path.join(_HERE, f"libfastpack-{digest}.so")
+    stem = os.path.splitext(os.path.basename(src))[0]
+    return os.path.join(_HERE, f"lib{stem}-{digest}.so")
 
 
-def _build(lib_path: str) -> bool:
+def _build(lib_path: str, src: str | None = None, link=()) -> bool:
     # Compile to a process-private temp path and rename into place: the
     # in-process lock doesn't cover concurrent builds from sibling worker
     # processes, and rename() is atomic so nobody ever dlopens a
     # half-written library.
     tmp = f"{lib_path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           _SRC, "-o", tmp]
+           src or _SRC, *link, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.rename(tmp, lib_path)
@@ -57,6 +61,23 @@ def _build(lib_path: str) -> bool:
         except OSError:
             pass
         return False
+
+
+def open_library(src: str | None = None, link=()):
+    """ctypes handle of the library built from ``src`` (fastpack.cpp by
+    default), compiling it first where no library of that source exists;
+    ``link`` names extra objects to link against.  None where it cannot
+    be built or loaded."""
+    try:
+        lib_path = _lib_path(src)
+    except OSError:
+        return None
+    if not os.path.exists(lib_path) and not _build(lib_path, src, link):
+        return None
+    try:
+        return ctypes.CDLL(lib_path)
+    except OSError:
+        return None
 
 
 def _load():
@@ -72,15 +93,8 @@ def _load():
 
         if env_knob("FIREBIRD_NO_NATIVE"):
             return None
-        try:
-            lib_path = _lib_path()
-        except OSError:
-            return None
-        if not os.path.exists(lib_path) and not _build(lib_path):
-            return None
-        try:
-            lib = ctypes.CDLL(lib_path)
-        except OSError:
+        lib = open_library()
+        if lib is None:
             return None
         i64, u8p = ctypes.c_int64, ctypes.POINTER(ctypes.c_uint8)
         i16p = ctypes.POINTER(ctypes.c_int16)

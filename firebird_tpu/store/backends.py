@@ -22,8 +22,13 @@ import time
 
 import numpy as np
 
+from firebird_tpu.native import sqlite as native_sqlite
 from firebird_tpu.obs import metrics as obs_metrics
 from firebird_tpu.store import schema
+
+# Seconds a write waits on another connection's lock before failing:
+# the Python connections' and the native writer's alike.
+_BUSY_TIMEOUT_S = 60
 
 
 def _retry_locked(fn, attempts: int = 240, delay: float = 0.25):
@@ -112,6 +117,130 @@ def _encode_column(frame: dict, c: str, typ: str, n: int) -> list:
     return out
 
 
+def _native_columns(frame: dict, types: dict, n: int):
+    """The frame's bind buffers in column order, for one native call; None
+    where any column has none (the frame then binds in Python) or the
+    native library is not available."""
+    if not n or not native_sqlite.available():
+        return None
+    cols = []
+    for c, typ in types.items():
+        col = _native_column(frame, c, typ, n)
+        if col is None:
+            return None
+        cols.append(col)
+    return cols
+
+
+def _native_column(frame: dict, c: str, typ: str, n: int):
+    """Column ``c`` as native bind buffers (native/sqlite.Column) that bind
+    exactly what ``_encode_column`` binds; None where the column has no
+    such form (JSON, a list, TEXT that is not ``str``, packed rows of
+    unequal length), and the frame then keeps the Python path."""
+    if c not in frame:
+        return native_sqlite.Column("null")
+    a = frame[c]
+    if typ == "JSON" or not isinstance(a, np.ndarray) or a.shape[:1] != (n,):
+        return None
+    if typ in schema.PACKED_DTYPES:
+        return _native_packed(a, schema.PACKED_DTYPES[typ], n)
+    col = _native_scalar(a, n)
+    if col is not None and typ == "TEXT" and col.kind not in ("text",
+                                                             "null"):
+        return None             # TEXT binds str alone natively
+    return col
+
+
+def _native_scalar(a: np.ndarray, n: int):
+    """A scalar column bound by its values' type, as sqlite3 binds them."""
+    if a.dtype == object:
+        return _native_object(a, n)
+    if a.dtype.kind in "iub":
+        if a.dtype.kind == "u" and a.dtype.itemsize == 8 \
+                and a.max() >= 2**63:
+            return None             # sqlite3 refuses it too
+        return native_sqlite.Column("int", np.ascontiguousarray(a, np.int64))
+    if a.dtype.kind == "f":
+        return native_sqlite.Column("real",
+                                    np.ascontiguousarray(a, np.float64))
+    if a.dtype.kind == "U":
+        return _native_text(a.tolist(), None, n)
+    return None
+
+
+def _native_object(a: np.ndarray, n: int):
+    """An object column of one cell type (None = NULL): int, float (NaN =
+    NULL) or str."""
+    nulls = np.fromiter((v is None for v in a), bool, n)
+    kinds = set(map(type, a[~nulls]))
+    if not kinds:
+        return native_sqlite.Column("null")
+    if all(issubclass(k, str) for k in kinds):
+        return _native_text(a[~nulls].tolist(), nulls, n)
+    if all(issubclass(k, (int, np.integer)) and not issubclass(k, bool)
+           for k in kinds):
+        data = np.zeros(n, np.int64)
+        try:
+            data[~nulls] = a[~nulls].astype(np.int64)
+        except OverflowError:
+            return None
+        return native_sqlite.Column("int", data, nulls=nulls)
+    if all(issubclass(k, (float, np.floating)) for k in kinds):
+        data = np.full(n, np.nan)
+        data[~nulls] = a[~nulls].astype(np.float64)
+        return native_sqlite.Column("real", data)
+    return None
+
+
+def _offsets(lengths: np.ndarray, nulls, n: int) -> np.ndarray:
+    """Row offsets into a column's bytes: non-NULL rows hold ``lengths``
+    in order, NULL rows none."""
+    per_row = np.zeros(n, np.int64)
+    if nulls is None:
+        per_row[:] = lengths
+    else:
+        per_row[~nulls] = lengths
+    out = np.zeros(n + 1, np.int64)
+    np.cumsum(per_row, out=out[1:])
+    return out
+
+
+def _native_text(strs: list, nulls, n: int):
+    """TEXT: the non-NULL strings as one UTF-8 buffer and row offsets."""
+    joined = "".join(strs)
+    try:
+        raw = joined.encode("utf-8")
+    except UnicodeEncodeError:
+        return None             # sqlite3 refuses it too, on the Python path
+    if len(raw) == len(joined):     # all ASCII: bytes per char is 1
+        lengths = np.fromiter(map(len, strs), np.int64, len(strs))
+    else:
+        lengths = np.fromiter((len(s.encode("utf-8")) for s in strs),
+                              np.int64, len(strs))
+    return native_sqlite.Column("text", np.frombuffer(raw, np.uint8),
+                                _offsets(lengths, nulls, n), nulls)
+
+
+def _native_packed(a: np.ndarray, dtype, n: int):
+    """A packed column: the non-NULL rows stacked once into a C-contiguous
+    [rows, k] buffer of ``dtype``, as bytes with row offsets."""
+    if a.dtype != object:
+        rows, nulls = a.reshape(n, -1), None
+    else:
+        nulls = np.fromiter((v is None for v in a), bool, n)
+        rows = a[~nulls].tolist()
+        if not rows:
+            return native_sqlite.Column("null")
+    try:
+        buf = np.ascontiguousarray(np.asarray(rows, dtype))
+    except ValueError:          # rows of unequal length
+        return None
+    buf = buf.reshape(len(buf), -1)
+    lengths = np.full(len(buf), buf.shape[1] * buf.itemsize, np.int64)
+    return native_sqlite.Column("blob", buf.reshape(-1).view(np.uint8),
+                                _offsets(lengths, nulls, n), nulls)
+
+
 def _decode_cell(v, typ: str):
     if v is None:
         return None
@@ -193,7 +322,7 @@ class SqliteStore:
                 "does not exist (the writer creates it; replicas only "
                 "ever attach)")
         self._local = threading.local()
-        self._all_conns: list[sqlite3.Connection] = []
+        self._all_conns: list = []      # Python and native connections
         self._conns_lock = threading.Lock()
         self._bands = None      # the segment table's band prefixes, once seen
         if not self.read_only:
@@ -209,11 +338,11 @@ class SqliteStore:
                 # depth, and neither converts journal modes (a replica
                 # must never run the WAL-conversion DDL the writer owns).
                 conn = sqlite3.connect(
-                    f"file:{self.path}?mode=ro", uri=True, timeout=60,
-                    check_same_thread=False)
+                    f"file:{self.path}?mode=ro", uri=True,
+                    timeout=_BUSY_TIMEOUT_S, check_same_thread=False)
                 conn.execute("PRAGMA query_only=ON")
             else:
-                conn = sqlite3.connect(self.path, timeout=60,
+                conn = sqlite3.connect(self.path, timeout=_BUSY_TIMEOUT_S,
                                        check_same_thread=False)
                 _retry_locked(
                     lambda: conn.execute("PRAGMA journal_mode=WAL"))
@@ -226,6 +355,16 @@ class SqliteStore:
             with self._conns_lock:
                 self._all_conns.append(conn)
         return self._local.conn
+
+    def _native_conn(self) -> native_sqlite.Connection:
+        """This thread's native write connection (native/sqlite.py)."""
+        if not hasattr(self._local, "native"):
+            conn = _retry_locked(lambda: native_sqlite.Connection(
+                self.path, _BUSY_TIMEOUT_S))
+            self._local.native = conn
+            with self._conns_lock:
+                self._all_conns.append(conn)
+        return self._local.native
 
     def _create_table(self, table: str, columns) -> None:
         con = self._conn()
@@ -289,14 +428,27 @@ class SqliteStore:
         types = self._types(table)
         cols = list(types)
         n = len(next(iter(frame.values())))
+        sql = (f'INSERT OR REPLACE INTO "{table}" ({", ".join(cols)}) '
+               f'VALUES ({", ".join("?" * len(cols))})')
+        native = _native_columns(frame, types, n)
+        if native is not None:
+            self._native_conn().insert(sql, native, n)
+            obs_metrics.counter(
+                "store_rows_native",
+                help="rows a store wrote through the native bulk insert "
+                     "(of store_rows_written)").inc(n)
+            return n
         rows = list(zip(*(_encode_column(frame, c, types[c], n)
                           for c in cols)))
-        ph = ", ".join("?" * len(cols))
         con = self._conn()
-        con.executemany(
-            f'INSERT OR REPLACE INTO "{table}" ({", ".join(cols)}) VALUES ({ph})',
-            rows)
-        con.commit()
+        try:
+            con.executemany(sql, rows)
+            con.commit()
+        except Exception:
+            # No half-written frame stays open on this connection, holding
+            # the write lock against the native one.
+            con.rollback()
+            raise
         return n
 
     def read(self, table: str, where: dict | None = None) -> dict:
@@ -338,8 +490,9 @@ class SqliteStore:
                 conn.close()
             except sqlite3.Error:
                 pass
-        if hasattr(self._local, "conn"):
-            del self._local.conn
+        for name in ("conn", "native"):
+            if hasattr(self._local, name):
+                delattr(self._local, name)
 
 
 class ParquetStore:

@@ -127,3 +127,41 @@ def test_pack_uses_out_buffer():
     out = np.empty((7, 16, 8), np.int16)
     got = native.pack_spectra(src, 8, -9999, out=out)
     assert got is out
+
+
+@pytest.mark.parametrize("fault", ["build", "load", "version"])
+def test_sqlite_library_failure_falls_back_to_python(tmp_path, monkeypatch,
+                                                     fault):
+    """The sqlite bulk-insert library failing to build, failing to load,
+    or built against another sqlite than Python's leaves the store on its
+    Python path: the rows land, none of them natively."""
+    import sqlite3
+
+    from firebird_tpu.native import sqlite as native_sqlite
+    from firebird_tpu.obs import metrics as obs_metrics
+    from firebird_tpu.store import SqliteStore
+
+    monkeypatch.setattr(native, "_HERE", str(tmp_path))  # no library yet
+    monkeypatch.setattr(native_sqlite, "_lib", None)
+    monkeypatch.setattr(native_sqlite, "_tried", False)
+    monkeypatch.delenv("FIREBIRD_NO_NATIVE", raising=False)
+    if fault == "build":
+        monkeypatch.setattr(native, "_build", lambda *a, **k: False)
+    elif fault == "load":
+        with open(native._lib_path(native_sqlite._SRC), "wb") as f:
+            f.write(b"not a shared library")
+    else:
+        monkeypatch.setattr(sqlite3, "sqlite_version_info", (2, 8, 17))
+    assert not native_sqlite.available()
+    obs_metrics.reset_registry()
+    store = SqliteStore(str(tmp_path / "s.db"), "ks")
+    try:
+        store.write("pixel", {"cx": np.arange(3), "cy": np.zeros(3, int),
+                              "px": np.arange(3), "py": np.arange(3),
+                              "mask": np.ones((3, 4), np.uint8)})
+        assert store.count("pixel") == 3
+        assert store.read("pixel")["mask"] == [[1, 1, 1, 1]] * 3
+    finally:
+        store.close()
+    assert "store_rows_native" not in \
+        obs_metrics.get_registry().snapshot()["counters"]
