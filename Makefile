@@ -12,8 +12,8 @@ ACQUIRED ?= 1982-01-01/2017-12-31
 .PHONY: install lint test bench obs-smoke pipeline-smoke chaos-smoke \
         fleet-smoke elastic-smoke serve-smoke pyramid-smoke serve-fleet \
         compact-smoke postmortem-smoke alert-smoke streamfleet-smoke \
-        telemetry-smoke slo-smoke wire-smoke fuse-smoke fuse-repro \
-        precision-smoke objectstore-smoke fanout-smoke fanout-proof \
+        telemetry-smoke slo-smoke wire-smoke objectstore-smoke \
+        fanout-smoke fanout-proof \
         image db-up db-schema db-test db-down changedetection \
         classification clean
 
@@ -36,8 +36,6 @@ lint:
 test: lint
 	python -m pytest tests/ -x -q
 	$(MAKE) pyramid-smoke
-	$(MAKE) fuse-smoke
-	$(MAKE) precision-smoke
 	$(MAKE) alert-smoke
 	$(MAKE) streamfleet-smoke
 	$(MAKE) telemetry-smoke
@@ -146,28 +144,6 @@ compact-smoke:
 # artifact folded by bench.py.
 wire-smoke:
 	python tools/wire_probe.py
-
-# Fused-fit / rebalancing-ring check (docs/ROOFLINE.md "Fused fit"):
-# fused on/off dispatches byte-identical, occupancy counters still
-# moving, and the straggler ring migrating lanes row-identically on a
-# forced-ragged 2-device simulated mesh; artifact folded by bench.py.
-fuse-smoke:
-	python tools/fuse_smoke.py
-
-# Mosaic SIGABRT bisection (the r05 mega/fused-combo compiler crash):
-# compiles each multi-phase pairing in subprocesses across a lane-block
-# ladder and records the smallest failing shape as a classified,
-# bench-foldable artifact.  CPU hosts record the honest
-# interpret-only caveat.
-fuse-repro:
-	python tools/fuse_repro.py
-
-# Mixed-precision envelope check (docs/ROOFLINE.md "Precision"): mixed
-# vs f32 dispatches decision-identical (break days/QA/segment counts/
-# curve ranks), coef/rmse drift inside the pinned scaled-ulp budget,
-# and the mixed trace counter moving; artifact folded by bench.py.
-precision-smoke:
-	python tools/precision_smoke.py
 
 # Alerting end-to-end drill (docs/ALERTS.md): a streaming run over a
 # step-change archive with injected ingest faults and a SIGKILL

@@ -67,7 +67,7 @@ def scrub_artifact(obj, limit: int | None = None):
 # Autotune probe-failure classes (ordered; first match wins): a raced
 # Pallas config that fails to compile can carry a kilobytes-long compiler
 # log with the real cause buried mid-stream — round 5 shipped raw
-# JaxRuntimeError reprs for the mega/fused-combo SIGABRTs.
+# JaxRuntimeError reprs for compiler SIGABRTs.
 # classify_tune_error turns each into a short structured record so the
 # bench tail stays diagnosable AND parseable.
 _TUNE_ERR_KINDS = (
@@ -84,8 +84,8 @@ _TUNE_ERR_KINDS = (
 
 def classify_tune_error(e) -> dict:
     """One failed autotune probe -> ``{variant-diagnosable record}``:
-    the exception class, a classified ``kind`` (_TUNE_ERR_KINDS; the
-    SIGABRT'd fused combos of round 5 land as compiler-crash), and a
+    the exception class, a classified ``kind`` (_TUNE_ERR_KINDS; a
+    SIGABRT'd compile lands as compiler-crash), and a
     short ANSI-stripped ``detail`` — never the raw multi-KB repr."""
     txt = clean_text(repr(e))
     low = txt.lower()
@@ -147,11 +147,11 @@ def autotune_parity(probe_outs):
 
 
 def autotune_pick(rates, errors, decision_exact):
-    """Decision-gated autotune pick (docs/DIVERGENCE.md, mega row): a
-    config that flips ANY pixel's structural decisions vs the XLA
-    baseline on real hardware is demoted — speed never buys back a
-    broken bit-identical contract.  (CPU interpret-mode tests pin the
-    same equality; this is the compiled-Mosaic enforcement.)
+    """Decision-gated autotune pick: a config that flips ANY pixel's
+    structural decisions vs the XLA baseline on real hardware is
+    demoted — speed never buys back a broken bit-identical contract.
+    (CPU interpret-mode tests pin the same equality; this is the
+    compiled-Mosaic enforcement.)
 
     Error-skipped configs are NOT "demoted" (they have no parity entry
     because they never ran) — in the decision-gated branch they drop out
@@ -176,59 +176,12 @@ def autotune_pick(rates, errors, decision_exact):
     return (max(eligible, key=lambda k: rates[k]), [], "0" in errors)
 
 
-def repro_block_seeds() -> dict:
-    """fuse_repro.json's smallest COMPILING block per pairing — consumed
-    as the FIREBIRD_MEGA_BLOCK_P seed for the mega/mon rungs (the
-    artifact stops being advisory).  Empty when the tool never ran on a
-    Mosaic-reachable host."""
-    from firebird_tpu.config import env_knob as _ek
-
-    try:
-        with open(os.path.join(_ek("FIREBIRD_FUSE_DIR"),
-                               "fuse_repro.json")) as f:
-            rep = json.load(f)
-        if not rep.get("mosaic_reachable"):
-            return {}
-        return {k: v["smallest_ok_block"]
-                for k, v in rep.get("probes", {}).items()
-                if v.get("smallest_ok_block")}
-    except (OSError, ValueError, KeyError):
-        return {}
-
-
-def apply_tune_flag(flag: str, repro_blocks: dict | None = None) -> None:
-    """One autotune rung -> the env it means: a '+mixed' suffix (or bare
-    'mixed') arms FIREBIRD_MIXED_PRECISION; 'fused' / 'fused+<components>'
-    arms FIREBIRD_FUSED_FIT=1 and 'mon' / 'mon+<components>' the
-    whole-round fusion (FIREBIRD_FUSED_FIT=mon), each with FIREBIRD_PALLAS
-    set to the (possibly empty) component list; anything else is a plain
-    FIREBIRD_PALLAS value with both knobs off.  The mega/mon rungs also
-    seed FIREBIRD_MEGA_BLOCK_P from ``repro_blocks`` (repro_block_seeds),
-    the smallest compiling block for their pairing.  Shared by the probes
+def apply_tune_flag(flag: str) -> None:
+    """One autotune rung -> the env it means: the FIREBIRD_PALLAS value
+    ('0' for the XLA loop, else a component list).  Shared by the probes
     and the final pick so the timed run executes exactly the raced
     configuration."""
-    repro_blocks = repro_blocks or {}
-    mixed_f = flag == "mixed" or flag.endswith("+mixed")
-    base = flag[:-len("+mixed")] if flag.endswith("+mixed") \
-        else ("0" if flag == "mixed" else flag)
-    os.environ["FIREBIRD_MIXED_PRECISION"] = "1" if mixed_f else "0"
-    if base == "fused" or base.startswith("fused+"):
-        tier = "1"
-        os.environ["FIREBIRD_PALLAS"] = base[len("fused+"):] or "0"
-    elif base == "mon" or base.startswith("mon+"):
-        tier = "mon"
-        os.environ["FIREBIRD_PALLAS"] = base[len("mon+"):] or "0"
-    else:
-        tier = "0"
-        os.environ["FIREBIRD_PALLAS"] = base
-    os.environ["FIREBIRD_FUSED_FIT"] = tier
-    fam = ("mon" if tier == "mon"
-           else "mega" if "mega" in base
-           else "fused" if tier == "1"
-           else None)
-    bp = repro_blocks.get(f"{fam}+mixed" if mixed_f else fam) \
-        if fam else None
-    os.environ["FIREBIRD_MEGA_BLOCK_P"] = str(bp or 0)
+    os.environ["FIREBIRD_PALLAS"] = flag
 
 
 def _fleet_obs_fold() -> dict:
@@ -434,27 +387,6 @@ def _wire_fold() -> dict:
                           "wire_smoke.json")
 
 
-def _fuse_fold() -> dict:
-    """`make fuse-smoke` + tools/fuse_repro.py evidence: fused-on/off
-    store identity, occupancy counters moving, the forced-ragged
-    rebalance leg, and the classified SIGABRT-repro probe outcomes
-    (bisectable compiler-crash records, docs/ROOFLINE.md "Fused fit")."""
-    out = _artifact_fold("fuse_smoke", "FIREBIRD_FUSE_DIR",
-                         "fuse_smoke.json")
-    out.update(_artifact_fold("fuse_repro", "FIREBIRD_FUSE_DIR",
-                              "fuse_repro.json"))
-    return out
-
-
-def _precision_fold() -> dict:
-    """`make precision-smoke` evidence: mixed-vs-f32 store decision
-    identity, the scale-anchored coef/rmse ulp-drift histogram against
-    params.MIXED_ULP_BUDGET, and the mixed trace counters moving
-    (docs/ROOFLINE.md "Precision")."""
-    return _artifact_fold("precision_smoke", "FIREBIRD_PRECISION_DIR",
-                          "precision_smoke.json")
-
-
 def previous_round_e2e(here: str) -> dict | None:
     """The newest committed TPU evidence artifact's end-to-end figure —
     the denominator of the headline regression gate.  Scans
@@ -515,14 +447,14 @@ def measure() -> None:
         # device inside the jitted prologue (kernel.device_designs).
         return tuple(jnp.asarray(a) for a in kernel.wire_args(pk))
 
-    # ---- CD-path auto-tune (accelerator only) ----
-    # The Lasso coordinate-descent loop has two implementations: the lax
-    # fori_loop default and the Pallas VMEM-resident kernel
-    # (FIREBIRD_PALLAS=1; f32-on-TPU only).  Which is faster depends on
-    # the toolchain, so time both on a small probe chip and keep the
-    # winner for the full run.  The flag is read at trace time, and the
-    # cache between variants is cleared so each probe really compiles its
-    # own path; a Pallas crash just keeps the default.
+    # ---- Pallas component auto-tune (accelerator only) ----
+    # The monitor, score and tmask components each have an XLA and a
+    # Pallas VMEM-resident implementation (FIREBIRD_PALLAS; f32-on-TPU
+    # only).  Which is faster depends on the toolchain, so time them on
+    # a small probe chip and keep the winner for the full run.  The flag
+    # is read at trace time, and the cache between variants is cleared so
+    # each probe really compiles its own path; a Pallas crash just keeps
+    # the default.
     pallas_detail = {}
     if not small:
         import functools as _ft
@@ -540,11 +472,8 @@ def measure() -> None:
 
         probe_outs = {}
 
-        _apply_tune_flag = _ft.partial(apply_tune_flag,
-                                       repro_blocks=repro_block_seeds())
-
         def probe_rate(flag: str) -> float:
-            _apply_tune_flag(flag)
+            apply_tune_flag(flag)
             jax.clear_caches()
             f = _ft.partial(kernel._detect_batch_wire, dtype=jnp.float32,
                             wcap=kernel.window_cap(probe),
@@ -605,69 +534,15 @@ def measure() -> None:
         # Per-component tuning: each Pallas kernel races the default
         # alone, then the individually-winning set races as a combo —
         # a component that loses on this toolchain can't drag down the
-        # ones that win (kernel.use_pallas component gating).
+        # ones that win (kernel.pallas_components).
         base = safe_rate("0")
-        # The whole-loop mega kernel replaces every component at once
-        # (one pallas_call, wire spectra VMEM-resident for the entire
-        # event loop).  Race it FIRST after the baseline: it is the
-        # highest-upside candidate (the only round-count-independent
-        # bytes/pixel route, docs/ROOFLINE.md), and a session that hits
-        # the autotune deadline must have measured it rather than spent
-        # the whole budget on per-component rungs.
-        safe_rate("mega")
-        winners = [c for c in ("lasso", "monitor", "tmask", "fit", "score")
+        winners = [c for c in ("monitor", "tmask", "score")
                    if safe_rate(c) > base]
-        # 'init' races only together with 'fit': the fused INIT kernel's
-        # internal stability fit uses the Pallas Gram/CD accumulation
-        # order, so an init-without-fit pick would put borderline
-        # init_ok/init_bad decisions on a third mixed path that the
-        # divergence register would have to carry (docs/DIVERGENCE.md).
-        # No mixed config can win because no mixed config is ever raced.
-        if safe_rate("init,fit") > max(base, rates.get("fit", 0.0)):
-            if "fit" not in winners:
-                winners.append("fit")
-            winners.append("init")
         # Keys are canonicalized (sorted join) so set-equal configs are
-        # never probed twice — use_pallas splits on ',' order-insensitively.
+        # never probed twice — the component list is order-insensitive.
         combo = ",".join(sorted(winners))
-        if len(winners) > 1 and combo not in rates \
-                and not any(set(k.split(",")) == set(winners) for k in rates):
+        if len(winners) > 1 and combo not in rates:
             safe_rate(combo)
-        # Wire-resident-only mode is an interaction the per-component
-        # race can't see: only init+score+fit TOGETHER drop the widened
-        # float spectra from the loop residents.  Race it explicitly
-        # (a winners-combo of exactly those three already recorded it).
-        if not any(set(k.split(",")) == {"fit", "score", "init"}
-                   for k in rates):
-            safe_rate("fit,init,score")
-        # Fused gram→CD→close rungs (FIREBIRD_FUSED_FIT): the fit-path
-        # ladder is lax fallback ('0'), gram+Pallas-CD ('lasso'),
-        # fully-fused fit kernel ('fit') — all raced above — plus the
-        # round-fusing kernel alone and composed with the monitor/init
-        # winners (the fused kernel replaces the close+fit pair, so it
-        # composes with score/init, and '+fit' keeps the prologue's
-        # one-shot alt fits on the Pallas fit kernel too).
-        safe_rate("fused")
-        safe_rate("fused+fit")
-        fw = ",".join(sorted(set(winners) | {"fit"}))
-        if f"fused+{fw}" not in rates:
-            safe_rate(f"fused+{fw}")
-        # Whole-round fusion (FIREBIRD_FUSED_FIT=mon): monitor+fit+close
-        # in ONE VMEM residency per round.  Raced bare and composed with
-        # the Pallas fit prologue like the fused rungs above.
-        safe_rate("mon")
-        safe_rate("mon+fit")
-        # Mixed-precision rungs (FIREBIRD_MIXED_PRECISION): bf16
-        # split-dot gram + int32 counts inside the Pallas fit routes
-        # with the f32 decision envelope.  Raced on the strongest
-        # Pallas-fit families only (mixed is a no-op on XLA routes);
-        # any decision flip is caught by autotune_parity below and the
-        # config demoted by autotune_pick.
-        safe_rate("fit+mixed")
-        safe_rate("mega+mixed")
-        safe_rate("mon+fit+mixed")
-        if f"fused+{fw}+mixed" not in rates:
-            safe_rate(f"fused+{fw}+mixed")
         parity, decision_exact = autotune_parity(probe_outs)
         pick, demoted, parity_unavailable = autotune_pick(
             rates, errors, decision_exact)
@@ -678,15 +553,8 @@ def measure() -> None:
             **({"parity_unavailable": True} if parity_unavailable else {}),
             **({"probe_parity_vs_xla": parity} if parity else {}),
             **({"errors": errors} if errors else {})}}
-        _apply_tune_flag(pick)
+        apply_tune_flag(pick)
         jax.clear_caches()
-
-    def _mega_fits_shape(pk, wcap_, seg_) -> bool:
-        from firebird_tpu.ccd import pallas_ops
-
-        return pallas_ops.mega_fits(
-            int(pk.spectra.shape[-1]), wcap_, pk.sensor.n_bands,
-            int(np.asarray(seg_.seg_meta).shape[-2]), 2)
 
     def timed_rate(run_fn, run_args, pixels, n_runs):
         """Steady-state pixels/sec: compile+warmup run, then timed runs.
@@ -870,15 +738,7 @@ def measure() -> None:
         phase_rounds=phase_rounds,
         # Model the picked FIREBIRD_PALLAS config's actual streams (the
         # autotune sets the env before the timed run); wire int16 = 2 B.
-        # 'mega' is modeled only when this dispatch shape passes the
-        # VMEM guard — a refused mega runs the XLA loop, and modeling
-        # one-pass traffic for it would overstate the ceiling ~100x.
-        pallas=frozenset(
-            [c for c in ("score", "init", "fit", "mega")
-             if kernel.use_pallas(c)
-             and (c != "mega" or _mega_fits_shape(packed, wcap, seg))]
-            + (["fused"] if kernel.use_fused_fit() else [])),
-        wire_bytes=2, mixed=kernel.use_mixed_precision())
+        pallas=kernel.pallas_components(), wire_bytes=2)
 
     # ---- rebalance: straggler-idle model + what the ring moved ----
     # Per-device round counts bound the idle a perfect balancer could
@@ -1094,13 +954,6 @@ def measure() -> None:
             # Last compact-smoke evidence (stores identical on vs off,
             # wasted lane-rounds reduced) when one ran on this host.
             **_compact_fold(),
-            # Last fuse-smoke / fuse-repro evidence (fused on/off store
-            # identity, forced-ragged rebalance leg, classified
-            # compiler-crash probe records) when one ran on this host.
-            **_fuse_fold(),
-            # Last precision-smoke evidence (mixed-vs-f32 decision
-            # identity + scaled-ulp drift histogram) when one ran here.
-            **_precision_fold(),
             # Last `make lint` summary (contract-checker clean flag +
             # per-rule counts) when the linter ran on this host.
             **_lint_fold(),

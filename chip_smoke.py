@@ -207,8 +207,7 @@ def main_phase(devs) -> None:
     os.environ.update(FIREBIRD_SOURCE="synthetic",
                       FIREBIRD_STORE_BACKEND="sqlite",
                       FIREBIRD_SYNTH_SENSOR="landsat-ard",
-                      FIREBIRD_PALLAS="0", FIREBIRD_FUSED_FIT="0",
-                      FIREBIRD_MIXED_PRECISION="0")
+                      FIREBIRD_PALLAS="0")
     say(f"native fastpack available: {native.available()}")
     cfg = core.resolve_batching(Config.from_env(), ACQUIRED)
     say(f"compile cache: {core.setup_compile_cache()}")
@@ -260,12 +259,9 @@ def main_phase(devs) -> None:
         f"{r1['counters'].get('compile_cache_misses', 0)}"
         f"/{c.get('compile_cache_misses', 0)} (run 1/run 2), "
         f"warm_compiles={r1['counters'].get('warm_compiles', 0)}")
-    route = [comp for comp in kernel._PALLAS_COMPONENTS
-             if kernel.use_pallas(comp)]
+    route = sorted(kernel.pallas_components())
     say(f"  kernel route: {'pallas ' + ','.join(route) if route else 'xla'}"
-        f", fused_fit={kernel.fused_mode() or 0}, "
-        f"mixed={int(kernel.use_mixed_precision())}, "
-        f"compaction={int(params.compact_default())}, backend="
+        f", compaction={int(params.compact_default())}, backend="
         f"{jax.default_backend()}, kernel_pallas_refused="
         f"{c.get('kernel_pallas_refused', 0)}")
     say(f"  store (run 2): {r2['rows']}")

@@ -84,8 +84,7 @@ def _sort_flops(rows: float, n: int) -> float:
     return 3.0 * rows * n * stages
 
 
-def round_flops(P: int, T: int, W: int, sensor=LANDSAT_ARD,
-                mixed: bool = False) -> dict:
+def round_flops(P: int, T: int, W: int, sensor=LANDSAT_ARD) -> dict:
     """FLOPs of one event-horizon round over P pixels (kernel.body).
 
     Terms are grouped by the cond gate that executes them (kernel
@@ -93,13 +92,6 @@ def round_flops(P: int, T: int, W: int, sensor=LANDSAT_ARD,
     initializing pixel, ``close`` on rounds closing a segment, ``refit``
     on rounds fitting a model, ``monitor`` every round.  ``total`` is
     the ungated (every-round) sum — the pre-gating upper bound.
-
-    ``mixed`` (FIREBIRD_MIXED_PRECISION) adds a ``mixed`` sub-dict
-    modeling the bf16 split-dot gram (pallas_ops._gram_cd_core): the
-    useful arithmetic is UNCHANGED (total stays comparable across
-    rungs), but the Gram/corr dots execute 2 and 3 bf16 MXU passes
-    instead of f32-"highest"'s 6, with bf16 (half-width) operands —
-    bench_detail turns that into the mixed compute ceiling.
     """
     B = sensor.n_bands
     D = len(sensor.detection_bands)
@@ -121,30 +113,10 @@ def round_flops(P: int, T: int, W: int, sensor=LANDSAT_ARD,
              + _sort_flops(P * B, params.PEEK_SIZE))         # mags median
     refit = _lasso_fit_flops(P, T, B, with_rmse=True)       # cfull
     init = init_fit + init_resid + tmask + onehot_w
-    out = {"init_fit": init_fit, "init_resid": init_resid,
-           "tmask": tmask, "onehot": onehot_w, "monitor": monitor,
-           "close": close, "refit": refit, "init": init,
-           "total": init + monitor + close + refit}
-    if mixed:
-        # Per firing fit (the INIT stability fit and the shared refit
-        # each contain one Gram + one corr): the useful dot FLOPs that
-        # move from the f32-"highest" MXU schedule (6 bf16 passes per
-        # dot) to the split-dot schedule (Gram 2 — 0/1 weights are
-        # bf16-exact; corr 3 — lo·lo dropped).  Everything else (CD
-        # loop, RMSE, monitor, Tmask, medians) stays f32: the decision
-        # envelope.
-        gram_dot = 2.0 * P * T * K * K
-        corr_dot = 2.0 * P * B * T * K
-        out["mixed"] = {
-            "gram_dot_flops": gram_dot, "corr_dot_flops": corr_dot,
-            "mxu_passes_f32": 6, "mxu_passes_gram": 2,
-            "mxu_passes_corr": 3,
-            "gram_operand_bytes_ratio": 0.5,    # bf16 vs f32 operands
-            "dot_stage_speedup_model": round(
-                6.0 * (gram_dot + corr_dot)
-                / (2.0 * gram_dot + 3.0 * corr_dot), 2),
-        }
-    return out
+    return {"init_fit": init_fit, "init_resid": init_resid,
+            "tmask": tmask, "onehot": onehot_w, "monitor": monitor,
+            "close": close, "refit": refit, "init": init,
+            "total": init + monitor + close + refit}
 
 
 def setup_flops(P: int, T: int, sensor=LANDSAT_ARD) -> float:
@@ -161,14 +133,13 @@ def setup_flops(P: int, T: int, sensor=LANDSAT_ARD) -> float:
 
 def detect_flops(P: int, T: int, W: int, rounds: float,
                  sensor=LANDSAT_ARD,
-                 phase_rounds: tuple | None = None,
-                 mixed: bool = False) -> dict:
+                 phase_rounds: tuple | None = None) -> dict:
     """Total kernel FLOPs for one dispatch and the per-pixel figure.
 
     ``phase_rounds`` = (init_rounds, fit_rounds, close_rounds) — the
     measured cond-gate execution counts (ChipSegments.round_counts).
     None models the ungated loop (every block every round)."""
-    r = round_flops(P, T, W, sensor, mixed=mixed)
+    r = round_flops(P, T, W, sensor)
     ir, fr, cr = phase_rounds if phase_rounds is not None \
         else (rounds, rounds, rounds)
     total = (r["monitor"] * rounds + r["init"] * ir + r["refit"] * fr
@@ -182,17 +153,8 @@ def round_bytes(P: int, T: int, W: int, S: int, dtype_bytes: int,
                 rounds: float = 1.0,
                 phase_rounds: tuple | None = None,
                 pallas: frozenset | set | tuple = (),
-                wire_bytes: int = 2, mixed: bool = False) -> float:
+                wire_bytes: int = 2) -> float:
     """Estimated HBM traffic (read+write) over the event loop.
-
-    ``mixed`` (FIREBIRD_MIXED_PRECISION): the HBM model is mixed-
-    INVARIANT on every route the knob actually reaches — the Pallas fit
-    kernels stream the wire int16 spectra either way, and the bf16 gram
-    operands live at the VMEM→MXU boundary, not in HBM (their halved
-    bytes are modeled in round_flops' ``mixed`` block and fold into
-    bench_detail's mixed compute ceiling).  The parameter is accepted so
-    call sites can pass the picked config through uniformly; it changes
-    no HBM term by design, and this docstring is the written argument.
 
     Per-phase apportionment mirrors the kernel's cond gates
     (_detect_batch_impl): the score-group spectra read, the [P,T]
@@ -205,43 +167,20 @@ def round_bytes(P: int, T: int, W: int, S: int, dtype_bytes: int,
     ``pallas`` names the enabled Pallas components (the bench's picked
     FIREBIRD_PALLAS config): a component's term is then modeled from its
     kernel's actual block streams (in/out BlockSpecs — known exactly,
-    unlike the XLA estimate) instead of the XLA path's materializations:
+    unlike the XLA estimate) instead of the XLA path's materializations.
+    Only 'score' moves a term ('monitor' and 'tmask' keep the XLA
+    estimate):
 
     - 'score': the monitor round streams the [D,T,P] *wire-dtype*
       spectra once and 4 [T,P] i32 planes (alive/included in, inc/rem_q
       out); the [P,D,T] prediction einsum, the f32 score plane and the
       rank planes never exist in HBM (pallas_ops._monitor_scored_block).
-    - 'init': the INIT round streams the [B,T,P] wire spectra + ~3
-      [T,P] i32 planes (alive in/out, w_stab out); the [P,W,T] one-hot
-      tensors and the stability fit's float Y re-read never exist
-      (pallas_ops._init_window_block).
-    - 'fit': the refit streams the [B,T,P] wire spectra + the [T,P]
-      window plane; Gram/corr/CD/RMSE stay in VMEM
-      (pallas_ops._fit_block).
-    - 'fused': the FIREBIRD_FUSED_FIT gram→CD→close kernel
-      (pallas_ops._fused_fit_close_block) — the close + shared-fit pair
-      runs on ONE wire-spectra residency per fit round, and the close's
-      buffer rewrite crosses the kernel boundary once instead of
-      round-tripping the [P,S*k] planes plus the PEEK-run one-hot
-      tensors.  Close-only rounds (the shared tail close) still pay one
-      buffer boundary; the rare break round adds kernel._close_mags'
-      spectra read, modeled under the close term.
     """
     B = sensor.n_bands
     D = len(sensor.detection_bands)
     ir, fr, cr = phase_rounds if phase_rounds is not None \
         else (rounds, rounds, rounds)
     pallas = frozenset(pallas)
-    if "mega" in pallas:
-        # Whole-loop kernel (pallas_ops._detect_mega_block): the event
-        # loop's HBM traffic is ROUND-INDEPENDENT — one [B,T,P] wire
-        # read, the start-state planes in (alive + phase/cursor vectors),
-        # and the result-buffer/final-alive boundary out.  Matches the
-        # mega pallas_call's in/out BlockSpecs term by term.
-        return (P * B * T * wire_bytes          # wire spectra, once
-                + 2 * 4.0 * P * T               # alive0 in + alive out
-                + 8.0 * P                        # i32 state vectors
-                + 2.0 * P * S * (6 + 2 * B + B * K) * dtype_bytes)
     # carried loop state: alive/included bool planes + coefs, read+written
     carry = 2 * (2.0 * P * T + P * B * K * dtype_bytes)
     if "score" in pallas:
@@ -252,33 +191,15 @@ def round_bytes(P: int, T: int, W: int, S: int, dtype_bytes: int,
         # on close rounds — unchanged cond pass-through aliases in place)
         every = (1.0 * P * D * T * dtype_bytes
                  + 10.0 * P * T * dtype_bytes + 6.0 * P * T + carry)
-    if "init" in pallas:
-        init = P * B * T * wire_bytes + 12.0 * P * T
-    else:
-        # oh_w bool written+read + float view read by the two selection
-        # matmuls + window members/XtXt + the c4 fit's Y read
-        init = (3.0 * P * W * T
-                + 3.0 * P * W * T * dtype_bytes
-                + 2.0 * P * W * (NT + B + NT * NT) * dtype_bytes
-                + P * B * T * dtype_bytes)
-    if "fused" in pallas:
-        # The fused kernel reads the wire spectra once per firing round
-        # and serves BOTH the fit and the close row write from it; the
-        # buffer planes stream through the kernel boundary (in + out)
-        # instead of the XLA path's oh_run tensors + cond round-trips.
-        # The pre-fusion model charged the spectra twice (fit + close
-        # oh_run) — the satellite bugfix this branch exists for: an
-        # unfused byte model here overstated the fused path's traffic
-        # and understated its intensity/MFU.
-        fit = P * B * T * wire_bytes + 5.0 * P * T
-        close = 2.0 * P * S * (6 + 2 * B + B * K) * dtype_bytes + 8.0 * P
-    else:
-        if "fit" in pallas:
-            fit = P * B * T * wire_bytes + 5.0 * P * T
-        else:
-            fit = P * B * T * dtype_bytes         # cfull Gram corr Y read
-        close = (2.0 * P * params.PEEK_SIZE * T * dtype_bytes    # oh_run
-                 + 2.0 * P * S * (6 + 2 * B + B * K) * dtype_bytes)  # bufs
+    # oh_w bool written+read + float view read by the two selection
+    # matmuls + window members/XtXt + the c4 fit's Y read
+    init = (3.0 * P * W * T
+            + 3.0 * P * W * T * dtype_bytes
+            + 2.0 * P * W * (NT + B + NT * NT) * dtype_bytes
+            + P * B * T * dtype_bytes)
+    fit = P * B * T * dtype_bytes                 # cfull Gram corr Y read
+    close = (2.0 * P * params.PEEK_SIZE * T * dtype_bytes        # oh_run
+             + 2.0 * P * S * (6 + 2 * B + B * K) * dtype_bytes)  # bufs
     return every * rounds + init * ir + fit * fr + close * cr
 
 
@@ -446,25 +367,18 @@ def bench_detail(pixels_per_sec: float, P: int, T: int, W: int, S: int,
                  rounds: float, device_kind: str, dtype_bytes: int = 4,
                  sensor=LANDSAT_ARD, phase_rounds: tuple | None = None,
                  pallas: frozenset | set | tuple = (),
-                 wire_bytes: int = 2, mixed: bool = False) -> dict:
+                 wire_bytes: int = 2) -> dict:
     """The roofline block bench.py embeds in its detail output.
 
     ``phase_rounds`` = measured (init, fit, close) cond-gate counts
     (ChipSegments.round_counts) — makes the model reflect what the
     phase-gated loop actually executed instead of the ungated bound.
     ``pallas`` = the enabled component set (see round_bytes) so the byte
-    model reflects the picked config's actual streams.  ``mixed`` = the
-    picked config runs the bf16 split-dot gram: the model's MFU numbers
-    stay against the SAME useful-arithmetic count (comparable across
-    rungs), and a ``mixed`` block reports the dot-stage pass/operand
-    model plus the raised compute ceiling — with
-    ``mfu_pct_vs_bf16_peak`` the headline utilization figure for the
-    picked config, since the dots then run on the bf16 MXU path."""
-    fl = detect_flops(P, T, W, rounds, sensor, phase_rounds=phase_rounds,
-                      mixed=mixed)
+    model reflects the picked config's actual streams."""
+    fl = detect_flops(P, T, W, rounds, sensor, phase_rounds=phase_rounds)
     by = round_bytes(P, T, W, S, dtype_bytes, sensor, rounds=rounds,
                      phase_rounds=phase_rounds, pallas=pallas,
-                     wire_bytes=wire_bytes, mixed=mixed) / max(P, 1)
+                     wire_bytes=wire_bytes) / max(P, 1)
     achieved = pixels_per_sec * fl["per_pixel"]
     hbm_rate = pixels_per_sec * by
     out = {
@@ -491,23 +405,4 @@ def bench_detail(pixels_per_sec: float, P: int, T: int, W: int, S: int,
         out["compute_bound_pixels_per_sec"] = round(
             pk.f32_flops / fl["per_pixel"], 1)
         out["hbm_bound_pixels_per_sec"] = round(pk.hbm_bytes / max(by, 1.0), 1)
-    if mixed:
-        r = fl["per_round"]
-        md = dict(r["mixed"])
-        if pk is not None and phase_rounds is not None:
-            # Mixed compute ceiling: the Gram/corr dots fire on init +
-            # fit rounds and run pass-counted at the bf16 peak; the rest
-            # of the useful arithmetic stays at the f32 peak.  Per
-            # pixel, over the dispatch:
-            ir, frr, _ = phase_rounds
-            dots = (md["gram_dot_flops"] + md["corr_dot_flops"]) \
-                * (ir + frr) / max(P, 1)
-            rest = max(fl["per_pixel"] - dots, 0.0)
-            t_mixed = (md["mxu_passes_gram"] * md["gram_dot_flops"]
-                       + md["mxu_passes_corr"] * md["corr_dot_flops"]) \
-                * (ir + frr) / max(P, 1) / pk.bf16_flops \
-                + rest / pk.f32_flops
-            md["mixed_compute_bound_pixels_per_sec"] = round(
-                1.0 / max(t_mixed, 1e-30), 1)
-        out["mixed"] = md
     return out

@@ -41,22 +41,6 @@ def design_matrix(t: np.ndarray, anchor: float, ncoef: int = params.MAX_COEFS) -
     return np.stack(cols[:ncoef], axis=1)
 
 
-def lasso_cd(X: np.ndarray, y: np.ndarray,
-             alpha: float = params.LASSO_ALPHA,
-             iters: int = params.LASSO_ITERS) -> np.ndarray:
-    """Lasso by cyclic coordinate descent with a fixed iteration count.
-
-    Objective: 1/(2n) ||y - X b||^2 + alpha * sum_{j>=1} |b_j|  (intercept,
-    column 0, unpenalized).  Operates on the Gram matrix so the TPU kernel
-    can run the identical update from incrementally accumulated G = X'X/n
-    and c = X'y/n.
-    """
-    n, p = X.shape
-    G = X.T @ X / n
-    c = X.T @ y / n
-    return lasso_cd_gram(G, c, alpha=alpha, iters=iters)
-
-
 def lasso_cd_gram(G: np.ndarray, c: np.ndarray,
                   alpha: float = params.LASSO_ALPHA,
                   iters: int = params.LASSO_ITERS) -> np.ndarray:
@@ -97,11 +81,14 @@ def fit_bands(t: np.ndarray, Y: np.ndarray, ncoef: int, anchor: float,
         parametrization, rmse [nbands]).
     """
     X = design_matrix(t, anchor, ncoef)
+    n = X.shape[0]
+    G = X.T @ X / n
     nb = Y.shape[0]
     coefs = np.zeros((nb, params.MAX_COEFS), dtype=np.float64)
     rmse = np.zeros(nb, dtype=np.float64)
     for b in range(nb):
-        beta = lasso_cd(X, Y[b].astype(np.float64), alpha=alpha)
+        beta = lasso_cd_gram(G, X.T @ Y[b].astype(np.float64) / n,
+                             alpha=alpha)
         coefs[b, :ncoef] = beta
         r = Y[b] - X @ beta
         rmse[b] = np.sqrt(np.mean(r * r))

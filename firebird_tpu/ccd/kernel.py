@@ -56,91 +56,36 @@ PHASE_INIT, PHASE_MONITOR, PHASE_DONE = 0, 1, 2
 PROC_STANDARD, PROC_SNOW, PROC_INSUF, PROC_NODATA = 0, 1, 2, 3
 
 
-def use_pallas(component: str = "lasso") -> bool:
-    """Whether `component` runs as its Pallas VMEM-resident kernel.
+# The Pallas components FIREBIRD_PALLAS can name.  Each beat the XLA loop
+# in a kernel-only v5e measurement at full decision parity; none is the
+# default.
+PALLAS_COMPONENTS = ("monitor", "score", "tmask")
 
-    FIREBIRD_PALLAS is "0"/"" (none), "1" (all), or a comma list of
-    component names ("lasso,monitor,tmask,fit,score,init") — bench.py
-    tunes the components independently on hardware, so a kernel that
-    loses on a given toolchain can't drag down the ones that win.
-    "fit" (the fused Gram+corr+CD+RMSE kernel) supersedes "lasso" (CD
-    loop only) at the fit call sites; "score" (the score-fused monitor
-    kernel) supersedes "monitor"; "init" (the fused INIT-window kernel)
-    supersedes "tmask" inside the init block; "mega" (the whole-loop
-    kernel) supersedes ALL of them and must be named explicitly — "1"
-    means every per-component kernel, not the mega route, so existing
-    "all-on" configs keep their meaning.  Read at trace time: set it
-    before the first detect call — already-compiled programs keep their
-    path."""
+
+def pallas_components() -> frozenset:
+    """The components FIREBIRD_PALLAS routes through their Pallas kernels.
+
+    FIREBIRD_PALLAS is "0"/"" (none), "1" (all of PALLAS_COMPONENTS), or
+    a comma list of component names ("monitor,tmask"); "score" (the
+    score-fused monitor kernel) supersedes "monitor".  A name that is no
+    component raises ValueError: a route that no longer exists must not
+    run as the XLA loop in silence.  Read at trace time: set it before
+    the first detect call — already-compiled programs keep their path."""
     from firebird_tpu.config import env_knob
 
     v = env_knob("FIREBIRD_PALLAS")
     if v in ("", "0"):
-        return False
+        return frozenset()
     if v == "1":
-        return component != "mega"
-    return component in {c.strip() for c in v.split(",")}
-
-
-def _wire_resident_only() -> bool:
-    """True when every event-loop consumer of the widened float spectra
-    is routed to a Pallas kernel reading the wire-dtype residents (the
-    init, score, and fit components together) — the prologue then keeps
-    the float view out of ``res`` so XLA frees it after the pre-loop
-    work.  _detect_batch_impl combines this with the f32-on-TPU gate
-    (the float64-on-TPU fallback keeps the float view resident) and
-    independently with the mega route (which reads only the wire residents by
-    construction, but only when mega_fits accepts the shape — a refused
-    mega must fall back to a loop that still has its float view)."""
-    return (use_pallas("init") and use_pallas("score")
-            and use_pallas("fit"))
-
-
-def use_fused_fit() -> bool:
-    """Whether the event loop's segment-close + shared-Lasso-fit pair
-    runs as the fused Pallas gram→CD→close kernel
-    (pallas_ops.fused_fit_close): one VMEM residency of the wire spectra
-    serves both phases instead of two HBM streams plus the [P,*]
-    intermediates between the cond-gated fusions.  FIREBIRD_FUSED_FIT,
-    default off; read at trace time like use_pallas (f32-on-TPU only
-    when compiled; interpret elsewhere); the mega route supersedes it."""
-    from firebird_tpu.config import env_knob
-
-    return env_knob("FIREBIRD_FUSED_FIT") not in ("", "0")
-
-
-def fused_mode():
-    """FIREBIRD_FUSED_FIT's three-way resolution: 0 (off), 1 (the fused
-    fit+close kernel, byte-identical to the unfused chain), or "mon"
-    (value "mon" or "2" — the monitor-fused round kernel
-    pallas_ops.fused_round, one VMEM residency for the whole post-INIT
-    round; decision-exact with the seg_mag f32 envelope, like the mega
-    route).  Read at trace time like use_pallas."""
-    from firebird_tpu.config import env_knob
-
-    v = env_knob("FIREBIRD_FUSED_FIT")
-    if v in ("", "0"):
-        return 0
-    if v in ("2", "mon"):
-        return "mon"
-    return 1
-
-
-def use_mixed_precision() -> bool:
-    """Whether the fit kernels accumulate the Gram/corr dots in bf16
-    split form (f32 accumulators, int32 counts) instead of the 6-pass
-    f32-"highest" emulation — pallas_ops._gram_cd_core's ``mixed``
-    path.  Decision fields stay identical to the f32 path (the split
-    exploits the int16-valued spectra and 0/1 weights; coef/rmse drift
-    is bounded by params.MIXED_ULP_BUDGET — tools/precision_smoke.py
-    enforces both).  FIREBIRD_MIXED_PRECISION, default off; read at
-    trace time like use_pallas; applies only to f32 stores (the f64
-    bit-parity path keeps full precision) and only to the Pallas fit
-    routes — the XLA reference path stays f32, it IS the oracle the
-    identity tests compare against."""
-    from firebird_tpu.config import env_knob
-
-    return env_knob("FIREBIRD_MIXED_PRECISION") not in ("", "0")
+        return frozenset(PALLAS_COMPONENTS)
+    names = frozenset(c.strip() for c in v.split(",")) - {""}
+    unknown = names - set(PALLAS_COMPONENTS)
+    if unknown:
+        raise ValueError(
+            f"FIREBIRD_PALLAS={v!r} names {sorted(unknown)}, which no "
+            f"Pallas component is; the components are "
+            f"{', '.join(PALLAS_COMPONENTS)} ('1' for all, '0' for none)")
+    return names
 
 
 # ---------------------------------------------------------------------------
@@ -290,10 +235,8 @@ def _fit_lasso_coefs(X, Y, w, coefmask, XX=None, active=None):
             MXU matmul instead of a [P,T,8] broadcast temporary.
         active: optional [P] bool skip guard (compaction mode): pixels
             outside it are guaranteed all-zero ``w`` rows, whose CD
-            output is exactly zero — so the Pallas kernel skips whole
-            dead lane blocks (a per-block ``pl.when``) and the lax path
-            cond-gates the CD slab on any(active).  ``None`` preserves
-            the unguarded program.
+            output is exactly zero — so the CD slab is cond-gated on
+            any(active).  ``None`` preserves the unguarded program.
 
     Returns:
         coefs [P,7,8].
@@ -306,32 +249,20 @@ def _fit_lasso_coefs(X, Y, w, coefmask, XX=None, active=None):
     c = jnp.einsum("pbt,tc->pbc", Y * w[:, None, :], X) / n[:, None, None]
     diag = jnp.maximum(jnp.diagonal(G, axis1=-2, axis2=-1), 1e-12)  # [P,8]
 
-    if use_pallas("lasso"):
-        on_tpu = jax.default_backend() == "tpu"
-        # Mosaic cannot lower float64; compiled Pallas is f32-on-TPU only.
-        # Off-TPU the same kernel runs interpreted (tests), any dtype.
-        if not on_tpu or c.dtype == jnp.float32:
-            from firebird_tpu.ccd import pallas_ops
-
-            return pallas_ops.lasso_cd(G, c, diag, coefmask,
-                                       active=active,
-                                       interpret=not on_tpu)
     if active is None:
         return _lasso_cd_lax(G, c, diag, coefmask)
-    # The lax slab guard: an all-dead slab (here the slab is the whole
-    # call — the batch-level cond gates already bound it) skips the CD
-    # loop for the exact zeros it would compute.  Under vmap the cond
-    # degenerates to a select; the value is identical either way, so
-    # tier-1 CPU runs exercise the same control flow the Pallas
-    # per-block guards take on TPU.
+    # The slab guard: an all-dead slab (here the slab is the whole call —
+    # the batch-level cond gates already bound it) skips the CD loop for
+    # the exact zeros it would compute.  Under vmap the cond degenerates
+    # to a select; the value is identical either way.
     return lax.cond(jnp.any(active),
                     lambda: _lasso_cd_lax(G, c, diag, coefmask),
                     lambda: jnp.zeros_like(c))
 
 
 def _lasso_cd_lax(G, c, diag, coefmask):
-    """The CD loop as a lax fori_loop (the default / reference path; the
-    Pallas VMEM-resident version is pallas_ops.lasso_cd)."""
+    """The CD loop as a lax fori_loop: harmonic.lasso_cd_gram's update,
+    batched over pixels and bands."""
     alpha = params.LASSO_ALPHA
 
     def one_iter(_, b):
@@ -659,24 +590,11 @@ def _detect_core(X, Xt, t, valid, Y, qa, *, wcap: int | None = None,
     return jax.tree_util.tree_map(lambda a: a[0], out)
 
 
-def _fit_chip(res, w, coefmask, with_rmse=True, *, fit_pallas, on_tpu,
-              mixed=False, active=None):
-    """One chip's batched Lasso fit, routed to the winning implementation
-    (the fused Pallas Gram+corr+CD+RMSE kernel reads the wire-dtype
-    resident spectra; the lax path reads the widened float view).
-    ``mixed`` (FIREBIRD_MIXED_PRECISION) selects the bf16 split-dot
-    Gram on the Pallas route only — the XLA path stays f32 (it is the
-    oracle the decision-identity tests compare against).  ``active`` is
-    the compaction-mode skip guard: pixels outside it carry all-zero
-    windows, so dead lane blocks are skipped for the zeros they would
-    compute (see _fit_lasso_coefs)."""
-    if fit_pallas:
-        from firebird_tpu.ccd import pallas_ops
-
-        b, r = pallas_ops.lasso_fit(res["Yt"], w, res["X"], coefmask,
-                                    with_rmse=with_rmse, mixed=mixed,
-                                    active=active, interpret=not on_tpu)
-        return (b, r) if with_rmse else b
+def _fit_chip(res, w, coefmask, with_rmse=True, *, active=None):
+    """One chip's batched Lasso fit from its residents (designs, the
+    widened float view, the per-row outer products).  ``active`` is the
+    compaction-mode skip guard: pixels outside it carry all-zero
+    windows (see _fit_lasso_coefs)."""
     if with_rmse:
         return _fit_lasso(res["X"], res["Y"], w, coefmask, XX=res["XX"],
                           active=active)
@@ -706,31 +624,23 @@ def _write_seg(bufs, nseg, wmask, meta, rmse_s, mag_s, coef_s, *, S):
     return bufs, nseg + wmask.astype(jnp.int32)
 
 
-def _prologue(X, Xt, t, valid, Y, qa, *, sensor, S, fdtype, fit,
-              wire_only=False, guards=False):
+def _prologue(X, Xt, t, valid, Y, qa, *, sensor, S, fdtype, guards=False):
     """One chip's pre-loop work: QA triage, usable sets, the one-shot
     snow/insufficient-clear fit, variogram, and the standard-procedure
     start state.  Returns (res, state): ``res`` holds the loop-invariant
     residents (spectra views, designs, variogram, procedure routing),
     ``state`` the event-loop carry."""
-    # Resident wire-dtype spectra [B,T,P] for the Pallas consumers (int16
-    # reads halve the round loop's dominant HBM term; widening in-register
-    # is exact), alongside the widened [P,B,T] float view the XLA paths
-    # read.  When the init+score+fit Pallas components are all enabled,
-    # the float view leaves ``res`` — the loop then never references it,
-    # XLA frees it after the prologue, and its [P,B,T] residency (~4.7 GB
-    # at the 8-chip bench shape) comes off the loop's working set.
+    # The widened [P,B,T] float view every XLA block reads.  The score-
+    # fused monitor kernel reads the detection bands' wire-dtype [nb,T,P]
+    # view instead (int16 reads halve its HBM term; widening in-register
+    # is exact); XLA drops that view when the kernel is off.
     Yt_res = Y.transpose(0, 2, 1)                              # [B,T,P]
     Y = Y.astype(fdtype).transpose(1, 0, 2)                    # -> [P,B,T]
     P, B, T = Y.shape
     # Per-row design outer products, shared by every Lasso Gram build.
     XX = (X[:, :, None] * X[:, None, :]).reshape(T, -1)        # [T,64]
-    # Detection-band wire-dtype slice for the score-fused monitor kernel
-    # (DCE'd from the program when FIREBIRD_PALLAS doesn't enable it).
     Yd = Yt_res[np.asarray(sensor.detection_bands)]            # [nb,T,P]
-    res = dict(X=X, Xt=Xt, t=t, Yt=Yt_res, Yd=Yd, XX=XX)
-    if not wire_only:
-        res["Y"] = Y
+    res = dict(X=X, Xt=Xt, t=t, Y=Y, Yd=Yd, XX=XX)
 
     # ---------------- QA triage (reference.detect) ----------------
     fill = _qa_bit(qa, params.QA_FILL_BIT) | ~valid[None, :]
@@ -781,8 +691,8 @@ def _prologue(X, Xt, t, valid, Y, qa, *, sensor, S, fdtype, fit,
     alt_n = jnp.sum(alt_usable, -1)
     alt_fit = is_alt & (alt_n >= params.MEOW_SIZE)
     w_alt = (alt_usable & alt_fit[:, None]).astype(fdtype)
-    alt_coefs, alt_rmse = fit(res, w_alt, _coefmask_for(alt_n), True,
-                              active=alt_fit if guards else None)
+    alt_coefs, alt_rmse = _fit_chip(res, w_alt, _coefmask_for(alt_n), True,
+                                    active=alt_fit if guards else None)
     first_i = jnp.argmax(alt_usable, -1)
     last_i = T - 1 - jnp.argmax(alt_usable[:, ::-1], -1)
     alt_meta = jnp.stack([
@@ -800,8 +710,9 @@ def _prologue(X, Xt, t, valid, Y, qa, *, sensor, S, fdtype, fit,
     # ---------------- standard procedure state ----------------
     is_std = procedure == PROC_STANDARD
     alive0 = usable_std & is_std[:, None]
-    # Mode read at trace time, like use_pallas — set FIREBIRD_VARIOGRAM
-    # before the first detect call (one compiled fn per mode).
+    # Mode read at trace time, like FIREBIRD_PALLAS — set
+    # FIREBIRD_VARIOGRAM before the first detect call (one compiled fn per
+    # mode).
     vario = _variogram(Y, alive0, t=t,
                        adjusted=params.variogram_adjusted_default())
     ex0, i0 = _first_at_or_after(alive0, jnp.zeros(P, jnp.int32))
@@ -824,8 +735,7 @@ def _prologue(X, Xt, t, valid, Y, qa, *, sensor, S, fdtype, fit,
     return res, state
 
 
-def _init_block(res, st, *, sensor, W, fdtype, fit, f32_ok, mixed=False,
-                guards=False):
+def _init_block(res, st, *, sensor, W, fdtype, pallas, guards=False):
     """One chip's INIT-phase round work: initialization-window search, the
     Tmask IRLS screen, and the stability test.  Runs under a scalar
     lax.cond — on rounds where no pixel is initializing (most of them:
@@ -833,27 +743,17 @@ def _init_block(res, st, *, sensor, W, fdtype, fit, f32_ok, mixed=False,
     block, including its one-hot window tensors (the loop's dominant HBM
     term), is skipped outright.  Every output is consumed downstream only
     under in_init-derived masks, so the skip branch's zeros are inert.
-    ``guards`` (compaction mode) threads the in_init lane set into the
-    Pallas kernels as a per-block skip guard — dense-prefix compaction
-    clusters DONE lanes into whole trailing blocks, which then cost a
-    predicate instead of the window search + IRLS."""
+    ``pallas`` is the set of Pallas components this program runs
+    (_detect_batch_impl).  ``guards`` (compaction mode) threads the
+    in_init lane set into the tmask kernel as a per-block skip guard —
+    dense-prefix compaction clusters DONE lanes into whole trailing
+    blocks, which then cost a predicate instead of the IRLS."""
     _DET = list(sensor.detection_bands)
     _TMB = list(sensor.tmask_bands)
     X, Xt, t = res["X"], res["Xt"], res["t"]
     alive = st["alive"]
     in_init = st["phase"] == PHASE_INIT
     act = in_init if guards else None
-
-    if use_pallas("init") and f32_ok:
-        # f32_ok: the shared Mosaic gate from _detect_batch_impl
-        # (f32-on-TPU only — Mosaic cannot lower float64).
-        on_tpu = jax.default_backend() == "tpu"
-        from firebird_tpu.ccd import pallas_ops
-
-        return pallas_ops.init_window(
-            alive, st["cur_i"], in_init, t, X, Xt, res["Yt"],
-            res["vario"], W=W, sensor=sensor, mixed=mixed, active=act,
-            interpret=not on_tpu)
 
     Y = res["Y"]
     P, B, T = Y.shape
@@ -901,7 +801,7 @@ def _init_block(res, st, *, sensor, W, fdtype, fit, f32_ok, mixed=False,
     Xw8, Xt_w = XW[..., :8], XW[..., 8:]
     Y2w = Yw7[:, _TMB, :]
     tmask_fn = _tmask_bad
-    if use_pallas("tmask") and f32_ok:
+    if "tmask" in pallas:
         on_tpu = jax.default_backend() == "tpu"
         from firebird_tpu.ccd import pallas_ops
 
@@ -919,7 +819,7 @@ def _init_block(res, st, *, sensor, W, fdtype, fit, f32_ok, mixed=False,
     w_stab = w_init & ~tm_removed[:, None]
     cm4 = jnp.arange(params.MAX_COEFS)[None, :] < 4
     cm4 = jnp.broadcast_to(cm4, (P, params.MAX_COEFS))
-    c4 = fit(res, w_stab.astype(fdtype), cm4, False, active=act)
+    c4 = _fit_chip(res, w_stab.astype(fdtype), cm4, False, active=act)
     r_w = Yw7 - jnp.sum(c4[:, :, None, :] * Xw8[:, None, :, :], -1)
     stab_w = valid_w & ~bad_w
     n4 = jnp.maximum(jnp.sum(stab_w, -1), 1.0)
@@ -966,14 +866,14 @@ def _init_zeros(st):
                 n_ok=zi, alive_init=st["alive"])
 
 
-def _mon_block(res, st, *, sensor, change_thr, outlier_thr, f32_ok,
+def _mon_block(res, st, *, sensor, change_thr, outlier_thr, pallas,
                guards=False):
     """One chip's MONITOR-phase round work: score all remaining
     observations against the current model and locate the first event
     (break / refit / tail) in rank space.  Runs under a scalar lax.cond
     (skipped on round 1, when every standard pixel is still
-    initializing).  ``guards`` threads the in_mon lane set into the
-    Pallas kernels as a per-block skip guard (see _init_block)."""
+    initializing).  ``pallas`` and ``guards`` as in _init_block: the
+    in_mon lane set is the monitor kernels' per-block skip guard."""
     _DET = list(sensor.detection_bands)
     X = res["X"]
     alive, included = st["alive"], st["included"]
@@ -988,11 +888,7 @@ def _mon_block(res, st, *, sensor, change_thr, outlier_thr, f32_ok,
     # expensive op on TPU, not the matmuls).
     dden = jnp.maximum(st["rmse"], res["vario"])[:, _DET]      # [P,5]
     on_tpu = jax.default_backend() == "tpu"
-    # f32_ok (Mosaic cannot lower float64; compiled Pallas is f32-on-TPU
-    # only) is computed ONCE from fdtype in _detect_batch_impl and shared
-    # with the wire-resident gate, so the monitor can never fall down the
-    # XLA path while res["Y"] was dropped by wire-only mode.
-    if use_pallas("score") and f32_ok:
+    if "score" in pallas:
         # Score-fused kernel: predictions, score, and rank derived in
         # VMEM from the wire-dtype detection-band spectra — skips the
         # [P,nb,T] prediction einsum and the s/rank plane round-trips.
@@ -1014,7 +910,7 @@ def _mon_block(res, st, *, sensor, change_thr, outlier_thr, f32_ok,
                     axis=1)
         rank = jnp.cumsum(alive, -1) - 1                       # [P,T]
         chain = _monitor_chain
-        if use_pallas("monitor") and f32_ok:
+        if "monitor" in pallas:
             from firebird_tpu.ccd import pallas_ops
 
             chain = functools.partial(pallas_ops.monitor_chain,
@@ -1047,12 +943,7 @@ def _mon_zeros(st):
 
 def _close_mags(res, st, mon, *, fdtype):
     """Break magnitudes: median full-band residual over the PEEK run at
-    the break — the spectra-reading half of the close, split out so the
-    fused-fit route (FIREBIRD_FUSED_FIT) can run EXACTLY this code under
-    its own any(is_brk) cond: break rounds are rare, and sharing the
-    very same program keeps the fused-on/off stores byte-identical
-    (tests/test_fuse.py golden) where a re-derived in-kernel median
-    would differ by backend-fusion ulps."""
+    the break — the spectra-reading half of the close."""
     X = res["X"]
     alive = st["alive"]
     P, B, _K = st["coefs"].shape
@@ -1076,15 +967,8 @@ def _close_mags(res, st, mon, *, fdtype):
                        precision=lax.Precision.HIGHEST)       # [P,K,8]
     pred_run = jnp.sum(st["coefs"][:, :, None, :]
                        * X_run[:, None, :, :], -1)            # [P,B,K]
-    if "Y" in res:
-        Y_run = jnp.einsum("pbt,pkt->pbk", res["Y"], oh_run,
-                           precision=lax.Precision.HIGHEST)
-    else:
-        # Wire-resident mode: the run members come from the int16 view.
-        # Each (p,b,k) output selects exactly one observation (one-hot
-        # over t), so this contraction is bit-exact vs the float view.
-        Y_run = jnp.einsum("btp,pkt->pbk", res["Yt"].astype(fdtype),
-                           oh_run, precision=lax.Precision.HIGHEST)
+    Y_run = jnp.einsum("pbt,pkt->pbk", res["Y"], oh_run,
+                       precision=lax.Precision.HIGHEST)
     resid_run = Y_run - pred_run                              # [P,7,PEEK]
     return _masked_median(
         resid_run, jnp.broadcast_to(run_ok[:, None, :], resid_run.shape))
@@ -1097,9 +981,6 @@ def _close_block(res, st, mon, *, S, fdtype):
     rounds), so most rounds skip both the PEEK-run one-hot einsums and
     the full result-buffer rewrite."""
     t = res["t"]
-    # Shapes from the always-present carries, not res["Yt"]: compaction
-    # mode carries only the residents the traced paths actually read, so
-    # the wire view may be absent here when the float view serves.
     P, B, _K = st["coefs"].shape
     T = res["X"].shape[0]
     is_tail, is_brk = mon["is_tail"], mon["is_brk"]
@@ -1136,17 +1017,17 @@ def _close_block(res, st, mon, *, S, fdtype):
 
 # The skip-guard accounting unit: a trailing lane block containing no
 # active lane costs a per-block predicate in the Pallas kernels instead
-# of its Gram/CD/monitor work.  Matches pallas_ops.BLOCK_P's scale (the
-# per-kernel widths are 128-512; 512 is the accounting width the
-# occupancy capture and flops.occupancy_detail use).
+# of its monitor/Tmask work.  The per-kernel widths are 128-512; 512 is
+# the accounting width the occupancy capture and flops.occupancy_detail
+# use.
 COMPACT_LANE_BLOCK = 512
 
 # State-dict keys permuted along their leading pixel axis by a compaction
 # (the [C,P,...] loop carries).
 _COMPACT_PIXEL_KEYS = ("phase", "cur_i", "cur_k", "alive", "included",
                        "coefs", "rmse", "n_last_fit", "first_seg", "nseg")
-# Carried residents whose pixel axis is NOT leading (wire layout [B,T,P]).
-_COMPACT_RESP_AXIS = {"Yt": 2, "Yd": 2}
+# Carried residents whose pixel axis is NOT leading (wire layout [nb,T,P]).
+_COMPACT_RESP_AXIS = {"Yd": 2}
 
 
 def _dense_prefix_perm(alive):
@@ -1282,9 +1163,7 @@ def _join_lanes(k, seg: ChipSegments) -> ChipSegments:
 def _detect_batch_core(Xs, Xts, ts, valids, Ys, qas, *,
                        wcap: int | None = None, sensor=LANDSAT_ARD,
                        max_segments: int = MAX_SEGMENTS, dtype=None,
-                       compact: bool | None = None,
-                       fused=None, mixed: bool | None = None,
-                       rebalance=None):
+                       compact: bool | None = None, rebalance=None):
     """A chip batch: Xs [C,T,8], Xts [C,T,5], ts [C,T], valids [C,T],
     Ys [C,B,P,T] (wire int16 or float), qas [C,P,T] int32 → ChipSegments
     with [C, ...] leading axes.
@@ -1321,21 +1200,6 @@ def _detect_batch_core(Xs, Xts, ts, valids, Ys, qas, *,
     FIREBIRD_COMPACT_FLOOR — row-identical results, cost tracking the
     active set instead of the padded batch.
 
-    ``fused`` (static) routes each round's segment-close + shared-fit
-    pair through the fused gram→CD→close Pallas kernel (None defers to
-    FIREBIRD_FUSED_FIT at trace time, like ``compact``); results are
-    byte-identical against the unfused Pallas-fit configuration
-    (tests/test_fuse.py golden).  The value "mon" (or env "mon"/"2")
-    instead fuses the WHOLE post-INIT round — monitor chain + close +
-    fit — into one pallas_call (pallas_ops.fused_round); that route is
-    decision-exact with seg_mag inside the f32 envelope, like mega.
-
-    ``mixed`` (static) accumulates the fit kernels' Gram/corr dots in
-    bf16 split form with f32 accumulators and int32 counts (None defers
-    to FIREBIRD_MIXED_PRECISION at trace time) — decision fields stay
-    identical to f32, coef/rmse inside params.MIXED_ULP_BUDGET; f32
-    stores and Pallas fit routes only (see use_mixed_precision).
-
     A chip wider than ``MAX_CHIP_LANES`` runs as equal lane blocks on
     the chip axis and is joined back before return (:func:`lane_blocks`).
 
@@ -1352,13 +1216,8 @@ def _detect_batch_core(Xs, Xts, ts, valids, Ys, qas, *,
         seg = _detect_batch_impl(Xs, Xts, ts, valids, Ys, qas, wcap=wcap,
                                  sensor=sensor, max_segments=max_segments,
                                  dtype=dtype, compact=compact,
-                                 fused=fused, mixed=mixed,
                                  rebalance=rebalance)
     return _join_lanes(k, seg) if k > 1 else seg
-
-
-_PALLAS_COMPONENTS = ("lasso", "monitor", "tmask", "fit", "score", "init",
-                      "mega")
 
 
 def _refuse_route(route: str, why: str) -> None:
@@ -1372,165 +1231,49 @@ def _refuse_route(route: str, why: str) -> None:
     obs_metrics.counter(
         "kernel_pallas_refused",
         help="programs traced with a requested Pallas route refused "
-             "(float64 on TPU, or the mega VMEM guard)").inc()
+             "(float64 on TPU)").inc()
     logger("pyccd").warning(
         "Pallas route %r requested but refused (%s); this program runs "
         "the XLA loop in its place", route, why)
 
 
 def _detect_batch_impl(Xs, Xts, ts, valids, Ys, qas, *, wcap, sensor,
-                       max_segments, dtype, compact=None, fused=None,
-                       mixed=None, rebalance=None):
+                       max_segments, dtype, compact=None, rebalance=None):
     C, B, P, T = Ys.shape
     S = max_segments
     W = T if wcap is None else min(wcap, T)
     fdtype = jnp.dtype(dtype) if dtype is not None else Ys.dtype
     _DET = list(sensor.detection_bands)
     change_thr, outlier_thr = chi2_thresholds(len(_DET))
-    on_tpu = jax.default_backend() == "tpu"
-    f32_ok = not on_tpu or fdtype == jnp.float32
-    if not f32_ok:
-        for route in [c for c in _PALLAS_COMPONENTS if use_pallas(c)] + (
-                ["fused"] if (fused_mode() if fused is None else fused)
-                else []):
+    # The Pallas components this program runs: parsed once, and none
+    # for a float64 program on TPU (Mosaic lowers float32 only; off-TPU
+    # the kernels run interpreted, any dtype).
+    pallas = pallas_components()
+    if jax.default_backend() == "tpu" and fdtype != jnp.float32:
+        for route in [c for c in PALLAS_COMPONENTS if c in pallas]:
             _refuse_route(route, f"Mosaic lowers float32 only, the "
                                  f"program is {fdtype.name} on TPU")
-    # The mega decision is made ONCE, up front, because it shapes the
-    # prologue: mega implies wire-resident mode (drops the float view)
-    # and the Pallas fit kernel for the one-shot alt fits — but a mega
-    # REFUSED by the VMEM guard must leave both decisions to the
-    # per-component flags, or the XLA fallback loop would read a float
-    # view the prologue never kept.
-    mega = False
-    if use_pallas("mega") and f32_ok:
-        from firebird_tpu.ccd import pallas_ops
-
-        mega = pallas_ops.mega_fits(T, W, B, S, Ys.dtype.itemsize)
-        if not mega:
-            _refuse_route("mega", f"mega_fits: T={T} W={W} B={B} S={S} "
-                                  f"exceeds VMEM at the 128-lane floor")
-    fit_pallas = (use_pallas("fit") or mega) and f32_ok
-    # Mixed-precision gram (FIREBIRD_MIXED_PRECISION / explicit mixed=):
-    # bf16 split dots + int32 counts inside the Pallas fit routes, f32
-    # everywhere decisions are made.  f32 stores only — the f64
-    # bit-parity path keeps full precision — and inert on the XLA fit
-    # path, which stays the f32 oracle.
-    mixed_on = (use_mixed_precision() if mixed is None else bool(mixed)) \
-        and f32_ok and fdtype == jnp.float32
-    fit = functools.partial(_fit_chip, fit_pallas=fit_pallas,
-                            on_tpu=on_tpu, mixed=mixed_on)
-    wire_only = (mega or _wire_resident_only()) and f32_ok
-    # Active-lane compaction (trace-time resolution, like use_pallas).
-    # The mega route already stops paying for finished pixels its own way
-    # (each VMEM block's while_loop exits when ITS pixels are done), so
-    # compaction applies to the XLA/per-component loop only.
+        pallas = frozenset()
+    # Active-lane compaction (trace-time resolution, like FIREBIRD_PALLAS).
     compact_on = (params.compact_default() if compact is None
-                  else bool(compact)) and not mega
-    # Fused gram→CD→close round kernel (FIREBIRD_FUSED_FIT / explicit
-    # fused=): each round's segment-close + shared-Lasso-fit pair runs
-    # as ONE pallas_call on a single VMEM residency of the wire spectra.
-    # Mode "mon" widens the fusion to the whole post-INIT round —
-    # monitor chain + close + fit in one kernel (pallas_ops.fused_round).
-    # The mega route supersedes both (the whole loop is already one
-    # kernel); the f64-on-TPU bit-parity path keeps the XLA pair.
-    fused_req = fused_mode() if fused is None else fused
-    if fused_req in ("mon", 2):
-        fused_req = "mon"
-    elif fused_req:
-        fused_req = 1
-    else:
-        fused_req = 0
-    fused_on = bool(fused_req) and f32_ok and not mega
-    fused_mon = fused_on and fused_req == "mon"
-
-    # Trace-time route counters (host code; a jit trace runs once per
-    # compiled shape, so these count PROGRAMS built on each route —
-    # tools/precision_smoke.py's "counters moving" check).
-    from firebird_tpu.obs import metrics as obs_metrics
-    if mixed_on:
-        obs_metrics.counter(
-            "kernel_mixed_traces",
-            help="programs traced with the bf16/int32 mixed-precision "
-                 "gram (FIREBIRD_MIXED_PRECISION)").inc()
-    if fused_mon:
-        obs_metrics.counter(
-            "kernel_fused_round_traces",
-            help="programs traced with the whole-round monitor-fused "
-                 "kernel (FIREBIRD_FUSED_FIT=mon)").inc()
+                  else bool(compact))
 
     res, state = jax.vmap(functools.partial(
-        _prologue, sensor=sensor, S=S, fdtype=fdtype, fit=fit,
-        wire_only=wire_only, guards=compact_on))(Xs, Xts, ts, valids, Ys,
-                                                 qas)
-
-    if mega:
-        # Whole-loop mega kernel: the entire event loop in one
-        # pallas_call, wire spectra VMEM-resident, each block exiting as
-        # soon as its own pixels finish (pallas_ops._detect_mega_block).
-        # mega_fits guarded the 128-lane VMEM floor above: an oversized
-        # T falls down the XLA loop below instead of a Mosaic OOM.
-        # (pallas_ops is already bound in scope by the guard import.)
-        out = pallas_ops.detect_mega(
-            res["Yt"], state["phase"], state["cur_i"], state["alive"],
-            state["nseg"], state["bufs"], res["t"], res["X"], res["Xt"],
-            res["vario"], W=W, S=S, sensor=sensor,
-            phases=(PHASE_INIT, PHASE_MONITOR, PHASE_DONE),
-            change_thr=float(change_thr), outlier_thr=float(outlier_thr),
-            mixed=mixed_on, interpret=not on_tpu)
-        final_mask = jnp.where(
-            res["is_std"][..., None], out["alive"],
-            jnp.where(res["is_alt"][..., None], res["alt_mask"], False))
-        return ChipSegments(
-            n_segments=out["nseg"], seg_meta=out["meta"],
-            seg_rmse=out["rmse"], seg_mag=out["mag"],
-            seg_coef=out["coef"], mask=final_mask,
-            procedure=res["procedure"], rounds=out["rounds"],
-            vario=res["vario"], round_counts=out["counts"])
+        _prologue, sensor=sensor, S=S, fdtype=fdtype,
+        guards=compact_on))(Xs, Xts, ts, valids, Ys, qas)
 
     initf = jax.vmap(functools.partial(
-        _init_block, sensor=sensor, W=W, fdtype=fdtype, fit=fit,
-        f32_ok=f32_ok, mixed=mixed_on, guards=compact_on))
+        _init_block, sensor=sensor, W=W, fdtype=fdtype, pallas=pallas,
+        guards=compact_on))
     monf = jax.vmap(functools.partial(
         _mon_block, sensor=sensor, change_thr=change_thr,
-        outlier_thr=outlier_thr, f32_ok=f32_ok, guards=compact_on))
+        outlier_thr=outlier_thr, pallas=pallas, guards=compact_on))
     closef = jax.vmap(functools.partial(_close_block, S=S, fdtype=fdtype))
     if compact_on:
-        fitf = jax.vmap(lambda r, w, n, a: fit(r, w, _coefmask_for(n),
-                                               active=a))
+        fitf = jax.vmap(lambda r, w, n, a: _fit_chip(r, w, _coefmask_for(n),
+                                                     active=a))
     else:
-        fitf = jax.vmap(lambda r, w, n: fit(r, w, _coefmask_for(n)))
-    if fused_mon:
-        from firebird_tpu.ccd import pallas_ops
-
-        def _round_chip(r, st_c, init_c, act=None):
-            in_mon_c = st_c["phase"] == PHASE_MONITOR
-            return pallas_ops.fused_round(
-                r["Yt"], r["X"], r["t"], st_c["alive"], st_c["included"],
-                st_c["cur_k"], st_c["n_last_fit"], in_mon_c,
-                st_c["coefs"], st_c["rmse"], r["vario"],
-                init_c["init_ok"], init_c["w_stab"], init_c["n_ok"],
-                st_c["first_seg"], st_c["nseg"], st_c["bufs"], S=S,
-                sensor=sensor, change_thr=float(change_thr),
-                outlier_thr=float(outlier_thr), mixed=mixed_on,
-                active=act, interpret=not on_tpu)
-
-        roundf = jax.vmap(_round_chip) if compact_on \
-            else jax.vmap(functools.partial(_round_chip, act=None))
-    elif fused_on:
-        from firebird_tpu.ccd import pallas_ops
-
-        def _fused_chip(r, w, df, nf, mg, st_c, mn_c, act=None):
-            return pallas_ops.fused_fit_close(
-                r["Yt"], r["X"], r["t"], w, df, nf,
-                mn_c["included_mon"], st_c["coefs"], st_c["rmse"], mg,
-                mn_c["is_tail"], mn_c["is_brk"],
-                mn_c["pos_ev"], mn_c["n_exceed"],
-                st_c["first_seg"], st_c["nseg"], st_c["bufs"], S=S,
-                mixed=mixed_on, active=act, interpret=not on_tpu)
-
-        fusedf = jax.vmap(_fused_chip) if compact_on \
-            else jax.vmap(functools.partial(_fused_chip, act=None))
-        magsf = jax.vmap(functools.partial(_close_mags, fdtype=fdtype))
+        fitf = jax.vmap(lambda r, w, n: _fit_chip(r, w, _coefmask_for(n)))
 
     max_rounds = 2 * T + 8
 
@@ -1551,15 +1294,7 @@ def _detect_batch_impl(Xs, Xts, ts, valids, Ys, qas, *, wcap, sensor,
     # mirror the blocks' trace-time routing exactly — a path that would
     # read an uncarried resident fails loudly at trace (KeyError), never
     # silently reads the unpermuted original.
-    score_pallas = use_pallas("score") and f32_ok
-    init_pallas = use_pallas("init") and f32_ok
-    resp_keys = ["vario"]
-    if "Y" in res:
-        resp_keys.append("Y")
-    if fit_pallas or init_pallas or fused_on or "Y" not in res:
-        resp_keys.append("Yt")
-    if score_pallas:
-        resp_keys.append("Yd")
+    resp_keys = ["vario", "Y"] + (["Yd"] if "score" in pallas else [])
     res_shared = {k: res[k] for k in ("X", "Xt", "t", "XX")}
 
     if compact_on:
@@ -1620,101 +1355,34 @@ def _detect_batch_impl(Xs, Xts, ts, valids, Ys, qas, *, wcap, sensor,
                             lambda: initf(res_l, st),
                             lambda: _init_zeros(st))
 
-            if fused_mon:
-                # Whole-round fusion: monitor chain + segment close +
-                # shared refit run as ONE pallas_call per chip
-                # (pallas_ops.fused_round), so the separate monf/closef/
-                # fitf conds collapse into a single any-work gate.  The
-                # INIT block stays cond-gated outside (rare after
-                # warmup) and hands its fit window into the kernel; the
-                # event flags come back in ``ev`` and feed the same
-                # next-state code as the other routes.
-                def _run_round():
-                    if compact_on:
-                        return roundf(res_l, st, init,
-                                      in_mon | init["init_ok"])
-                    return roundf(res_l, st, init)
+            mon = lax.cond(jnp.any(in_mon),
+                           lambda: monf(res_l, st),
+                           lambda: _mon_zeros(st))
 
-                def _skip_round():
-                    zb = jnp.zeros_like(in_mon)
-                    zi = jnp.zeros_like(st["cur_i"])
-                    ev0 = dict(is_tail=zb, is_brk=zb, is_refit=zb,
-                               pos_ev=zi, do_fit=zb, n_full=zi,
-                               included_mon=st["included"],
-                               alive_mon=st["alive"])
-                    return (st["bufs"], st["nseg"], st["coefs"],
-                            st["rmse"], ev0)
+            close = mon["is_tail"] | mon["is_brk"]
+            any_close = jnp.any(close)
+            # Refit / init-ok shared fit (skipped when no pixel needs one).
+            init_ok, is_refit = init["init_ok"], mon["is_refit"]
+            do_fit = init_ok | is_refit
+            any_fit = jnp.any(do_fit)
+            n_full = jnp.where(init_ok, init["n_ok"], mon["n_rf"])
 
-                bufs, nseg, cfull, rfull, ev = lax.cond(
-                    jnp.any(in_mon) | jnp.any(init["init_ok"]),
-                    _run_round, _skip_round)
-                mon = dict(is_tail=ev["is_tail"], is_brk=ev["is_brk"],
-                           is_refit=ev["is_refit"], pos_ev=ev["pos_ev"],
-                           included_mon=ev["included_mon"],
-                           alive_mon=ev["alive_mon"])
-                close = mon["is_tail"] | mon["is_brk"]
-                any_close = jnp.any(close)
-                init_ok, is_refit = init["init_ok"], mon["is_refit"]
-                do_fit, n_full = ev["do_fit"], ev["n_full"]
-                any_fit = jnp.any(do_fit)
-            else:
-                mon = lax.cond(jnp.any(in_mon),
-                               lambda: monf(res_l, st),
-                               lambda: _mon_zeros(st))
+            bufs, nseg = lax.cond(any_close,
+                                  lambda: closef(res_l, st, mon),
+                                  lambda: (st["bufs"], st["nseg"]))
 
-                close = mon["is_tail"] | mon["is_brk"]
-                any_close = jnp.any(close)
-                # Refit / init-ok shared fit (skipped when no pixel
-                # needs one).
-                init_ok, is_refit = init["init_ok"], mon["is_refit"]
-                do_fit = init_ok | is_refit
-                any_fit = jnp.any(do_fit)
-                n_full = jnp.where(init_ok, init["n_ok"], mon["n_rf"])
+            def _run_fit():
+                # The [C,P,T] fit-window build lives inside the branch so
+                # a no-fit round materializes nothing.
+                w = jnp.where(init_ok[..., None], init["w_stab"],
+                              mon["included_mon"] & is_refit[..., None])
+                w = w.astype(fdtype)
+                if compact_on:
+                    return fitf(res_l, w, n_full, do_fit)
+                return fitf(res_l, w, n_full)
 
-            def _w_full():
-                # The [C,P,T] fit-window build lives inside the branches
-                # so a no-fit round materializes nothing.
-                return jnp.where(init_ok[..., None], init["w_stab"],
-                                 mon["included_mon"] & is_refit[..., None])
-
-            if fused_mon:
-                pass        # bufs/nseg/cfull/rfull merged in-kernel above
-            elif fused_on:
-                # One fused pallas_call serves the close AND the shared
-                # fit on a single VMEM residency of the wire spectra;
-                # the do_fit coefs/rmse merge happens in-kernel, so the
-                # branch returns the MERGED model directly.  The break
-                # magnitudes stay on the shared _close_mags program
-                # under their own (rare) any-break cond — the identical
-                # code on fused and unfused paths, which is what keeps
-                # the golden byte-identical instead of envelope-bound.
-                def _run_fused():
-                    w = _w_full().astype(fdtype)
-                    mg = lax.cond(jnp.any(mon["is_brk"]),
-                                  lambda: magsf(res_l, st, mon),
-                                  lambda: jnp.zeros_like(st["rmse"]))
-                    if compact_on:
-                        return fusedf(res_l, w, do_fit, n_full, mg, st,
-                                      mon, do_fit | close)
-                    return fusedf(res_l, w, do_fit, n_full, mg, st, mon)
-
-                bufs, nseg, cfull, rfull = lax.cond(
-                    any_close | any_fit, _run_fused,
-                    lambda: (st["bufs"], st["nseg"], st["coefs"],
-                             st["rmse"]))
-            else:
-                bufs, nseg = lax.cond(any_close,
-                                      lambda: closef(res_l, st, mon),
-                                      lambda: (st["bufs"], st["nseg"]))
-
-                def _run_fit():
-                    w = _w_full().astype(fdtype)
-                    if compact_on:
-                        return fitf(res_l, w, n_full, do_fit)
-                    return fitf(res_l, w, n_full)
-
-                cfull, rfull = lax.cond(any_fit, _run_fit,
-                                        lambda: (st["coefs"], st["rmse"]))
+            cfull, rfull = lax.cond(any_fit, _run_fit,
+                                    lambda: (st["coefs"], st["rmse"]))
 
             # ============== next state (batched elementwise) ============
             is_tail, is_brk = mon["is_tail"], mon["is_brk"]
@@ -1740,12 +1408,8 @@ def _detect_batch_impl(Xs, Xts, ts, valids, Ys, qas, *, wcap, sensor,
                 jnp.where(is_brk[..., None], False,
                           jnp.where(in_mon[..., None], mon["included_mon"],
                                     st["included"])))
-            if fused_on:
-                coefs_n, rmse_n = cfull, rfull    # merged in-kernel
-            else:
-                coefs_n = jnp.where(do_fit[..., None, None], cfull,
-                                    st["coefs"])
-                rmse_n = jnp.where(do_fit[..., None], rfull, st["rmse"])
+            coefs_n = jnp.where(do_fit[..., None, None], cfull, st["coefs"])
+            rmse_n = jnp.where(do_fit[..., None], rfull, st["rmse"])
             nlast_n = jnp.where(do_fit, n_full.astype(jnp.int32),
                                 st["n_last_fit"])
             first_n = st["first_seg"] & ~is_brk
@@ -1941,27 +1605,25 @@ def device_designs(days, n_obs, dtype):
 
 def _detect_batch_wire(days_i32, n_obs_i32, Y_i16, qa_wire, *, dtype,
                        wcap=None, sensor=LANDSAT_ARD,
-                       max_segments=MAX_SEGMENTS, compact=None,
-                       fused=None, mixed=None):
+                       max_segments=MAX_SEGMENTS, compact=None):
     """Batch detect from the all-integer wire: spectra ride int16, QA
     uint8/uint16, and the day ordinals ride int32 — the harmonic design
     matrices, the float date grid, and the validity mask are built on
     device by :func:`device_designs` inside this jitted prologue, so NO
     float plane crosses host->device at all (docs/ROOFLINE.md "Wire
     budget").  The core widens the spectra on device and keeps a
-    wire-dtype resident copy so the Pallas fit path reads int16 from HBM.
+    wire-dtype resident copy of the detection bands so the score-fused
+    monitor kernel reads int16 from HBM.
     ``compact`` (static) is the active-lane-compaction override (None =
     FIREBIRD_COMPACT at trace time)."""
     Xs, Xts, ts, valids = device_designs(days_i32, n_obs_i32, dtype)
     return _detect_batch_core(Xs, Xts, ts, valids, Y_i16,
                               qa_wire.astype(jnp.int32), wcap=wcap,
                               sensor=sensor, max_segments=max_segments,
-                              dtype=dtype, compact=compact, fused=fused,
-                              mixed=mixed)
+                              dtype=dtype, compact=compact)
 
 
-_WIRE_STATICS = ("dtype", "wcap", "sensor", "max_segments", "compact",
-                 "fused", "mixed")
+_WIRE_STATICS = ("dtype", "wcap", "sensor", "max_segments", "compact")
 # Donating twin for the driver's staged steady-state dispatch: the packed
 # wire buffers (spectra + QA, the dominant HBM input term) are consumed by
 # the dispatch, so a deeper pipeline (Config.pipeline_depth) doesn't pin
@@ -2061,28 +1723,8 @@ def working_set_bytes(T: int, W: int | None = None,
     bufs = 2 * P * S * (6 + 2 * B + B * K) * dtype_bytes
     widened = 2 * P * B * T * dtype_bytes
     pt_temps = 20 * P * T * dtype_bytes
-    # The [P,W,T] one-hot window tensors exist only on the XLA INIT path;
-    # the fused Pallas INIT kernel (FIREBIRD_PALLAS=init) and the
-    # whole-loop mega kernel never materialize them, so batches can size
-    # past that peak — but mega earns the exemption only on shapes
-    # mega_fits ACCEPTS: a refused mega falls back to the XLA init path
-    # and its one-hot peak (kernel._detect_batch_impl), which the batch
-    # sizing must then have budgeted.  The widened-view and temporary
-    # terms stay even for mega: the PROLOGUE (triage/variogram/alt fit)
-    # runs identically in every config and its [P,B,T]-scale float peak
-    # is the sizing constraint regardless of how lean the loop itself
-    # is.  The kernel route is f32-only on TPU (Mosaic), so f64 sizing
-    # keeps the term.
-    def _mega_applies() -> bool:
-        if not use_pallas("mega"):
-            return False
-        from firebird_tpu.ccd import pallas_ops
-
-        return pallas_ops.mega_fits(T, W, B, S, 2)
-
-    onehot = (0 if (use_pallas("init") or _mega_applies())
-              and dtype_bytes == 4
-              else P * W * T * (1 + dtype_bytes))
+    # The [P,W,T] one-hot window tensors of the INIT block.
+    onehot = P * W * T * (1 + dtype_bytes)
     return int(wire + widened + pt_temps + onehot + bufs)
 
 
@@ -2141,7 +1783,7 @@ def record_occupancy(seg) -> dict | None:
     (paid - active) lane-rounds and compactions — the padded-vs-effective
     accounting flops.occupancy_detail turns into the bench artifact.
     Returns the summary dict, or None when the dispatch carried no
-    occupancy capture (mega route, pre-compaction artifacts)."""
+    occupancy capture (pre-compaction artifacts)."""
     occ = getattr(seg, "occupancy", None)
     if occ is None:
         return None
@@ -2265,8 +1907,7 @@ def stage_packed(packed, dtype) -> tuple:
 
 def aot_compile(avatars, *, dtype, wcap, sensor=LANDSAT_ARD,
                 max_segments: int = MAX_SEGMENTS, donate: bool = False,
-                compact: bool | None = None, fused=None,
-                mixed: bool | None = None):
+                compact: bool | None = None):
     """AOT lower+compile the wire-dtype batch program for a shape WITHOUT
     running it (``avatars`` are jax.ShapeDtypeStructs in the
     ``_detect_batch_wire`` argument order: days int32 [C,T], n_obs int32
@@ -2281,15 +1922,14 @@ def aot_compile(avatars, *, dtype, wcap, sensor=LANDSAT_ARD,
     fn = _detect_batch_wire_donated if donate else _detect_batch_wire
     return fn.lower(*avatars, dtype=jnp.dtype(dtype), wcap=wcap,
                     sensor=sensor, max_segments=max_segments,
-                    compact=compact, fused=fused, mixed=mixed).compile()
+                    compact=compact).compile()
 
 
 def detect_packed(packed, dtype=jnp.float32,
                   max_segments: int = MAX_SEGMENTS,
                   check_capacity: bool = True, staged: tuple | None = None,
                   donate: bool = False,
-                  compact: bool | None = None,
-                  fused=None, mixed: bool | None = None) -> ChipSegments:
+                  compact: bool | None = None) -> ChipSegments:
     """Run the kernel over a PackedChips batch -> ChipSegments with leading
     chip axis [C, P, ...].  The batch's sensor spec selects the band
     layout the kernel compiles for.
@@ -2308,20 +1948,18 @@ def detect_packed(packed, dtype=jnp.float32,
     instead of transferring here; ``donate=True`` (honored only with
     ``check_capacity=False`` — a retry would re-dispatch deleted buffers)
     frees the wire input buffers at dispatch.  ``compact`` overrides the
-    FIREBIRD_COMPACT default (params.compact_default) per call;
-    ``fused`` (False/True/"mon") and ``mixed`` likewise override
-    FIREBIRD_FUSED_FIT / FIREBIRD_MIXED_PRECISION.
+    FIREBIRD_COMPACT default (params.compact_default) per call.
     """
     ensure_x64(dtype)
     args = staged if staged is not None else stage_packed(packed, dtype)
     kw = dict(dtype=jnp.dtype(dtype), wcap=window_cap(packed),
               sensor=getattr(packed, "sensor", LANDSAT_ARD),
-              compact=compact, fused=fused, mixed=mixed)
+              compact=compact)
     fn = _detect_batch_wire_donated if donate and not check_capacity \
         else _detect_batch_wire
     dispatch = lambda S: record_first_call(
         ("single", packed.spectra.shape, str(kw["dtype"]), kw["wcap"],
-         kw["sensor"].name, S, compact, fused, mixed),
+         kw["sensor"].name, S, compact),
         lambda: fn(*args, max_segments=S, **kw))
     if not check_capacity:
         return dispatch(max(max_segments, 1))

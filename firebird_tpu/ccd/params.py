@@ -106,22 +106,6 @@ REFIT_FACTOR = 1.33
 LASSO_ALPHA = 1.0
 LASSO_ITERS = 50
 
-# Mixed-precision coef/rmse drift budget (FIREBIRD_MIXED_PRECISION):
-# max scale-anchored ulp distance |mixed - f32| / (eps32 * scale)
-# enforced by tools/precision_smoke.py and tests/test_precision.py,
-# where ``scale`` anchors at the magnitude the error actually
-# propagates from — max(|f32 value|, 1) for rmse, and the coefficient
-# VECTOR's max(|coef|, 1) per (pixel, band, segment) for coefs (a
-# lasso-thresholded near-zero coefficient absorbs absolute error
-# proportional to its siblings' scale, so elementwise ulps there are
-# meaningless).  The bf16 split-dot gram carries ~2^-17 relative error
-# into the normal equations versus f32's ~2^-24; measured drift on the
-# adversarial-fuzz chip is ~340 coef / ~670 rmse scaled ulps, while a
-# naive bf16 weight cast (the bug this budget exists to catch) lands
-# ~2^15.  Decisions (break day/QA/segment count/curve rank) must be
-# IDENTICAL — the budget applies only to the continuous payload.
-MIXED_ULP_BUDGET = 1 << 12
-
 # Tmask robust screen: IRLS (Huber weights) harmonic fit without trend on
 # TMASK_BANDS; an observation is an outlier if |residual| exceeds
 # TMASK_CONST * max(variogram, rmse) in any Tmask band.

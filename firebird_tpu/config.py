@@ -297,21 +297,8 @@ KNOBS = (
          readers=("tools/compact_smoke.py",),  # pins the child kernel's env
          help="bucket fraction that triggers loop re-entry"),
     Knob(name="FIREBIRD_PALLAS", default="0",
-         help="Pallas kernel component selection (0/1/comma list)"),
-    Knob(name="FIREBIRD_FUSED_FIT", default="0",
-         help="fused gram→CD→close Pallas round kernel (one VMEM "
-              "residency serves the close + shared-fit pair); 'mon' "
-              "(or 2) widens the fusion to the whole post-INIT round — "
-              "monitor chain + close + fit in one pallas_call"),
-    Knob(name="FIREBIRD_MIXED_PRECISION", default="0",
-         help="bf16 split-dot gram + int32 counts inside the Pallas fit "
-              "routes, f32 decision envelope (f32 stores only; XLA "
-              "paths stay f32 and are the decision-identity oracle)"),
-    Knob(name="FIREBIRD_MEGA_BLOCK_P", default="0",
-         help="static lane-block width override for the mega/fused-round "
-              "kernels (multiple of 128; 0 = size from the VMEM budget; "
-              "bench seeds it from fuse_repro.json's smallest compiling "
-              "block)"),
+         help="Pallas kernel components: 0 (none), 1 (all), or a comma "
+              "list of monitor, score, tmask"),
     Knob(name="FIREBIRD_REBALANCE", default="0",
          help="cross-device straggler rebalancing ring at the "
               "bucketed-tail boundary (sharded dispatches)"),
@@ -383,11 +370,6 @@ KNOBS = (
          help="wire-smoke artifact directory"),
     Knob(name="FIREBIRD_PYRAMID_DIR", default="/tmp/fb_pyramid",
          help="pyramid-smoke artifact directory"),
-    Knob(name="FIREBIRD_FUSE_DIR", default="/tmp/fb_fuse",
-         help="fuse-smoke / fuse-repro artifact directory"),
-    Knob(name="FIREBIRD_PRECISION_DIR", default="/tmp/fb_precision",
-         readers=("tools/precision_smoke.py",),
-         help="precision-smoke artifact directory"),
     Knob(name="FIREBIRD_LINT_DIR", default="/tmp/fb_lint",
          readers=("Makefile",), internal=True,
          help="lint-report artifact directory (make lint)"),

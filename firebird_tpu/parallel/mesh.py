@@ -330,8 +330,7 @@ def detect_sharded(packed, mesh: Mesh, dtype=None,
                    check_capacity: bool = True,
                    max_segments: int | None = None,
                    staged: tuple | None = None, donate: bool = False,
-                   compact: bool | None = None,
-                   fused=None, mixed: bool | None = None):
+                   compact: bool | None = None):
     """Run the CCD kernel with the chip batch sharded over the mesh.
 
     This is the multi-device production path: same math as
@@ -340,7 +339,7 @@ def detect_sharded(packed, mesh: Mesh, dtype=None,
     the zero-collective property (any accidental cross-chip dependence
     would fail to trace rather than silently all-gather), and (b) gives
     each shard a plain single-device context, so Mosaic custom calls (the
-    Pallas CD kernel, FIREBIRD_PALLAS=1) need no SPMD partitioning rule.
+    Pallas kernels, FIREBIRD_PALLAS=1) need no SPMD partitioning rule.
 
     ``staged`` takes the ``(args, wcap)`` pair from :func:`stage_sharded`
     instead of transferring here; ``donate=True`` (honored only with
@@ -349,8 +348,7 @@ def detect_sharded(packed, mesh: Mesh, dtype=None,
     overrides FIREBIRD_COMPACT per call (kernel._detect_batch_core;
     compaction is per-shard — each shard permutes its own chips' lanes,
     so no cross-shard dependence is introduced and the zero-collective
-    property holds).  ``fused`` (False/True/"mon") and ``mixed``
-    override FIREBIRD_FUSED_FIT / FIREBIRD_MIXED_PRECISION likewise.
+    property holds).
 
     The one deliberate exception to zero-collectives is the straggler
     rebalancing ring (FIREBIRD_REBALANCE, default off): three
@@ -376,11 +374,10 @@ def detect_sharded(packed, mesh: Mesh, dtype=None,
         fn = sharded_detect_fn(mesh, jnp.dtype(dtype), wcap,
                                packed.sensor, max_segments=S,
                                donate=do_donate, compact=compact,
-                               fused=fused, mixed=mixed, rebalance=rb)
+                               rebalance=rb)
         return record_first_call(
             ("sharded", packed.spectra.shape, str(jnp.dtype(dtype)), wcap,
-             packed.sensor.name, S, len(mesh.devices.flat), compact,
-             fused, mixed, rb),
+             packed.sensor.name, S, len(mesh.devices.flat), compact, rb),
             lambda: fn(*args))
 
     def read_worst(seg):
@@ -402,7 +399,6 @@ def sharded_detect_fn(mesh: Mesh, dtype, wcap: int, sensor,
                       max_segments: int | None = None,
                       donate: bool = False,
                       compact: bool | None = None,
-                      fused=None, mixed: bool | None = None,
                       rebalance: RebalanceSpec | None = None):
     """The jitted shard_map program, cached per (mesh, dtype, wcap, sensor,
     capacity) — rebuilding the jit wrapper per batch would retrace every
@@ -417,15 +413,14 @@ def sharded_detect_fn(mesh: Mesh, dtype, wcap: int, sensor,
 
     core = functools.partial(_detect_batch_core, wcap=wcap, sensor=sensor,
                              max_segments=max_segments or MAX_SEGMENTS,
-                             dtype=dtype, compact=compact, fused=fused,
-                             mixed=mixed, rebalance=rebalance)
+                             dtype=dtype, compact=compact,
+                             rebalance=rebalance)
 
     def local_batch(days, n_obs, Y_i16, qa_wire):
         # All-integer wire: each shard builds its own chips' float
         # designs/date grid/validity mask on device (kernel.device_designs
         # is per-chip math — no cross-shard dependence), and the core
-        # widens the spectra itself, keeping an int16 resident copy for
-        # the Pallas fit path's HBM reads.  The batched core (not vmap of
+        # widens the spectra itself.  The batched core (not vmap of
         # the per-chip core): its phase-gated lax.conds must stay scalar
         # per shard to skip work.
         from firebird_tpu.ccd.kernel import device_designs
@@ -450,8 +445,7 @@ def sharded_detect_fn(mesh: Mesh, dtype, wcap: int, sensor,
 def aot_compile_sharded(mesh: Mesh, dtype, wcap: int, sensor, shapes,
                         max_segments: int | None = None,
                         donate: bool = False,
-                        compact: bool | None = None,
-                        fused=None, mixed: bool | None = None):
+                        compact: bool | None = None):
     """AOT lower+compile the sharded batch program for a shape without
     running it (``shapes``: the 4 global array shapes in shard_packed's
     argument order — days [C,T], n_obs [C], spectra [C,B,P,T], QA
@@ -465,8 +459,7 @@ def aot_compile_sharded(mesh: Mesh, dtype, wcap: int, sensor, shapes,
 
     fn = sharded_detect_fn(mesh, jnp.dtype(dtype), wcap, sensor,
                            max_segments=max_segments, donate=donate,
-                           compact=compact, fused=fused, mixed=mixed,
-                           rebalance=rebalance_spec(mesh))
+                           compact=compact, rebalance=rebalance_spec(mesh))
     sh = chip_sharding(mesh)
     dts = (jnp.int32, jnp.int32, jnp.int16, wire_qa_dtype())
     avatars = tuple(jax.ShapeDtypeStruct(s, jnp.dtype(d), sharding=sh)

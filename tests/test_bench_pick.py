@@ -1,7 +1,7 @@
 """The autotune pick policy (bench.autotune_parity / autotune_pick) is
 decision-gated: a Pallas config that flips any pixel's structural
 decisions vs the XLA baseline is demoted regardless of speed
-(docs/DIVERGENCE.md #1 mega row; VERDICT r3 #3 enforcement side).
+(VERDICT r3 #3 enforcement side).
 
 Pure-function tests — the TPU-only autotune block in bench.measure
 composes exactly these, so the policy is provable without hardware.
@@ -41,15 +41,15 @@ def _probe(n_pixels=4, flip_day=None, flip_nseg=None, jitter_chprob=False):
 
 
 def test_parity_exact_and_flips():
-    outs = {"0": _probe(), "mega": _probe(),
+    outs = {"0": _probe(), "monitor,tmask": _probe(),
             "score": _probe(flip_day=1),
-            "fit": _probe(flip_nseg=2),
+            "tmask": _probe(flip_nseg=2),
             "monitor": _probe(jitter_chprob=True)}
     parity, exact = autotune_parity(outs)
-    assert exact == {"mega": True, "score": False, "fit": False,
-                     "monitor": True}
+    assert exact == {"monitor,tmask": True, "score": False,
+                     "tmask": False, "monitor": True}
     assert parity["score"]["decision_agree"] == 0.75
-    assert parity["fit"]["nseg_agree"] == 0.75
+    assert parity["tmask"]["nseg_agree"] == 0.75
     # chprob jitter is invisible to the decision gate but visible to the
     # 2e-4 meta envelope only if it exceeds atol (1e-5 doesn't)
     assert parity["monitor"]["decision_agree"] == 1.0
@@ -59,33 +59,34 @@ def test_parity_exact_and_flips():
 def test_single_pixel_flip_gates_even_when_fraction_rounds_to_one():
     """The gate must use the exact predicate: one flipped pixel in 20001
     rounds to decision_agree == 1.0 but still demotes."""
-    outs = {"0": _probe(n_pixels=20001), "mega": _probe(n_pixels=20001,
-                                                       flip_day=7)}
+    outs = {"0": _probe(n_pixels=20001), "score": _probe(n_pixels=20001,
+                                                        flip_day=7)}
     parity, exact = autotune_parity(outs)
-    assert parity["mega"]["decision_agree"] == 1.0   # display rounds up
-    assert exact["mega"] is False                    # gate does not
+    assert parity["score"]["decision_agree"] == 1.0   # display rounds up
+    assert exact["score"] is False                    # gate does not
     pick, demoted, unavailable = autotune_pick(
-        {"0": 1.0, "mega": 9.9}, {}, exact)
-    assert pick == "0" and demoted == ["mega"] and not unavailable
+        {"0": 1.0, "score": 9.9}, {}, exact)
+    assert pick == "0" and demoted == ["score"] and not unavailable
 
 
 def test_fastest_clean_config_wins():
-    exact = {"mega": True, "score": True, "fit": False}
+    exact = {"monitor,tmask": True, "score": True, "tmask": False}
     pick, demoted, unavailable = autotune_pick(
-        {"0": 1.0, "mega": 3.0, "score": 2.0, "fit": 5.0}, {}, exact)
-    assert pick == "mega"
-    assert demoted == ["fit"]
+        {"0": 1.0, "monitor,tmask": 3.0, "score": 2.0, "tmask": 5.0}, {},
+        exact)
+    assert pick == "monitor,tmask"
+    assert demoted == ["tmask"]
     assert not unavailable
 
 
 def test_errored_config_excluded_but_not_demoted():
     # 'tmask' errored: rate 0.0, no parity entry -> neither picked nor
     # listed as a decision divergence (it never produced decisions).
-    exact = {"mega": True}
+    exact = {"monitor,tmask": True}
     pick, demoted, _ = autotune_pick(
-        {"0": 1.0, "mega": 2.0, "tmask": 0.0},
+        {"0": 1.0, "monitor,tmask": 2.0, "tmask": 0.0},
         {"tmask": "RuntimeError('Mosaic')"}, exact)
-    assert pick == "mega"
+    assert pick == "monitor,tmask"
     assert demoted == []
 
 
@@ -93,9 +94,9 @@ def test_baseline_error_falls_back_to_fastest_measured():
     # '0' probe errored -> no parity evidence at all; the fastest config
     # that actually ran wins and the artifact says parity_unavailable.
     pick, demoted, unavailable = autotune_pick(
-        {"0": 0.0, "mega": 2.0, "score": 1.0},
+        {"0": 0.0, "monitor,tmask": 2.0, "score": 1.0},
         {"0": "RuntimeError('compile hiccup')"}, {})
-    assert pick == "mega"
+    assert pick == "monitor,tmask"
     assert demoted == []
     assert unavailable
 
@@ -105,8 +106,8 @@ def test_baseline_ok_all_others_errored_is_not_parity_unavailable():
     # described by the errors dict, so parity_unavailable must NOT be
     # set (it is reserved for 'the baseline probe itself errored').
     pick, demoted, unavailable = autotune_pick(
-        {"0": 1.0, "mega": 0.0, "score": 0.0},
-        {"mega": "RuntimeError", "score": "RuntimeError"}, {})
+        {"0": 1.0, "monitor,tmask": 0.0, "score": 0.0},
+        {"monitor,tmask": "RuntimeError", "score": "RuntimeError"}, {})
     assert pick == "0"
     assert demoted == []
     assert not unavailable
@@ -150,7 +151,7 @@ def test_scrub_artifact_truncates_error_fields_only():
         "detail": {
             "note": "n" * 2000,                      # not an error key
             "pallas_autotune": {
-                "errors": {"mega": "\x1b[31m" + "x" * 5000 + "\x1b[0m"},
+                "errors": {"score": "\x1b[31m" + "x" * 5000 + "\x1b[0m"},
             },
             "child": {"tail": "t" * 5000},
             "nested": ["\x1b[2mdim\x1b[0m", 3],
@@ -158,7 +159,7 @@ def test_scrub_artifact_truncates_error_fields_only():
     }
     out = scrub_artifact(rec)
     assert out["value"] == 1.5
-    err = out["detail"]["pallas_autotune"]["errors"]["mega"]
+    err = out["detail"]["pallas_autotune"]["errors"]["score"]
     assert len(err) < ERR_TEXT_LIMIT + 40 and "\x1b" not in err
     assert len(out["detail"]["child"]["tail"]) < ERR_TEXT_LIMIT + 40
     assert out["detail"]["note"] == "n" * 2000       # non-error text intact
@@ -193,68 +194,32 @@ def test_bench_exits_nonzero_without_tpu(capsys):
     assert not [line for line in out.splitlines() if line.startswith("{")]
 
 
-def test_repro_block_seeds_parsing(tmp_path, monkeypatch):
-    """The fuse_repro.json -> block-seed contract: absent file and
-    unreachable-Mosaic artifacts yield no seeds; a reachable artifact
-    yields only the pairings whose ladder actually found a compiling
-    block (null smallest_ok_block rows drop out)."""
-    import json as _json
-
-    from bench import repro_block_seeds
-
-    monkeypatch.setenv("FIREBIRD_FUSE_DIR", str(tmp_path))
-    assert repro_block_seeds() == {}                  # no artifact yet
-    art = tmp_path / "fuse_repro.json"
-    art.write_text(_json.dumps({
-        "mosaic_reachable": False,
-        "probes": {"mega": {"smallest_ok_block": 256}}}))
-    assert repro_block_seeds() == {}                  # advisory-only host
-    art.write_text(_json.dumps({
-        "mosaic_reachable": True,
-        "probes": {"mega": {"smallest_ok_block": 256},
-                   "mon+mixed": {"smallest_ok_block": 128},
-                   "fused": {"smallest_ok_block": None}}}))
-    assert repro_block_seeds() == {"mega": 256, "mon+mixed": 128}
-    art.write_text("not json")
-    assert repro_block_seeds() == {}                  # corrupt artifact
-
-
 def test_apply_tune_flag_env_grammar():
-    """Every rung shape the autotune races maps to exactly one env
-    combination (FIREBIRD_FUSED_FIT tier, FIREBIRD_PALLAS components,
-    FIREBIRD_MIXED_PRECISION, FIREBIRD_MEGA_BLOCK_P seed)."""
+    """Every rung the autotune races maps to exactly one FIREBIRD_PALLAS
+    value, which the kernel parses: the XLA loop and the component
+    lists."""
     from bench import apply_tune_flag
+    from firebird_tpu.ccd import kernel
 
     # apply_tune_flag writes os.environ directly, and monkeypatch.delenv
     # on an ABSENT key registers no undo — snapshot/restore by hand or
-    # the last case's fused/mixed env leaks into the whole suite.
-    keys = ("FIREBIRD_FUSED_FIT", "FIREBIRD_PALLAS",
-            "FIREBIRD_MIXED_PRECISION", "FIREBIRD_MEGA_BLOCK_P")
-    saved = {k: os.environ.get(k) for k in keys}
-    seeds = {"mega": 256, "mega+mixed": 384, "mon": 128,
-             "mon+mixed": 512, "fused": 640}
+    # the last case's env leaks into the whole suite.
+    saved = os.environ.get("FIREBIRD_PALLAS")
     cases = {
-        # flag -> (FUSED_FIT, PALLAS, MIXED, BLOCK_P)
-        "0": ("0", "0", "0", "0"),
-        "fit,init": ("0", "fit,init", "0", "0"),
-        "mega": ("0", "mega", "0", "256"),
-        "mega+mixed": ("0", "mega", "1", "384"),
-        "mixed": ("0", "0", "1", "0"),
-        "fused": ("1", "0", "0", "640"),
-        "fused+fit,init": ("1", "fit,init", "0", "640"),
-        "fused+fit,init+mixed": ("1", "fit,init", "1", "0"),
-        "mon": ("mon", "0", "0", "128"),
-        "mon+fit": ("mon", "fit", "0", "128"),
-        "mon+fit+mixed": ("mon", "fit", "1", "512"),
+        "0": set(),
+        "monitor": {"monitor"},
+        "tmask": {"tmask"},
+        "score": {"score"},
+        "monitor,tmask": {"monitor", "tmask"},
+        "monitor,score,tmask": {"monitor", "score", "tmask"},
     }
     try:
-        for flag, (ff, pal, mx, bp) in cases.items():
-            apply_tune_flag(flag, seeds)
-            got = tuple(os.environ[k] for k in keys)
-            assert got == (ff, pal, mx, bp), flag
+        for flag, comps in cases.items():
+            apply_tune_flag(flag)
+            assert os.environ["FIREBIRD_PALLAS"] == flag
+            assert kernel.pallas_components() == comps, flag
     finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+        if saved is None:
+            os.environ.pop("FIREBIRD_PALLAS", None)
+        else:
+            os.environ["FIREBIRD_PALLAS"] = saved

@@ -56,9 +56,7 @@ def _production_tracing(monkeypatch):
     written here can never be read back without a chip)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    for knob in ("FIREBIRD_PALLAS", "FIREBIRD_FUSED_FIT",
-                 "FIREBIRD_MIXED_PRECISION", "FIREBIRD_MEGA_BLOCK_P"):
-        monkeypatch.delenv(knob, raising=False)
+    monkeypatch.delenv("FIREBIRD_PALLAS", raising=False)
     was_cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -97,7 +95,7 @@ def test_main_step_compiles_and_fits_one_chip(compact, one_chip):
     compiled = kernel._detect_batch_wire_donated.lower(
         *avatars, dtype=jnp.dtype(jnp.float32), wcap=wcap,
         sensor=kernel.LANDSAT_ARD, max_segments=kernel.MAX_SEGMENTS,
-        compact=compact, fused=None, mixed=None).compile()
+        compact=compact).compile()
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
@@ -107,18 +105,12 @@ def test_main_step_compiles_and_fits_one_chip(compact, one_chip):
     assert 0 < total < HBM_BYTES
 
 
-# Which route each kernel is traced under, and the route's env.  Not
-# here: init_window, whose v5e compile aborts the process (a libtpu CHECK
-# failure, CHANGES.md PR 21; ROADMAP C1).
+# Which route each kernel is traced under, and the route's env ("score"
+# supersedes "monitor", so the plain chain needs a route without it).
 KERNEL_ROUTES = {
-    "lasso_cd": {"FIREBIRD_PALLAS": "lasso,monitor,tmask"},
-    "monitor_chain": {"FIREBIRD_PALLAS": "lasso,monitor,tmask"},
-    "tmask_bad": {"FIREBIRD_PALLAS": "lasso,monitor,tmask"},
-    "lasso_fit": {"FIREBIRD_PALLAS": "fit,score"},
-    "monitor_chain_scored": {"FIREBIRD_PALLAS": "fit,score"},
-    "fused_fit_close": {"FIREBIRD_FUSED_FIT": "1"},
-    "fused_round": {"FIREBIRD_FUSED_FIT": "mon"},
-    "detect_mega": {"FIREBIRD_PALLAS": "mega"},
+    "monitor_chain": {"FIREBIRD_PALLAS": "monitor,tmask"},
+    "tmask_bad": {"FIREBIRD_PALLAS": "monitor,tmask"},
+    "monitor_chain_scored": {"FIREBIRD_PALLAS": "score"},
 }
 
 

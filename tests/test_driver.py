@@ -415,10 +415,10 @@ def test_auto_chips_per_batch_sizes_from_device_memory():
     assert resolve_batching(Config(chips_per_batch=5), acq).chips_per_batch == 5
 
 
-def test_auto_chips_per_batch_grows_with_init_kernel(monkeypatch):
-    """The fused INIT kernel never materializes the [P,W,T] one-hot
-    window peak, so f32 batch sizing packs more chips — while f64 sizing
-    keeps the term (the Mosaic route is f32-on-TPU only)."""
+@pytest.mark.parametrize("pallas", ["tmask", "1"])
+def test_auto_chips_per_batch_is_route_independent(pallas, monkeypatch):
+    """Every Pallas route runs the XLA INIT block and its [P,W,T] one-hot
+    window peak, so batch sizing is the same whichever components run."""
     from firebird_tpu.ccd import kernel
     from firebird_tpu.driver.core import auto_chips_per_batch
 
@@ -426,29 +426,9 @@ def test_auto_chips_per_batch_grows_with_init_kernel(monkeypatch):
     acq = "1982-01-01/2017-12-31"
     monkeypatch.delenv("FIREBIRD_PALLAS", raising=False)
     base = auto_chips_per_batch(cfg, acq, device=FakeDevice(16e9))
-    base_ws64 = kernel.working_set_bytes(512, dtype_bytes=8)
-    monkeypatch.setenv("FIREBIRD_PALLAS", "init")
-    assert auto_chips_per_batch(cfg, acq, device=FakeDevice(16e9)) > base
-    assert kernel.working_set_bytes(512, dtype_bytes=8) == base_ws64
+    base_ws = kernel.working_set_bytes(512)
+    monkeypatch.setenv("FIREBIRD_PALLAS", pallas)
+    assert auto_chips_per_batch(cfg, acq, device=FakeDevice(16e9)) == base
+    assert kernel.working_set_bytes(512) == base_ws
 
 
-def test_auto_chips_per_batch_grows_with_mega(monkeypatch):
-    """The whole-loop mega kernel skips the [P,W,T] one-hot peak like the
-    init config, so f32 batch sizing grows vs the XLA path — but NOT past
-    the init config: the prologue's [P,B,T]-scale float peak runs
-    identically in every config and stays the sizing constraint."""
-    from firebird_tpu.ccd import kernel
-    from firebird_tpu.driver.core import auto_chips_per_batch
-
-    cfg = Config(chips_per_batch=0)
-    acq = "1982-01-01/2017-12-31"
-    monkeypatch.delenv("FIREBIRD_PALLAS", raising=False)
-    base = auto_chips_per_batch(cfg, acq, device=FakeDevice(16e9))
-    base_ws64 = kernel.working_set_bytes(512, dtype_bytes=8)
-    monkeypatch.setenv("FIREBIRD_PALLAS", "init")
-    with_init = auto_chips_per_batch(cfg, acq, device=FakeDevice(16e9))
-    monkeypatch.setenv("FIREBIRD_PALLAS", "mega")
-    with_mega = auto_chips_per_batch(cfg, acq, device=FakeDevice(16e9))
-    assert with_mega > base
-    assert with_mega == with_init
-    assert kernel.working_set_bytes(512, dtype_bytes=8) == base_ws64

@@ -5,6 +5,7 @@ XLA's own cost analysis of the same algebra."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from firebird_tpu.ccd import flops, params
 from firebird_tpu.ccd.sensor import LANDSAT_ARD, SENTINEL2
@@ -80,33 +81,29 @@ def test_bench_detail_shapes():
     assert c["model_flops_per_pixel"] == d["model_flops_per_pixel"]
 
 
-def test_mixed_block_models_pass_counts_not_new_flops():
-    """FIREBIRD_MIXED_PRECISION changes the MXU schedule, not the useful
-    arithmetic: every shared term (and total) is identical with and
-    without mixed=True, and the mixed sub-dict models exactly the
-    dot-stage pass trade (gram 6->2, corr 6->3, bf16 operands)."""
-    f32 = flops.round_flops(1000, 400, 120)
-    mx = flops.round_flops(1000, 400, 120, mixed=True)
-    assert {k: v for k, v in mx.items() if k != "mixed"} == f32
-    md = mx["mixed"]
-    assert (md["mxu_passes_f32"], md["mxu_passes_gram"],
-            md["mxu_passes_corr"]) == (6, 2, 3)
-    assert md["gram_operand_bytes_ratio"] == 0.5
-    g, c = md["gram_dot_flops"], md["corr_dot_flops"]
-    assert g > 0 and c > 0
-    assert md["dot_stage_speedup_model"] == round(
-        6.0 * (g + c) / (2.0 * g + 3.0 * c), 2)
-    # the schedule trade is strictly a win and bounded by the pass ratios
-    assert 2.0 < md["dot_stage_speedup_model"] < 3.0
 
 
-def test_round_bytes_is_mixed_invariant():
-    """The HBM model must NOT move under mixed: the wire spectra stream
-    int16 either way and the bf16 operands live at the VMEM->MXU
-    boundary (round_bytes' docstring is the written argument)."""
-    for pallas in ((), ("fit",), ("fit", "init", "score")):
-        a = flops.round_bytes(1000, 400, 120, 4, 4, rounds=12.0,
-                              pallas=pallas)
-        b = flops.round_bytes(1000, 400, 120, 4, 4, rounds=12.0,
-                              pallas=pallas, mixed=True)
-        assert a == b
+# The byte and flop counts of the default route and of the Pallas
+# components, pinned at the values the models gave while the routes that
+# never won on a chip still had terms in them: deleting those terms must
+# not move these.  (P, T, W, S, rounds, phase rounds) per sensor.
+_SHAPES = {"landsat": (LANDSAT_ARD, 10_000, 1152, 48, 10, 40.0,
+                       (12.0, 20.0, 6.0)),
+           "sentinel2": (SENTINEL2, 90_000, 448, 80, 10, 30.0, None)}
+_PINNED = {
+    ("landsat", ""): (147677440000.0, 499845493728.0),
+    ("landsat", "monitor,score,tmask"): (129245440000.0, 499845493728.0),
+    ("sentinel2", ""): (1812240000000.0, 7580893348672.0),
+    ("sentinel2", "monitor,score,tmask"): (1763856000000.0,
+                                           7580893348672.0),
+}
+
+
+@pytest.mark.parametrize("shape,route", sorted(_PINNED))
+def test_route_counts_are_pinned(shape, route):
+    sensor, P, T, W, S, rounds, phase = _SHAPES[shape]
+    pallas = tuple(route.split(",")) if route else ()
+    by = flops.round_bytes(P, T, W, S, 4, sensor, rounds=rounds,
+                           phase_rounds=phase, pallas=pallas)
+    fl = flops.detect_flops(P, T, W, rounds, sensor, phase_rounds=phase)
+    assert (by, fl["total"]) == _PINNED[(shape, route)]

@@ -1,276 +1,20 @@
-"""Fused gram→CD→close kernel + cross-device rebalancing ring.
-
-The fused round kernel (FIREBIRD_FUSED_FIT, pallas_ops.fused_fit_close)
-must be INVISIBLE in results against the unfused Pallas-fit
-configuration — same _gram_cd_core fit arithmetic, same _close_mags
-magnitude program, exact-select close writes — so the golden here is
-byte equality, not an envelope (the mega kernel's decision-exact
-contract is the weaker cousin; this one is strict because the fused
-kernel shares every float program with its baseline).  The rebalancing
-ring (FIREBIRD_REBALANCE, parallel.mesh) must migrate straggler lanes
-between devices of a simulated mesh without moving a single store row,
-and account the migrated lanes in the occupancy/metric surface.
+"""The cross-device rebalancing ring (FIREBIRD_REBALANCE, parallel.mesh):
+it must migrate straggler lanes between devices of a simulated mesh
+without moving a single store row, and account the migrated lanes in the
+occupancy/metric surface.
 """
 
 import os
 
 import numpy as np
 import jax.numpy as jnp
-import pytest
 
 from firebird_tpu.ccd import kernel, params, synthetic
 from firebird_tpu.ingest.packer import PackedChips
 
-P_TEST = 32      # every detect case shares one compiled shape family
-
-STORE_FIELDS = ("n_segments", "seg_meta", "seg_rmse", "seg_mag",
-                "seg_coef", "mask", "procedure", "rounds", "round_counts",
-                "vario")
-
-
-@pytest.fixture(autouse=True, scope="module")
-def _fuse_env():
-    """The fused golden's baseline arithmetic: the Pallas fit kernel
-    (the fused kernel wraps the same _gram_cd_core).  The cascade gate
-    stays at its production default for the goldens — the bucketed
-    re-entry doubles the traced program, and the rebalance test (which
-    NEEDS the stage-2 boundary) lowers FIREBIRD_COMPACT_MIN_LANES for
-    its own dispatches only, keeping this module inside the tier-1
-    budget.  Module-scoped, set before the first compile; trace-time
-    reads."""
-    old = os.environ.get("FIREBIRD_PALLAS")
-    os.environ["FIREBIRD_PALLAS"] = "fit"
-    yield
-    if old is None:
-        os.environ.pop("FIREBIRD_PALLAS", None)
-    else:
-        os.environ["FIREBIRD_PALLAS"] = old
-
 
 def _grid():
     return synthetic.acquisition_dates("1995-01-01", "1997-06-01", 16)
-
-
-def _adversarial_pixels(seed=7):
-    """Mixed + fuzz-adversarial lanes: breaks, spikes (Tmask path),
-    near-empty series, all-cloud and fill lanes — scattered so the
-    compaction permutation moves rows and close/fit rounds interleave."""
-    rng = np.random.default_rng(seed)
-    t = _grid()
-    T = t.shape[0]
-    px = []
-    for i in range(10):
-        Y = synthetic.harmonic_series(t, rng)
-        if i % 2 == 0:
-            Y[:, T // 2:] += 800.0            # break + re-init
-        if i % 3 == 0:
-            Y[:, rng.integers(0, T)] += 2500  # spike (outlier path)
-        px.append((Y, np.full(T, synthetic.QA_CLEAR, np.uint16)))
-    # a lane with only a handful of clear obs (init-starved)
-    Ys = synthetic.harmonic_series(t, rng)
-    qs = np.full(T, synthetic.QA_CLOUD, np.uint16)
-    qs[:: max(T // 5, 1)] = synthetic.QA_CLEAR
-    px.append((Ys, qs))
-    # all-cloud and fill lanes (alt procedures, DONE from round 0)
-    px.append((synthetic.harmonic_series(t, rng),
-               np.full(T, synthetic.QA_CLOUD, np.uint16)))
-    while len(px) < P_TEST:
-        px.append((np.full((7, T), params.FILL_VALUE, np.float64),
-                   np.full(T, synthetic.QA_FILL, np.uint16)))
-    order = rng.permutation(P_TEST)
-    return t, [px[i] for i in order]
-
-
-def _pack(t, pixels, n_chips=1):
-    Ys, qas = zip(*pixels)
-    spectra = np.stack([np.asarray(Y, np.int16) for Y in Ys])
-    spectra = spectra.transpose(1, 0, 2)[None]
-    return PackedChips(
-        cids=np.stack([np.full(2, i, np.int64) for i in range(n_chips)]),
-        dates=np.tile(t[None], (n_chips, 1)).astype(np.int32),
-        spectra=np.tile(spectra, (n_chips, 1, 1, 1)),
-        qas=np.tile(np.stack(qas)[None], (n_chips, 1, 1)),
-        n_obs=np.full(n_chips, t.shape[0], np.int32))
-
-
-_RUNS: dict = {}
-
-
-def _run(fused: bool, compact: bool):
-    key = (fused, compact)
-    if key not in _RUNS:
-        t, px = _adversarial_pixels()
-        _RUNS[key] = kernel.detect_packed(_pack(t, px), dtype=jnp.float32,
-                                          compact=compact, fused=fused)
-    return _RUNS[key]
-
-
-def _assert_identical(on, off):
-    for f in STORE_FIELDS:
-        np.testing.assert_array_equal(np.asarray(getattr(on, f)),
-                                      np.asarray(getattr(off, f)),
-                                      err_msg=f)
-
-
-@pytest.mark.slow  # ~41s (two extra full kernel shapes); tier-1 keeps the compact-ON golden — the production configuration — and `make test` / fuse-smoke still run this compaction-free pure-kernel leg
-def test_fused_golden_compact_off():
-    """The headline contract: fused on/off byte-identical with
-    compaction off (no permutation in play — pure kernel equality)."""
-    _assert_identical(_run(True, False), _run(False, False))
-
-
-@pytest.mark.slow  # ~65s (two more full kernel shapes); `make test` / fuse-smoke still dispatch the fused-vs-core comparison on every verify run
-def test_fused_golden_compact_on():
-    """Same golden under active-lane compaction: the fused kernel rides
-    the dense-prefix permutation and the per-block skip guards without
-    moving a bit."""
-    _assert_identical(_run(True, True), _run(False, True))
-
-
-def _assert_mon_golden(mon, base):
-    """The whole-round fusion's contract (FIREBIRD_FUSED_FIT=mon): every
-    decision field AND the coef/rmse payload byte-identical to the
-    unfused chain (same _mon_scored_logic/_close_logic/_gram_cd_core
-    programs), with seg_mag alone on the mega-style envelope — the
-    break-magnitude median is computed from the in-VMEM PEEK run instead
-    of arriving from kernel._close_mags, and a last-ulp input difference
-    can flip which element the median selects (measured 1.2e-4 here)."""
-    for f in STORE_FIELDS:
-        if f == "seg_mag":
-            continue
-        np.testing.assert_array_equal(np.asarray(getattr(mon, f)),
-                                      np.asarray(getattr(base, f)),
-                                      err_msg=f)
-    np.testing.assert_allclose(np.asarray(mon.seg_mag),
-                               np.asarray(base.seg_mag),
-                               rtol=5e-3, atol=1e-2)
-
-
-@pytest.mark.slow  # ~40s (one extra full kernel shape on the shared baseline); `make test` / precision-smoke still dispatch the mon route every verify run
-def test_fused_mon_golden_compact_off():
-    """Monitor+fit+close as ONE pallas_call vs the unfused chain,
-    compaction off — pure kernel equality, no permutation in play."""
-    _assert_mon_golden(_run("mon", False), _run(False, False))
-
-
-@pytest.mark.slow  # ~65s (one extra full kernel shape incl. the cascade); tier-1 keeps the fused_round skip-guard + knob rungs below
-def test_fused_mon_golden_compact_on():
-    """Same golden under active-lane compaction: the whole-round kernel
-    rides the dense-prefix permutation and the per-block skip guards."""
-    _assert_mon_golden(_run("mon", True), _run(False, True))
-
-
-@pytest.mark.slow  # ~93s in tier-1 (the compact-ON fused run is uncached there with the goldens deselected); `make test` shares the golden's cached run and `make fuse-smoke` asserts the same occupancy-counters-moving contract every verify run
-def test_fused_occupancy_still_captured():
-    """The fused route must not blind the occupancy telemetry the
-    roofline model feeds on."""
-    seg = _run(True, True)
-    r = int(np.asarray(seg.rounds)[0])
-    occ = np.asarray(seg.occupancy)[0]
-    assert r > 0 and (occ[:r, 0] > 0).any()
-    assert int(np.asarray(seg.round_counts).reshape(-1, 3)[0, 1]) > 0
-
-
-def test_fused_guard_skip_is_pass_through():
-    """Skip-guard exactness for the fused kernel's active= mask: a block
-    with no closing and no fitting lane must pass buffers, nseg, coefs
-    and rmse through BIT-identically (the skip branch copies inputs —
-    and for inactive lanes the compute branch is a no-op, so a guarded
-    call equals the unguarded call everywhere)."""
-    from firebird_tpu.ccd import pallas_ops
-
-    rng = np.random.default_rng(3)
-    B, T, K, S, P, BP = 7, 24, 8, 3, 16, 8
-    Yt = jnp.asarray(rng.integers(100, 3000, (B, T, P)), jnp.int16)
-    X = jnp.asarray(rng.standard_normal((T, K)), jnp.float32)
-    t = jnp.asarray(np.sort(rng.integers(724000, 725000, T)), jnp.float32)
-    # Lanes 0..BP-1 active (block 0), lanes BP.. all inactive (block 1):
-    # inactive lanes carry do_fit=False and no close flags.
-    act = np.zeros(P, bool)
-    act[:BP] = True
-    do_fit = act.copy()
-    is_brk = np.zeros(P, bool)
-    is_brk[1] = True
-    is_tail = np.zeros(P, bool)
-    is_tail[2] = True
-    w_fit = (rng.integers(0, 2, (P, T)) * act[:, None]).astype(np.float32)
-    bufs = tuple(jnp.asarray(rng.standard_normal((P, S * k)), jnp.float32)
-                 for k in (6, B, B, B * K))
-    args = (Yt, X, t, jnp.asarray(w_fit), jnp.asarray(do_fit),
-            jnp.full(P, 20, jnp.int32),
-            jnp.asarray(rng.integers(0, 2, (P, T)).astype(bool)),
-            jnp.asarray(rng.standard_normal((P, B, K)), jnp.float32),
-            jnp.ones((P, B), jnp.float32),
-            jnp.asarray(rng.standard_normal((P, B)), jnp.float32),
-            jnp.asarray(is_tail), jnp.asarray(is_brk),
-            jnp.full(P, T // 2, jnp.int32), jnp.zeros(P, jnp.int32),
-            jnp.ones(P, bool), jnp.zeros(P, jnp.int32), bufs)
-    kw = dict(S=S, block_p=BP, interpret=True)
-    ref = pallas_ops.fused_fit_close(*args, **kw)
-    got = pallas_ops.fused_fit_close(*args, active=jnp.asarray(act), **kw)
-    for r, g in zip(jax_leaves(ref), jax_leaves(got)):
-        np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
-    # and the dead block really passed its buffers through untouched
-    for b_in, b_out in zip(bufs, got[0]):
-        np.testing.assert_array_equal(np.asarray(b_in)[BP:],
-                                      np.asarray(b_out)[BP:])
-
-
-@pytest.mark.slow  # ~13s interpret trace; the mon goldens above ride the same guard path under compaction and `make precision-smoke`'s mon leg dispatches it every verify run
-def test_fused_round_guard_skip_is_pass_through():
-    """Skip-guard exactness for the whole-round kernel: a block with no
-    monitoring and no initializing lane must pass buffers, nseg, coefs
-    and rmse through BIT-identically and zero the event flags — the
-    outer loop's _skip_round contract.  Inactive lanes are a compute
-    no-op, so the guarded call equals the unguarded call everywhere."""
-    from firebird_tpu.ccd import pallas_ops
-    from firebird_tpu.ccd.sensor import LANDSAT_ARD
-
-    rng = np.random.default_rng(9)
-    B, T, K, S, P, BP = 7, 24, 8, 3, 16, 8
-    Yt = jnp.asarray(rng.integers(100, 3000, (B, T, P)), jnp.int16)
-    X = jnp.asarray(rng.standard_normal((T, K)), jnp.float32)
-    t = jnp.asarray(np.sort(rng.integers(724000, 725000, T)), jnp.float32)
-    act = np.zeros(P, bool)
-    act[:BP] = True
-    in_mon = act.copy()
-    in_mon[0] = False
-    init_ok = np.zeros(P, bool)
-    init_ok[0] = True                 # lane 0: the INIT handoff path
-    w_stab = np.zeros((P, T), np.int32)
-    w_stab[0, ::2] = 1
-    bufs = tuple(jnp.asarray(rng.standard_normal((P, S * k)), jnp.float32)
-                 for k in (6, B, B, B * K))
-    args = (Yt, X, t,
-            jnp.ones((P, T), bool),
-            jnp.asarray(rng.integers(0, 2, (P, T)).astype(bool)),
-            jnp.full(P, T // 2, jnp.int32), jnp.full(P, 12, jnp.int32),
-            jnp.asarray(in_mon),
-            jnp.asarray(rng.standard_normal((P, B, K)), jnp.float32),
-            jnp.ones((P, B), jnp.float32), jnp.ones((P, B), jnp.float32),
-            jnp.asarray(init_ok), jnp.asarray(w_stab),
-            jnp.full(P, 20, jnp.int32), jnp.ones(P, bool),
-            jnp.zeros(P, jnp.int32), bufs)
-    kw = dict(S=S, sensor=LANDSAT_ARD, change_thr=35.9, outlier_thr=31.7,
-              block_p=BP, interpret=True)
-    out_u = pallas_ops.fused_round(*args, **kw)
-    out_g = pallas_ops.fused_round(*args, active=jnp.asarray(act), **kw)
-    for r, g in zip(jax_leaves(out_u), jax_leaves(out_g)):
-        np.testing.assert_array_equal(np.asarray(r), np.asarray(g))
-    # and the dead block really passed its buffers through untouched
-    for b_in, b_out in zip(bufs, out_g[0]):
-        np.testing.assert_array_equal(np.asarray(b_in)[BP:],
-                                      np.asarray(b_out)[BP:])
-    # event flags on the dead block are the _skip_round zeros
-    ev = out_g[4]
-    for f in ("is_tail", "is_brk", "is_refit", "do_fit"):
-        assert not np.asarray(ev[f])[BP:].any(), f
-
-
-def jax_leaves(tree):
-    import jax
-
-    return jax.tree_util.tree_leaves(tree)
 
 
 def test_rebalance_ring_row_identity_and_migration():
@@ -321,10 +65,9 @@ def test_rebalance_ring_row_identity_and_migration():
         # The ring lives at the stage-2 boundary: lower the cascade gate
         # so the P=48 shape builds it (trace-time read).  The ring is
         # orthogonal to WHICH kernel computes the lanes (it migrates
-        # state, not programs), so this test runs the cheap lax path —
-        # `make fuse-smoke` proves the same row-identity with the fused
-        # kernel enabled; tracing two interpret-Pallas cascade programs
-        # here would double the module's tier-1 cost for no coverage.
+        # state, not programs), so this test runs the cheap lax path;
+        # tracing two interpret-Pallas cascade programs here would
+        # double the module's tier-1 cost for no coverage.
         os.environ["FIREBIRD_COMPACT_MIN_LANES"] = "8"
         os.environ["FIREBIRD_PALLAS"] = "0"
         os.environ["FIREBIRD_REBALANCE"] = "0"
@@ -378,43 +121,3 @@ def test_rebalance_spec_resolution(monkeypatch):
     assert rebalance_spec(mesh1) is None
 
 
-def test_fused_knob_resolution(monkeypatch):
-    """use_fused_fit reads the registered knob; explicit fused= wins at
-    the dispatch layer regardless of env (the compact precedent)."""
-    monkeypatch.delenv("FIREBIRD_FUSED_FIT", raising=False)
-    assert kernel.use_fused_fit() is False
-    monkeypatch.setenv("FIREBIRD_FUSED_FIT", "1")
-    assert kernel.use_fused_fit() is True
-    monkeypatch.setenv("FIREBIRD_FUSED_FIT", "0")
-    assert kernel.use_fused_fit() is False
-
-
-def test_fused_mode_tristate(monkeypatch):
-    """fused_mode's tri-state: off ('', '0') -> 0, whole-round ('mon' or
-    '2') -> 'mon', any other truthy value -> 1 — and use_fused_fit stays
-    truthy for BOTH fused tiers (the roofline's fused modeling keys on
-    it)."""
-    monkeypatch.delenv("FIREBIRD_FUSED_FIT", raising=False)
-    assert kernel.fused_mode() == 0
-    for v, want in (("0", 0), ("1", 1), ("mon", "mon"), ("2", "mon")):
-        monkeypatch.setenv("FIREBIRD_FUSED_FIT", v)
-        assert kernel.fused_mode() == want, v
-        assert kernel.use_fused_fit() is bool(want)
-
-
-def test_mega_block_p_env_override(monkeypatch):
-    """FIREBIRD_MEGA_BLOCK_P (the bench autotune's fuse_repro seed) is a
-    trace-time multiple-of-128 override; 0/unset defers to the VMEM
-    budget sizing."""
-    from firebird_tpu.ccd import pallas_ops
-
-    monkeypatch.delenv("FIREBIRD_MEGA_BLOCK_P", raising=False)
-    assert pallas_ops._env_block_p() is None
-    monkeypatch.setenv("FIREBIRD_MEGA_BLOCK_P", "256")
-    assert pallas_ops._env_block_p() == 256
-    monkeypatch.setenv("FIREBIRD_MEGA_BLOCK_P", "300")
-    assert pallas_ops._env_block_p() == 256     # floored to the lane width
-    monkeypatch.setenv("FIREBIRD_MEGA_BLOCK_P", "100")   # below one vector
-    assert pallas_ops._env_block_p() is None
-    monkeypatch.setenv("FIREBIRD_MEGA_BLOCK_P", "junk")
-    assert pallas_ops._env_block_p() is None
